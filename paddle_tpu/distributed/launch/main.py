@@ -13,6 +13,7 @@ and relaunches the pod on failure (~ ElasticManager, bounded restarts).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -46,6 +47,16 @@ def _parse_args(argv=None):
     p.add_argument("training_script")
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return p.parse_args(argv)
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from its device nodes — the
+    launcher must not ask JAX: a process that has initialised the TPU
+    backend holds the chips its children need."""
+    if os.environ.get("JAX_PLATFORMS", "").lower().startswith("cpu"):
+        return 0  # the children are pinned to the CPU backend
+    return len(glob.glob("/dev/accel[0-9]*")) \
+        or len(glob.glob("/dev/vfio/[0-9]*"))
 
 
 class Container:
@@ -101,6 +112,15 @@ def build_pod(args, n_nodes=None, node_index=None,
     nproc = args.nproc_per_node
     if nproc is None:
         nproc = 1
+    if nproc > 1 and local_tpu_chips():
+        # nothing here gives a local rank its own chip: every child past
+        # the first would fail to take the device the first one holds
+        raise SystemExit(
+            f"--nproc_per_node={nproc} on a TPU host: a chip belongs to "
+            "one process at a time and this launcher does not partition "
+            "the host's chips between local ranks. Drive all local chips "
+            "from ONE process over a jax Mesh (--nproc_per_node=1), or "
+            "set JAX_PLATFORMS=cpu for a CPU run.")
     nn = args.nnodes if n_nodes is None else n_nodes
     ni = args.node_rank if node_index is None else node_index
     world = nn * nproc

@@ -1,100 +1,33 @@
-"""Version-bridging wrappers for jax APIs that were renamed in flight.
+"""Mesh and sharding helpers over the installed jax (0.9).
 
-The chip image carries a newer jax (``jax.shard_map`` with
-``check_vma``/``axis_names``, ``pltpu.CompilerParams``); CPU test
-images may carry an older one (``jax.experimental.shard_map`` with
-``check_rep``/``auto``, ``pltpu.TPUCompilerParams``). Importing from
-here keeps every kernel and parallel module loadable on both, instead
-of each call site feature-testing jax inline.
+Everything the installed jax spells natively is called natively at the
+call site (``jax.shard_map``, ``jax.sharding.set_mesh`` /
+``get_abstract_mesh``, ``pltpu.CompilerParams``); what stays here is
+the repo's own policy: meshes are built with AUTO axes, and trees are
+placed on a mesh by PartitionSpec args.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-# compat context-mesh slot for jax builds without jax.sharding.set_mesh
-# (set_mesh below stores the mesh here; get_context_mesh reads it)
-_CTX_MESH = {"mesh": None}
-
-
-def _native_ctx_mesh() -> bool:
-    """ONE feature test for the whole context-mesh pair: jax must have
-    BOTH jax.sharding.set_mesh and get_abstract_mesh for the native
-    path — on builds with only one (the 0.5.x window shipped
-    get_abstract_mesh before set_mesh went public), a split test would
-    store the mesh in the compat slot while the probe reads the empty
-    native abstract mesh, silently disabling manual sharding."""
-    return (hasattr(jax.sharding, "set_mesh")
-            and callable(getattr(jax.sharding, "get_abstract_mesh",
-                                 None)))
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient sharding mesh:
-    ``jax.sharding.set_mesh`` on new jax, else a module-level slot that
-    ``get_context_mesh`` (the pallas-sharding probe) reads."""
-    if _native_ctx_mesh():
-        return jax.sharding.set_mesh(mesh)
-
-    @contextlib.contextmanager
-    def _cm():
-        prev = _CTX_MESH["mesh"]
-        _CTX_MESH["mesh"] = mesh
-        try:
-            yield mesh
-        finally:
-            _CTX_MESH["mesh"] = prev
-
-    return _cm()
-
-
-def get_context_mesh():
-    """(mesh, eligible_axes) for manual shard_map over the ambient mesh.
-
-    New jax: the abstract mesh + its AUTO axes (only those may go
-    manual inside a pjit trace). Old jax (no abstract-mesh API): the
-    compat ``set_mesh`` context, every axis eligible — 0.4.x has no
-    auto/manual axis types, shard_map with a concrete mesh under jit
-    is the normal form there."""
-    if _native_ctx_mesh():
-        amesh = jax.sharding.get_abstract_mesh()
-        eligible = getattr(amesh, "auto_axes", ()) if amesh is not None \
-            else ()
-        return amesh, eligible
-    mesh = _CTX_MESH["mesh"]
-    return mesh, (mesh.axis_names if mesh is not None else ())
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axis_names):
-    """A 1-or-more-D device mesh over the first prod(shape) local
-    devices: ``jax.make_mesh`` on jax builds that have it (it also
-    picks a bandwidth-aware device order on real topologies), else the
-    classic ``Mesh(np.reshape(devices), names)`` construction — the
-    form every 0.4.x build accepts."""
+    """A device mesh over the first prod(shape) local devices with
+    every axis ``AxisType.Auto``. ``jax.make_mesh`` defaults to
+    Explicit axes, under which sharded contractions are type errors
+    instead of GSPMD-inserted collectives — the serving and training
+    factories all rely on GSPMD (Pallas calls go manual through
+    ``shard_map`` on top of it)."""
     shape = tuple(int(s) for s in shape)
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, tuple(axis_names))
-    import math
-
-    import numpy as np
-    n = math.prod(shape)
-    devs = jax.devices()
-    if n > len(devs):
-        raise ValueError(f"mesh {shape} needs {n} devices, have "
-                         f"{len(devs)}")
-    return jax.sharding.Mesh(np.array(devs[:n]).reshape(shape),
-                             tuple(axis_names))
+    return jax.make_mesh(shape, tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def named_sharding(mesh, *names):
-    """``NamedSharding(mesh, PartitionSpec(*names))`` in one call —
-    the SNIPPETS-[3] utility shape. ``names`` entries are mesh axis
-    names or None (replicated dim); no names at all = fully
-    replicated over the mesh. One construction site so callers never
-    touch the PartitionSpec class directly (its import path moved
-    across jax versions; ``jax.sharding.PartitionSpec`` is the stable
-    spelling both old and new builds expose)."""
+    """``NamedSharding(mesh, PartitionSpec(*names))`` in one call.
+    ``names`` entries are mesh axis names or None (replicated dim); no
+    names at all = fully replicated over the mesh."""
     return jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec(*names))
 
@@ -109,12 +42,7 @@ def device_put_sharded(tree, mesh, specs=None):
     - a single PartitionSpec-args tuple: every leaf gets it;
     - a dict keyed like ``tree`` (flat param dicts): per-leaf spec
       tuples, missing keys replicated.
-
-    Unlike the LEGACY ``jax.device_put_sharded`` (per-device shard
-    lists, removed on newer jax), this is the NamedSharding form that
-    exists on both sides of the drift; the name is kept because it is
-    the operation serving code means — "put this tree on the mesh,
-    sharded as specified"."""
+    """
     def _sh(spec):
         return named_sharding(mesh, *spec) if spec else \
             named_sharding(mesh)
@@ -132,33 +60,3 @@ def device_put_sharded(tree, mesh, specs=None):
     sh = _sh(tuple(specs) if specs else ())
     return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh),
                                   tree)
-
-
-def tpu_compiler_params():
-    """``pltpu.CompilerParams`` (new jax) or ``pltpu.TPUCompilerParams``
-    (old name) — the Pallas kernel modules import this once instead of
-    each feature-testing pltpu."""
-    from jax.experimental.pallas import tpu as pltpu
-    return getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
-              axis_names=None):
-    """``jax.shard_map`` when available, else the experimental one.
-
-    ``axis_names`` (new-jax): the MANUAL axes. The old API takes the
-    complement — ``auto`` = mesh axes left to GSPMD — so the set is
-    inverted here. ``check_vma`` maps onto the old ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, **kw)

@@ -37,9 +37,9 @@ from ...core.tensor import Parameter, Tensor
 from ...distributed.fleet.meta_parallel import (ColumnParallelLinear,
                                                 RowParallelLinear,
                                                 VocabParallelEmbedding)
-from ...jax_compat import shard_map as _shard_map
 from ...nn import functional as F
 from ...ops.dispatch import apply_op
+from ...ops.pallas.lowering import lowering_for_chip
 
 
 @dataclasses.dataclass
@@ -94,20 +94,18 @@ _TP = {"mesh": None, "axis": "model"}
 
 
 def set_tensor_parallel_mesh(mesh, axis: str = "model"):
-    """Mesh whose `axis` shards attention heads (set by the train-step
-    factories). Needed because GSPMD cannot partition a Pallas custom
-    call: without it, flash attention under TP forces per-layer
-    all-gathers of Q/K/V (measured: 140 all-gathers vs 0 on a 2-layer
-    TP=2 program). With it, the flash call runs inside a partial-manual
-    shard_map over `axis` — per-device kernels on local heads."""
+    """The train step's mesh, whose `axis` shards attention heads (set
+    by the train-step factories). Needed because GSPMD cannot partition
+    a Pallas custom call — on a chip it does not lower at all under a
+    >1-device mesh. With the mesh known, the flash call runs inside a
+    shard_map: per-device kernels on local heads and local batch rows."""
     _TP["mesh"] = mesh
     _TP["axis"] = axis
 
 
 def _tensor_parallel_mesh():
     mesh, axis = _TP["mesh"], _TP["axis"]
-    if mesh is None or axis not in mesh.axis_names \
-            or mesh.shape[axis] <= 1:
+    if mesh is None or mesh.size <= 1:
         return None, None
     return mesh, axis
 
@@ -314,8 +312,7 @@ class LlamaAttention(nn.Layer):
                 from ...ops.pallas.flash_attention_gqa import (
                     grouped_flash_attention)
                 tp_mesh, tp_axis = _tensor_parallel_mesh()
-                # the wrapper self-guards divisibility and falls back to a
-                # plain call; mesh=None probes the context abstract mesh
+                # mesh=None takes the context abstract mesh
                 out = _shard_map_heads(
                     lambda q, k, v: grouped_flash_attention(
                         q, k, v, True, scale),
@@ -566,10 +563,9 @@ def llama_train_step_factory(model: LlamaForCausalLM, mesh: Mesh,
         prev_tp = (_TP["mesh"], _TP["axis"])
         set_context_parallel_mesh(mesh if has_sep else None)
         # GSPMD can't partition Pallas calls: give the attention the mesh
-        # so the flash kernel runs shard_mapped over 'model' (no Q/K/V
-        # all-gathers under TP)
-        set_tensor_parallel_mesh(mesh if (has_model and not has_sep)
-                                 else None)
+        # so the flash kernel runs shard_mapped over 'model' and 'data'
+        # (under 'sep' the ring path owns its own shard_map)
+        set_tensor_parallel_mesh(None if has_sep else mesh)
         use_chunked = bool(chunked_vocab_ce) and not has_model
         try:
             # tape off: jax.value_and_grad differentiates this trace; the
@@ -589,37 +585,38 @@ def llama_train_step_factory(model: LlamaForCausalLM, mesh: Mesh,
             from ...ops.chunked_ce import chunked_causal_lm_loss
             return chunked_causal_lm_loss(h, w_head, labels,
                                           int(chunked_vocab_ce))
-        if jax.default_backend() != "cpu" and not has_model:
+        if lowering_for_chip() and not has_model:
             # Pallas fused softmax-xent: skips the (B*S, V) softmax HBM
             # round trip (the largest intermediate of the training loss).
-            # GSPMD can't partition the Pallas call, so batch/sequence
-            # mesh axes go manual (per-shard mean + pmean == global mean:
-            # no label shift, equal shard sizes). With a >1 'model' axis
-            # the logits are vocab-sharded — the dense path below is the
-            # right form there (GSPMD partitions the log_softmax
-            # reductions with psums instead of gathering (B,S,V)).
+            # GSPMD can't partition the Pallas call, so under a >1-device
+            # mesh EVERY axis goes manual: batch/sequence split over
+            # 'data'/'sep' (per-shard mean + pmean == global mean: no
+            # label shift, equal shard sizes), any other axis holds
+            # replicas. With a >1 'model' axis the logits are
+            # vocab-sharded — the dense path below is the right form
+            # there (GSPMD partitions the log_softmax reductions with
+            # psums instead of gathering (B,S,V)).
             from ...ops.pallas.fused_ce import causal_lm_loss
+            if mesh.size <= 1:
+                return causal_lm_loss(logits, labels)
             B_, S_ = labels.shape
             dim_for = {"data": B_, "sep": S_}
-            manual = [a for a in ("data", "sep")
-                      if a in mesh.axis_names and mesh.shape[a] > 1
-                      and dim_for[a] % mesh.shape[a] == 0]
-            if not manual:
-                return causal_lm_loss(logits, labels)
+            split = [a for a in ("data", "sep")
+                     if a in mesh.axis_names and mesh.shape[a] > 1
+                     and dim_for[a] % mesh.shape[a] == 0]
 
             def _fused(lg, lb):
                 loss = causal_lm_loss(lg, lb)
-                for a in manual:
+                for a in split:
                     loss = jax.lax.pmean(loss, a)
                 return loss
 
-            b_ax = "data" if "data" in manual else None
-            s_ax = "sep" if "sep" in manual else None
-            return _shard_map(
+            b_ax = "data" if "data" in split else None
+            s_ax = "sep" if "sep" in split else None
+            return jax.shard_map(
                 _fused, mesh=mesh,
                 in_specs=(P(b_ax, s_ax, None), P(b_ax, s_ax)),
-                out_specs=P(), check_vma=False,
-                axis_names=frozenset(manual))(logits, labels)
+                out_specs=P(), check_vma=False)(logits, labels)
         logits = logits.astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, -1)
         nll = -jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
@@ -644,7 +641,7 @@ def llama_train_step_factory(model: LlamaForCausalLM, mesh: Mesh,
     moment_dev_sh = {k: with_memory_kind(opt_state["m"][k].sharding,
                                          "device")
                      for k in params} if offload_moments else None
-    in_jit_offload = offload_moments and jax.default_backend() != "cpu"
+    in_jit_offload = offload_moments and lowering_for_chip()
 
     host_m_sh = {k: opt_state["m"][k].sharding
                  for k in params} if offload_moments else None

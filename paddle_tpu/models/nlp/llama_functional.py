@@ -16,6 +16,7 @@ scaled execution form:
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Dict
 
 import jax
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ...ops.pallas.lowering import lowering_for_chip
 from .llama import LlamaConfig, LlamaForCausalLM, apply_rotary
 
 LAYER_KEYS = [
@@ -75,8 +77,11 @@ def _rms(x, w, eps):
 _FORCE_FLASH_FOR_TESTS = False  # CPU interpret-mode flash in the factories
 
 
-def layer_forward(cfg: LlamaConfig, p: Dict[str, jax.Array], x):
-    """One decoder layer over its param dict (pure)."""
+def layer_forward(cfg: LlamaConfig, p: Dict[str, jax.Array], x,
+                  attn_mesh=None):
+    """One decoder layer over its param dict (pure). ``attn_mesh``: the
+    mesh to shard_map the flash kernel over when the caller runs under
+    plain GSPMD (None = the context mesh of an enclosing shard_map)."""
     B, S, H = x.shape
     nh, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
     hd = H // nh
@@ -92,8 +97,7 @@ def layer_forward(cfg: LlamaConfig, p: Dict[str, jax.Array], x):
     vt = jnp.swapaxes(v, 1, 2)
     use_flash = (S >= 256 and S % 128 == 0 and hd in (64, 128, 256)
                  and qt.dtype in (jnp.float32, jnp.bfloat16)
-                 and (jax.default_backend() != "cpu"
-                      or _FORCE_FLASH_FOR_TESTS))
+                 and (lowering_for_chip() or _FORCE_FLASH_FOR_TESTS))
     if use_flash:
         # GQA configs keep K/V at nkv heads (grouped kernel — no repeat
         # blowup through HBM)
@@ -108,7 +112,7 @@ def layer_forward(cfg: LlamaConfig, p: Dict[str, jax.Array], x):
         # manual instead of all-gathering Q/K/V per microbatch
         from ...parallel.pallas_sharding import shard_map_attention
         ctx = shard_map_attention(lambda a, b, c: _fa(a, b, c, True),
-                                  qt, kt, vt)
+                                  qt, kt, vt, mesh=attn_mesh)
     else:
         if nh != nkv:
             kt = jnp.repeat(kt, nh // nkv, axis=1)
@@ -145,7 +149,7 @@ def forward(cfg: LlamaConfig, outer, layers, tokens, remat=True):
 def _ce(logits, labels):
     """Causal-LM CE: Pallas fused softmax-xent on TPU (no (N,V) softmax
     HBM round-trip), dense log_softmax on CPU."""
-    if jax.default_backend() != "cpu":
+    if lowering_for_chip():
         from ...ops.pallas.fused_ce import causal_lm_loss
         return causal_lm_loss(logits, labels)
     logits = logits.astype(jnp.float32)
@@ -409,8 +413,9 @@ def llama_4d_train_step_factory(model: LlamaForCausalLM, mesh: Mesh,
               "layers": zeros_tree(layers, layer_msh)},
     }
 
-    def stage_fn(stage_params, x):
-        body = lambda carry, lp: (layer_forward(cfg, lp, carry), None)
+    def stage_fn(stage_params, x, attn_mesh=None):
+        body = lambda carry, lp: (
+            layer_forward(cfg, lp, carry, attn_mesh), None)
         x, _ = jax.lax.scan(body, x, stage_params)
         return x
 
@@ -431,7 +436,10 @@ def llama_4d_train_step_factory(model: LlamaForCausalLM, mesh: Mesh,
             # caller asked for it.
             assert V == 1, "virtual stages need a 'pipe' mesh axis"
             stage0 = jax.tree.map(lambda a: a[0], params["layers"])
-            fn = jax.checkpoint(stage_fn) if remat else stage_fn
+            # no enclosing shard_map here: hand the flash kernel the mesh
+            # (a Mosaic call cannot lower under plain GSPMD)
+            fn = partial(stage_fn, attn_mesh=mesh)
+            fn = jax.checkpoint(fn) if remat else fn
             h = fn(stage0, emb)
         elif V > 1:
             h = pipeline_apply_interleaved(

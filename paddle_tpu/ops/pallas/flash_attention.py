@@ -40,17 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import tpu_compiler_params
-
-# jax renamed TPUCompilerParams -> CompilerParams (version-bridged in
-# one place, jax_compat)
-_CompilerParams = tpu_compiler_params()
-
-
-def _interpret() -> bool:
-    # CPU backend (tests / sim meshes) runs kernels in interpreter mode
-    import jax
-    return jax.default_backend() == "cpu"
+from .lowering import interpret as _interpret
 
 DEFAULT_BLOCK_Q = None  # auto: largest of 512/256/128 dividing the seq
 DEFAULT_BLOCK_K = None
@@ -517,7 +507,7 @@ def _flash_fwd_stream(q, k, v, causal, sm_scale, block_q, block_k):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, D), lse[..., 0].reshape(B, H, Sq)
@@ -558,7 +548,7 @@ def _flash_bwd_stream(q, k, v, out, lse, do, causal, sm_scale, block_q,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, t: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr, dor, lser, delta)
 
@@ -587,7 +577,7 @@ def _flash_bwd_stream(q, k, v, out, lse, do, causal, sm_scale, block_q,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr, dor, lser, delta)
 
@@ -625,7 +615,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
             jax.ShapeDtypeStruct((bh, Sq, D), q.dtype),
             jax.ShapeDtypeStruct((bh, Sq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, D), lse[..., 0].reshape(B, H, Sq)
@@ -759,7 +749,7 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, bwd_block_q, bwd_block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, Sq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qr, kr, vr, dor, lser, delta)
 
@@ -783,7 +773,7 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, bwd_block_q, bwd_block_k,
             jax.ShapeDtypeStruct((bh, Sk, D), k.dtype),
             jax.ShapeDtypeStruct((bh, Sk, D), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qr, kr, vr, dor, lser, delta)
 

@@ -23,13 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import tpu_compiler_params
-
-# jax renamed TPUCompilerParams -> CompilerParams (version-bridged in
-# one place, jax_compat)
-_CompilerParams = tpu_compiler_params()
-
-from .flash_attention import LN2, LOG2E, NEG_INF, _interpret, _pick_block
+from .flash_attention import LN2, LOG2E, NEG_INF, _pick_block
+from .lowering import interpret as _interpret
 
 
 # f32-element budget for ONE (G*block_q, block_k) score/probability buffer
@@ -303,7 +298,7 @@ def _gqa_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k):
             jax.ShapeDtypeStruct((bh, G, Sq, D), q.dtype),
             jax.ShapeDtypeStruct((bh, G, Sq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qr, kr, vr)
     return (out.reshape(B, Hq, Sq, D),
@@ -410,7 +405,7 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, res, do):
         ],
         out_specs=pl.BlockSpec((1, G, block_q, D), lambda b, i: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, G, Sq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qr, kr, vr, dor, lser, delta)
 
@@ -440,7 +435,7 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, res, do):
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr, dor, lser, delta)
 

@@ -21,10 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
-
+from .lowering import interpret as _interpret
 
 BLOCK_ROWS = 16
 LANES = 128

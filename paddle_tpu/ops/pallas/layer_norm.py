@@ -15,11 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    # CPU backend (tests / sim meshes) runs kernels in interpreter mode
-    import jax
-    return jax.default_backend() == "cpu"
+from .lowering import interpret as _interpret
 
 BLOCK_ROWS = 256
 

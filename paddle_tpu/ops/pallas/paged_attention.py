@@ -33,14 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import tpu_compiler_params
 from ...obs import ledger as obs_ledger
-
-# jax renamed TPUCompilerParams -> CompilerParams (version-bridged in
-# one place, jax_compat)
-_CompilerParams = tpu_compiler_params()
-
-from .flash_attention import NEG_INF, _interpret
+from .flash_attention import NEG_INF
+from .lowering import interpret as _interpret
 
 
 def _paged_kernel(st_ref, pt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
@@ -154,7 +149,7 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q4.dtype),
         interpret=_interpret(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(jnp.asarray(starts, jnp.int32).reshape(B),
       jnp.asarray(page_tables, jnp.int32),

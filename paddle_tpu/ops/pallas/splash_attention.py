@@ -35,13 +35,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import tpu_compiler_params
-
-# jax renamed TPUCompilerParams -> CompilerParams (version-bridged in
-# one place, jax_compat)
-_CompilerParams = tpu_compiler_params()
-
-from .flash_attention import LN2, LOG2E, NEG_INF, _interpret
+from .flash_attention import LN2, LOG2E, NEG_INF
+from .lowering import interpret as _interpret
 
 # f32-element budget for one (G*block_q, block_k) score/probability buffer
 # (2 MB each); _resolve raises when a grouped config exceeds it
@@ -515,7 +510,7 @@ def _splash_fwd(q, k, v, block_mask, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((bh, G, Sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
     )(jnp.asarray(kv_idx), jnp.asarray(kv_cnt), qr, kr, vr)
     out = out.reshape(B, Hq, Sq, D)
@@ -598,7 +593,7 @@ def _splash_bwd(block_mask, causal, sm_scale, block_q, block_k, window,
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, G, Sq, D), q.dtype),
         interpret=_interpret(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=dq_semantics),
     )(jnp.asarray(kv_idx), jnp.asarray(kv_cnt), qr, kr, vr, dor, lser,
       delta)
@@ -636,7 +631,7 @@ def _splash_bwd(block_mask, causal, sm_scale, block_q, block_k, window,
             jax.ShapeDtypeStruct((bh, Sk, D), v.dtype),
         ],
         interpret=_interpret(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(bm_i32, qr, kr, vr, dor, lser, delta)
 

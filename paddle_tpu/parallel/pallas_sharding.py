@@ -1,11 +1,14 @@
 """Manual sharding wrapper for Pallas attention kernels.
 
-GSPMD cannot partition a Pallas custom call over ANY dimension: left to
-itself it all-gathers the operands around the kernel (measured on a
-2-layer TP=2 x dp=2 Llama step: 36 all-gathers / 27.3 MB per step vs 0 on
-the dense path). Every flash-attention call site therefore routes through
-``shard_map_attention``: heads go manual over the 'model' axis and batch
-over 'data' when divisible, other mesh axes stay with GSPMD.
+A Mosaic custom call cannot be partitioned by GSPMD at all: on a chip,
+lowering one under a mesh of more than one device raises unless EVERY
+mesh axis is manual at the call ("Mosaic kernels cannot be
+automatically partitioned"). Every flash-attention call site therefore
+routes through ``shard_map_attention``: all free mesh axes go manual,
+heads split over the 'model' axis and batch over 'data'; any other axis
+holds replicas. (On the CPU backend the kernels are interpreted into
+ordinary HLO and would partition, which is why only a chip compile —
+tests/test_chip_aot.py — can see a violation of this rule.)
 
 One implementation for the three call-site families (LlamaAttention,
 llama_functional.layer_forward inside the partial-manual pipeline, and
@@ -14,50 +17,57 @@ drift.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 from jax.sharding import PartitionSpec as P
-
-from ..jax_compat import get_context_mesh
-from ..jax_compat import shard_map as _shard_map
 
 # test hook: set True whenever a wrapped (manual) kernel launch is traced
 ENGAGED = {"flag": False}
 
 
+class PallasShardingError(ValueError):
+    """A Pallas kernel sits under a mesh it cannot be split over."""
+
+
 def shard_map_attention(fn, q, k, v, mesh=None, head_axis: str = "model",
                         batch_axis: str = "data"):
     """Run ``fn(q, k, v)`` (layout (B, H, S, D); k/v may carry fewer heads
-    — GQA) with the head dim manual over ``head_axis`` and the batch dim
-    manual over ``batch_axis`` when divisible.
+    — GQA) per device: heads manual over ``head_axis``, batch manual over
+    ``batch_axis``, replicated over every other free axis.
 
-    mesh=None probes the context abstract mesh (pjit/GSPMD traces and
-    nested shard_map regions — only AUTO axes are eligible there); a
-    concrete mesh skips the probe (the train-step factories pass theirs).
-    Falls back to a plain ``fn(q, k, v)`` call whenever manual sharding
-    does not apply.
+    mesh=None takes the context abstract mesh (``jax.sharding.set_mesh``
+    scopes and enclosing shard_map regions — only its AUTO axes are still
+    free there); a concrete mesh is used as given (the train-step
+    factories pass theirs). With no mesh, or a one-device mesh, this is a
+    plain ``fn(q, k, v)``. A head or batch dimension that its >1 axis
+    does not divide raises ``PallasShardingError``.
     """
     if mesh is None:
-        amesh, eligible = get_context_mesh()
-        if head_axis not in eligible:
-            return fn(q, k, v)
-        mesh = amesh
+        mesh = jax.sharding.get_abstract_mesh()
+        free = tuple(mesh.auto_axes)
     else:
-        eligible = mesh.axis_names
-    if (head_axis not in mesh.axis_names
-            or mesh.shape[head_axis] <= 1
-            or q.shape[1] % mesh.shape[head_axis]
-            or k.shape[1] % mesh.shape[head_axis]):
+        free = tuple(mesh.axis_names)
+    if math.prod(mesh.shape[a] for a in free) <= 1:
         return fn(q, k, v)
-    b_ax = batch_axis if (batch_axis in eligible
-                          and mesh.shape.get(batch_axis, 1) > 1
-                          and q.shape[0] % mesh.shape[batch_axis] == 0) \
-        else None
-    spec = P(b_ax, head_axis, None, None)
-    manual = frozenset({head_axis} | ({b_ax} if b_ax else set()))
-    out = _shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
-                     out_specs=spec, check_vma=False,
-                     axis_names=manual)(q, k, v)
+
+    def split(axis, what, *dims):
+        if axis not in free or mesh.shape[axis] <= 1:
+            return None
+        if any(d % mesh.shape[axis] for d in dims):
+            raise PallasShardingError(
+                f"Pallas attention under mesh {dict(mesh.shape)}: axis "
+                f"{axis!r} (size {mesh.shape[axis]}) does not divide the "
+                f"{what} of q{tuple(q.shape)} / k{tuple(k.shape)}; a "
+                "Mosaic kernel cannot be partitioned automatically")
+        return axis
+
+    h_ax = split(head_axis, "head counts", q.shape[1], k.shape[1])
+    b_ax = split(batch_axis, "batch", q.shape[0])
+    spec = P(b_ax, h_ax, None, None)
+    out = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                        out_specs=spec, check_vma=False,
+                        axis_names=frozenset(free))(q, k, v)
     ENGAGED["flag"] = True  # after the call: a tracing failure above must
-    #                         not leave the marker set (call sites may
-    #                         catch and fall back)
+    #                         not leave the marker set
     return out

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map as _shard_map
 
 
 def stack_stage_params(per_stage_params):
@@ -255,7 +254,7 @@ def _launch(spmd, params, xm, mesh, axis, data_axis, auto_axes,
         # incoming param shardings (4D composition in ONE program)
         kw["axis_names"] = frozenset(
             a for a in mesh.axis_names if a not in auto_axes)
-    fn = _shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_spec, check_vma=False, **kw)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False, **kw)
     y = fn(params, xm)
     return y.reshape((B,) + y.shape[2:])

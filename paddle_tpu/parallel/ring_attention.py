@@ -39,7 +39,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..jax_compat import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -403,7 +402,7 @@ def ring_window_attention(q, k, v, mesh: Mesh, window: int,
     else:
         spmd = _dense_window_ring(axis, n, window, sm_scale, Sloc)
     spec = P(b_ax, h_ax, axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(spec,) * 3,
         out_specs=spec, check_vma=False)
@@ -469,7 +468,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sep",
             return (acc / l[..., None]).astype(q.dtype)
 
     spec = P(b_ax, h_ax, axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(spec,) * 3,
         out_specs=spec, check_vma=False)

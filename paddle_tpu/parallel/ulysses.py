@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map as _shard_map
 
 
 def _local_attention(q, k, v, causal: bool, sm_scale: float):
@@ -76,8 +75,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sep",
         return heads_to_seq(oh)        # (B, H, S/P, D)
 
     spec = P(None, None, axis, None)
-    fn = _shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     sh = NamedSharding(mesh, spec)
     with mesh:
         return fn(jax.device_put(q, sh), jax.device_put(k, sh),

@@ -1,0 +1,208 @@
+"""Chip-less compile check: AOT-compile for the TPU v5e topology what
+chip_smoke.py runs, from a host that has no chip.
+
+On the CPU backend every Pallas kernel runs in interpret mode — ordinary
+HLO — so the rest of the suite cannot see a Mosaic refusal (a kernel
+under GSPMD, a VMEM overflow, an unsupported layout). Compiling against
+``get_topology_desc("v5e:2x2")`` inside ``lower_for_chip()`` takes the
+chip branches and runs the real Mosaic + XLA:TPU compilers. Nothing
+executes: this proves "it compiles", not "it is right" — that is
+chip_smoke.py's job on the chip.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import paddle_tpu as paddle
+from paddle_tpu.jax_compat import make_mesh
+from paddle_tpu.models.nlp import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.ops.pallas.lowering import lower_for_chip
+
+pytestmark = pytest.mark.slow
+
+BF16 = jnp.bfloat16
+# chip_smoke.py's shapes: the 0.44B width, the engine's pool geometry
+NH, HD, V, S = 12, 128, 32000, 2048
+SLOTS, PAGE, WIDTH = 8, 64, 16
+POOL = SLOTS * WIDTH + 1
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu on this host
+        pytest.skip(f"no TPU compile-only topology here: {e!r}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **jit_kw):
+    with lower_for_chip():
+        return jax.jit(fn, **jit_kw).lower(*args).compile()
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _full_width_model(kv_heads, layers=2):
+    cfg = LlamaConfig(vocab_size=V, hidden_size=NH * HD,
+                      intermediate_size=4096, num_hidden_layers=layers,
+                      num_attention_heads=NH, num_key_value_heads=kv_heads,
+                      max_position_embeddings=S, dtype=BF16)
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    return model
+
+
+def _on(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, sharding), tree)
+
+
+def test_kernels_compile_at_smoke_shapes(v5e):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.flash_attention_gqa import (
+        grouped_flash_attention)
+    from paddle_tpu.ops.pallas.fused_ce import causal_lm_loss
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    one = SingleDeviceSharding(v5e[0])
+
+    pool = _sds((NH, POOL, PAGE, HD), BF16, one)
+    c = _compile(paged_attention, _sds((SLOTS, NH, HD), BF16, one), pool,
+                 pool, _sds((SLOTS, WIDTH), jnp.int32, one),
+                 _sds((SLOTS,), jnp.int32, one))
+    assert _mosaic_calls(c) == 1
+
+    for fn, kvh in ((flash_attention, NH), (grouped_flash_attention, 4)):
+        def loss(q, k, v, fn=fn):
+            return jnp.sum(fn(q, k, v, True, HD ** -0.5).astype(jnp.float32))
+        q = _sds((2, NH, S, HD), BF16, one)
+        kv = _sds((2, kvh, S, HD), BF16, one)
+        c = _compile(jax.grad(loss, (0, 1, 2)), q, kv, kv)
+        assert _mosaic_calls(c) == 3      # fwd, dq, dk/dv
+
+    c = _compile(jax.grad(causal_lm_loss), _sds((2, S, V), BF16, one),
+                 _sds((2, S), jnp.int32, one))
+    assert _mosaic_calls(c) == 2          # fwd, bwd
+
+
+def test_oversize_resident_flash_block_is_refused(v5e):
+    """The negative control: this probe must be able to fail. Resident
+    (non-streamed) K/V at S=16384 over more than one head does not fit
+    the 16M scoped VMEM, and the chip's own compiler says so."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    one = SingleDeviceSharding(v5e[0])
+    x = _sds((2, NH, 16384, HD), BF16, one)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(lambda q, k, v: flash_attention(
+            q, k, v, True, None, 256, 256, stream=False), x, x, x)
+
+
+def test_train_step_compiles_full_width(v5e):
+    """bench.py's best row (GQA kv=4, bf16 moments, no remat) at full
+    width, two layers deep, B=8 x S=2048."""
+    from paddle_tpu.models.nlp.llama import llama_train_step_factory
+    one = SingleDeviceSharding(v5e[0])
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    with lower_for_chip():
+        params, opt_state, step, _ = llama_train_step_factory(
+            _full_width_model(kv_heads=4), mesh, remat=False,
+            accum_dtype=BF16)
+    tok = _sds((8, S), jnp.int32, one)
+    # the factory's jit is bound to its (CPU) mesh shardings: compile the
+    # same step function for the chip instead
+    c = _compile(step.__wrapped__, _on(params, one), _on(opt_state, one),
+                 tok, tok, donate_argnums=(0, 1))
+    assert _mosaic_calls(c) == 3 * 2 + 2  # flash fwd/dq/dkdv per layer + CE
+
+
+def test_paged_decode_step_compiles_full_width(v5e):
+    from paddle_tpu.models.nlp.llama_decode import llama_paged_decode_factory
+    one = SingleDeviceSharding(v5e[0])
+    outer, layers, pools, _, decode_step, decode_n = \
+        llama_paged_decode_factory(_full_width_model(kv_heads=NH),
+                                   page_size=PAGE, n_pool_pages=POOL,
+                                   chunked_prefill=PAGE)
+    i32 = lambda *s: _sds(s, jnp.int32, one)  # noqa: E731
+    args = (_on(outer, one), _on(layers, one), i32(SLOTS),
+            i32(SLOTS, WIDTH), i32(SLOTS), _on(pools, one))
+    with lower_for_chip():
+        assert _mosaic_calls(decode_step.lower(*args).compile()) >= 1
+        assert _mosaic_calls(decode_n.lower(*args, 8).compile()) >= 1
+
+
+def test_tp_sharded_paged_call_on_four_chips(v5e):
+    """The paged kernel under tp=4: kv heads manual over the tp axis
+    (``paged_kernel_call``); the bare sharded call is what JAX refuses."""
+    from paddle_tpu.models.nlp.llama_decode import paged_kernel_call
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    mesh = Mesh(np.asarray(v5e), ("tp",))
+    ns = lambda *names: NamedSharding(mesh, P(*names))  # noqa: E731
+    pool = _sds((NH, POOL, PAGE, HD), BF16, ns("tp"))
+    args = (_sds((SLOTS, NH, HD), BF16, ns(None, "tp")), pool, pool,
+            _sds((SLOTS, WIDTH), jnp.int32, ns()),
+            _sds((SLOTS,), jnp.int32, ns()))
+    c = _compile(lambda *a: paged_kernel_call(paged_attention, *a,
+                                              mesh=mesh, axis="tp"), *args)
+    assert _mosaic_calls(c) == 1
+    with pytest.raises(NotImplementedError, match="automatically partition"):
+        _compile(paged_attention, *args)
+
+
+@pytest.mark.parametrize("shape,names", [((2, 2), ("data", "model")),
+                                         ((4,), ("data",))])
+def test_multi_device_train_step_lowers_for_tpu(shape, names):
+    """The Mosaic-under-GSPMD refusal is raised while LOWERING for the TPU
+    platform, so a train step on a CPU mesh of the four-chip shapes shows
+    it without a topology: every flash / fused-CE call must sit inside an
+    all-manual shard_map."""
+    from paddle_tpu.models.nlp.llama import llama_train_step_factory
+    cfg = LlamaConfig(vocab_size=512, hidden_size=256, intermediate_size=512,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, max_position_embeddings=512,
+                      dtype=BF16)
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    with lower_for_chip():
+        params, opt_state, step, batch_sh = llama_train_step_factory(
+            model, make_mesh(shape, names), remat=False, accum_dtype=BF16)
+        tok = jax.device_put(np.zeros((4, 256), np.int32), batch_sh)
+        text = step.trace(params, opt_state, tok, tok).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 3 * 2
+
+
+def test_4d_train_step_without_pipe_axis_lowers_for_tpu():
+    """The 4-D factory's no-pipe branch runs its stage body under plain
+    GSPMD: the flash kernel must be handed the mesh explicitly."""
+    from paddle_tpu.models.nlp.llama_functional import (
+        llama_4d_train_step_factory)
+    cfg = LlamaConfig(vocab_size=512, hidden_size=256, intermediate_size=512,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, max_position_embeddings=512,
+                      dtype=BF16)
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    mesh = make_mesh((2, 2), ("data", "model"))
+    with lower_for_chip():
+        params, opt_state, step = llama_4d_train_step_factory(
+            model, mesh, n_microbatches=1, remat=False)
+        tok = jax.device_put(np.zeros((4, 256), np.int32),
+                             NamedSharding(mesh, P("data")))
+        text = step.trace(params, opt_state, tok, tok).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 3

@@ -20,12 +20,13 @@ import sys
 import textwrap
 import time
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 TRAINER = textwrap.dedent("""
     import json
     import os
     import sys
     import time
-    sys.path.insert(0, "/root/repo")
     import jax
     jax.config.update("jax_platforms", "cpu")
 
@@ -63,7 +64,6 @@ PEER = textwrap.dedent("""
     import os
     import sys
     import time
-    sys.path.insert(0, "/root/repo")
     from paddle_tpu.distributed.store import TCPStore
     from paddle_tpu.distributed.fleet.elastic import ElasticManager
 
@@ -86,6 +86,7 @@ def test_scale_up_down_relaunch_resume(tmp_path):
     peer = tmp_path / "peer.py"
     peer.write_text(PEER)
     env = dict(os.environ)
+    env["PYTHONPATH"] = REPO
     env["TEST_OUT_DIR"] = str(tmp_path)
     env["JAX_PLATFORMS"] = "cpu"
     env["PADDLE_AUTO_CHECKPOINT_DIR"] = str(tmp_path / "ckpt")
@@ -96,7 +97,7 @@ def test_scale_up_down_relaunch_resume(tmp_path):
          "--master", f"127.0.0.1:{master_port}",
          "--nproc_per_node", "1", "--elastic_level", "1",
          "--np", "1:2", "--elastic_node_id", "aa-nodeA", str(script)],
-        cwd="/root/repo", env=env, stdout=subprocess.PIPE,
+        cwd=REPO, env=env, stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True)
     try:
         log = tmp_path / "epochs.jsonl"
@@ -113,7 +114,7 @@ def test_scale_up_down_relaunch_resume(tmp_path):
         # relaunch latency varies with load)
         peer_proc = subprocess.Popen(
             [sys.executable, str(peer), str(master_port + 7), "120.0"],
-            cwd="/root/repo", env=env)
+            cwd=REPO, env=env)
         try:
             deadline = time.time() + 90
             while time.time() < deadline:

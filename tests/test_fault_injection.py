@@ -16,11 +16,12 @@ import subprocess
 import sys
 import textwrap
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 TRAINER = textwrap.dedent("""
     import json
     import os
     import sys
-    sys.path.insert(0, "/root/repo")
     import jax
     jax.config.update("jax_platforms", "cpu")
 
@@ -58,6 +59,7 @@ def test_crash_relaunch_resume(tmp_path):
     script = tmp_path / "trainer.py"
     script.write_text(TRAINER)
     env = dict(os.environ)
+    env["PYTHONPATH"] = REPO
     env["TEST_OUT_DIR"] = str(tmp_path)
     env["JAX_PLATFORMS"] = "cpu"
     env["PADDLE_AUTO_CHECKPOINT_DIR"] = str(tmp_path / "ckpt")
@@ -67,7 +69,7 @@ def test_crash_relaunch_resume(tmp_path):
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "1", "--elastic_level", "1",
          "--max_restart", "2", str(script)],
-        cwd="/root/repo", env=env, capture_output=True, text=True,
+        cwd=REPO, env=env, capture_output=True, text=True,
         timeout=300)
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     assert "elastic restart" in proc.stderr

@@ -251,7 +251,6 @@ class TestSdpaUnderMesh:
         from jax.sharding import Mesh
         import paddle_tpu.nn.functional as F
         from paddle_tpu.core.tensor import Tensor
-        from paddle_tpu.jax_compat import set_mesh
 
         from paddle_tpu.parallel import pallas_sharding as PS
         if len(jax.devices()) < 2:
@@ -267,9 +266,7 @@ class TestSdpaUnderMesh:
             return out._value
 
         PS.ENGAGED["flag"] = False
-        # jax_compat.set_mesh: jax.sharding.set_mesh on new jax; a compat
-        # context the pallas-sharding probe reads on 0.4.x images
-        with set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             sharded = jax.jit(run)(jnp.asarray(q))
         assert PS.ENGAGED["flag"], "manual shard_map path did not engage"
         plain = run(jnp.asarray(q))

@@ -20,6 +20,8 @@ import numpy as np
 
 from paddle_tpu.distributed.ps import PSClient, PSServer
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _free_port():
     s = socket.socket()
@@ -32,7 +34,6 @@ def _free_port():
 HETER_WORKER = textwrap.dedent("""
     import json
     import sys
-    sys.path.insert(0, "/root/repo")
     import jax
     jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
@@ -78,7 +79,6 @@ CPU_WORKER = textwrap.dedent("""
     import json
     import sys
     import time
-    sys.path.insert(0, "/root/repo")
     import numpy as np
     from paddle_tpu.distributed.fleet.heter import CpuSection, StageChannel
     from paddle_tpu.distributed.ps import PSClient
@@ -126,15 +126,16 @@ def test_heter_pipeline_three_processes(tmp_path):
     cw = tmp_path / "cpu_worker.py"
     cw.write_text(CPU_WORKER)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = REPO
     env.pop("XLA_FLAGS", None)
     try:
         heter = subprocess.Popen(
             [sys.executable, str(hw), str(stage_port), str(heter_out)],
-            cwd="/root/repo", env=env)
+            cwd=REPO, env=env)
         cpu = subprocess.Popen(
             [sys.executable, str(cw), str(server.port), str(stage_port),
              str(cpu_out)],
-            cwd="/root/repo", env=env)
+            cwd=REPO, env=env)
         assert cpu.wait(timeout=180) == 0
         assert heter.wait(timeout=60) == 0
     finally:

@@ -17,12 +17,13 @@ import textwrap
 
 import pytest
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 TRAINER = textwrap.dedent("""
     import json
     import os
     import sys
-    sys.path.insert(0, "/root/repo")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     rank = int(os.environ["PADDLE_GLOBAL_RANK"])
@@ -54,12 +55,13 @@ def test_launch_two_ranks_rendezvous(tmp_path):
     script = tmp_path / "trainer.py"
     script.write_text(TRAINER)
     env = dict(os.environ)
+    env["PYTHONPATH"] = REPO
     env["TEST_OUT_DIR"] = str(tmp_path)
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "2", str(script)],
-        cwd="/root/repo", env=env, capture_output=True, text=True,
+        cwd=REPO, env=env, capture_output=True, text=True,
         timeout=110)
     assert proc.returncode == 0, proc.stdout + proc.stderr
 

@@ -24,11 +24,12 @@ import textwrap
 
 import numpy as np
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 TRAINER = textwrap.dedent("""
     import json
     import os
     import sys
-    sys.path.insert(0, "/root/repo")
     import jax
     jax.config.update("jax_platforms", "cpu")
 
@@ -76,6 +77,7 @@ TRAINER = textwrap.dedent("""
 
 def _trainer_env(out_dir, n_local_devices):
     env = dict(os.environ)
+    env["PYTHONPATH"] = REPO
     env["TEST_OUT_DIR"] = str(out_dir)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("PADDLE_GLOBAL_RANK", None)
@@ -94,13 +96,13 @@ def _run(tmp_path, nproc):
     env = _trainer_env(out, 8 // nproc)
     if nproc == 1:
         proc = subprocess.run([sys.executable, str(script)],
-                              cwd="/root/repo", env=env,
+                              cwd=REPO, env=env,
                               capture_output=True, text=True, timeout=600)
     else:
         proc = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--nproc_per_node", str(nproc), str(script)],
-            cwd="/root/repo", env=env, capture_output=True, text=True,
+            cwd=REPO, env=env, capture_output=True, text=True,
             timeout=600)
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     losses = []
@@ -151,7 +153,7 @@ def test_two_node_launch_httpmaster_rendezvous(tmp_path):
                  "--master", master, "--nnodes", "2",
                  "--node_rank", str(nr),
                  "--nproc_per_node", "1", str(script)],
-                cwd="/root/repo", env=env, stdout=subprocess.PIPE,
+                cwd=REPO, env=env, stdout=subprocess.PIPE,
                 stderr=subprocess.PIPE, text=True))
             _time.sleep(0.5)  # node 0 binds the HTTP master first
         outs = [p.communicate(timeout=600) for p in pods]

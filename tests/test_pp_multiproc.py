@@ -16,11 +16,12 @@ import textwrap
 
 import numpy as np
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 TRAINER = textwrap.dedent("""
     import json
     import os
     import sys
-    sys.path.insert(0, "/root/repo")
     import jax
     jax.config.update("jax_platforms", "cpu")
 
@@ -94,19 +95,20 @@ def _run(tmp_path, nproc):
     out = tmp_path / f"np{nproc}"
     out.mkdir()
     env = dict(os.environ)
+    env["PYTHONPATH"] = REPO
     env["TEST_OUT_DIR"] = str(out)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("PADDLE_GLOBAL_RANK", None)
     env.pop("PADDLE_WORLD_SIZE", None)
     if nproc == 1:
         proc = subprocess.run([sys.executable, str(script)],
-                              cwd="/root/repo", env=env, capture_output=True,
+                              cwd=REPO, env=env, capture_output=True,
                               text=True, timeout=240)
     else:
         proc = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--nproc_per_node", str(nproc), str(script)],
-            cwd="/root/repo", env=env, capture_output=True, text=True,
+            cwd=REPO, env=env, capture_output=True, text=True,
             timeout=240)
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     losses = {}

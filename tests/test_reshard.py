@@ -23,6 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import paddle_tpu as paddle
 from paddle_tpu.distributed import reshard, reshard_like
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _devs(n):
     return np.asarray(jax.devices()[:n])
@@ -92,7 +94,6 @@ jax.distributed.initialize(coordinator_address=sys.argv[1],
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-sys.path.insert(0, "/root/repo")
 from paddle_tpu.distributed.reshard import reshard
 
 devs = np.asarray(jax.devices())          # 4 per process = 8 global
@@ -123,6 +124,7 @@ def test_cross_process_reshard(tmp_path, free_port):
     script.write_text(_WORKER)
     addr = f"127.0.0.1:{free_port}"
     env = dict(os.environ)
+    env["PYTHONPATH"] = REPO
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env.pop("JAX_PLATFORMS", None)
     procs = [subprocess.Popen(

@@ -42,15 +42,19 @@ VOCAB = 97
 COSTS = {"prefill_unit": 1.0, "decode": 1.0}
 
 
-# --- jax_compat bridge helpers ----------------------------------------------
+# --- jax_compat mesh/sharding helpers ---------------------------------------
 
 def test_jax_compat_mesh_helpers():
     """make_mesh / named_sharding / device_put_sharded on the forced
-    8-device CPU mesh: replication puts a full copy per device,
-    per-leaf specs shard, missing dict keys replicate."""
+    8-device CPU mesh: AUTO axes (jax.make_mesh's Explicit default turns
+    every sharded contraction in the TP programs into a type error),
+    replication puts a full copy per device, per-leaf specs shard,
+    missing dict keys replicate."""
     mesh = make_mesh((2,), ("tp",))
     assert tuple(mesh.axis_names) == ("tp",)
     assert mesh.devices.size == 2
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+    assert TPConfig((2,)).build_mesh().axis_types == mesh.axis_types
     sh = named_sharding(mesh, None, "tp")
     assert sh.mesh.axis_names == mesh.axis_names
     assert tuple(sh.spec) == (None, "tp")

@@ -3,12 +3,10 @@ check_op_benchmark_result.py:1 + ci_model_benchmark.sh:37-60 discipline).
 
 Compares a fresh chip measurement against the commit-stamped last
 recorded row and FAILS (exit 1) on >threshold regression, so a round
-cannot silently ship a slower build. Three modes:
+cannot silently ship a slower build. Modes:
 
   python tools/bench_gate.py check <fresh.json>   # compare a bench.py
       output file (or '-' for stdin) against PERF_LAST_TPU.json
-  python tools/bench_gate.py run                  # run bench.py now,
-      then compare (the first chip-queue item each round)
   python tools/bench_gate.py serving <fresh.jsonl> [--stamp]
   python tools/bench_gate.py obs <fresh.jsonl>
       # gate the OBSERVABILITY rows (tools/serving_workload_bench.py
@@ -156,7 +154,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -171,9 +168,6 @@ def _legacy_mfu(detail: dict, fallback: float) -> float:
 
 
 def load_baseline():
-    """Snapshot PERF_LAST_TPU.json BEFORE running bench.py — the bench
-    itself refreshes that file on a good chip run, so reading it after
-    would compare the fresh row against itself."""
     rec_path = os.path.join(REPO, "PERF_LAST_TPU.json")
     if not os.path.exists(rec_path):
         return None
@@ -190,11 +184,6 @@ def check(fresh: dict, last: dict | None) -> int:
     detail = fresh.get("detail", {})
     fresh_head = float(fresh.get("value", 0.0))
     fresh_legacy = _legacy_mfu(detail, fresh_head)
-    if fresh.get("detail", {}).get("device", "").startswith("TFRT_CPU"):
-        print(json.dumps({"gate": "skip",
-                          "reason": "fresh run fell back to CPU; gate "
-                                    "only judges chip-vs-chip"}))
-        return 0
     ratio = fresh_legacy / last_legacy if last_legacy else 1.0
     rec = {
         "gate": "pass" if ratio >= 1.0 - THRESHOLD else "FAIL",
@@ -2328,14 +2317,14 @@ def check_serving(rows: list, last: dict | None, stamp: bool) -> int:
 
 
 def main() -> int:
-    mode = sys.argv[1] if len(sys.argv) > 1 else "run"
+    mode = sys.argv[1] if len(sys.argv) > 1 else None
     if mode == "check":
         baseline = load_baseline()
         src = sys.argv[2] if len(sys.argv) > 2 else "-"
         text = sys.stdin.read() if src == "-" else open(src).read()
         # bench.py prints one JSON line (possibly after warnings); no
         # JSON line at all is a FAIL record, not a bare IndexError
-        # (round-5 advice #3 — run mode already failed gracefully)
+        # (round-5 advice #3)
         lines = [ln for ln in text.splitlines() if ln.startswith("{")]
         if not lines:
             print(json.dumps({"gate": "FAIL",
@@ -2357,34 +2346,7 @@ def main() -> int:
         src = operands[0] if operands else "-"
         text = sys.stdin.read() if src == "-" else open(src).read()
         return check_obs(_json_lines(text))
-    if mode == "run":
-        baseline = load_baseline()
-        r = subprocess.run([sys.executable,
-                            os.path.join(REPO, "bench.py")],
-                           capture_output=True, text=True, timeout=1800)
-        lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-        if r.returncode != 0 or not lines:
-            print(json.dumps({"gate": "FAIL",
-                              "reason": "bench.py did not produce a row",
-                              "stderr": (r.stderr or "")[-400:]}))
-            return 1
-        rc = check(json.loads(lines[-1]), baseline)
-        if rc != 0 and baseline is not None:
-            # bench.py stamped the REGRESSED row into PERF_LAST_TPU.json;
-            # restore the snapshot so a failing build cannot become the
-            # next run's baseline (self-laundering: fail once, pass
-            # forever after). Accepting an intended slowdown = commit
-            # the new stamp deliberately after reading the FAIL row.
-            rec_path = os.path.join(REPO, "PERF_LAST_TPU.json")
-            tmp = rec_path + ".tmp"
-            with open(tmp, "w") as f:
-                json.dump(baseline, f, indent=2)
-                f.write("\n")
-            os.replace(tmp, rec_path)
-            print(json.dumps({"gate_note":
-                              "restored pre-run baseline stamp"}))
-        return rc
-    raise SystemExit("mode: run | check <file|-> | "
+    raise SystemExit("mode: check <file|-> | "
                      "serving <file|-> [--stamp] | obs <file|->")
 
 

@@ -10,7 +10,7 @@ and prints one JSON line per row plus a summary.
 Measured rows are inlined from PERF.md records (commit-stamped there);
 re-run after fresh chip sessions to keep the table honest.
 
-Run: PYTHONPATH=/root/repo python tools/cost_validate.py
+Run: python tools/cost_validate.py
 """
 from __future__ import annotations
 

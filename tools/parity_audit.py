@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import ast
 import importlib
+import os
 import sys
 from pathlib import Path
 
@@ -85,7 +86,8 @@ def ref_all(rel_paths):
 
 
 def main():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     total_missing = 0
     report = []
     for ns, (rels, ours_path) in NAMESPACES.items():

@@ -15,7 +15,7 @@ so the projection and the planner cannot drift apart.
              + t_dp + t_p2p
 
 Prints one JSON line; cites which measurement fed measured_eff.
-Run: PYTHONPATH=/root/repo python tools/pod_projection.py
+Run: python tools/pod_projection.py
 """
 from __future__ import annotations
 

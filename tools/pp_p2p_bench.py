@@ -6,7 +6,7 @@ traffic shape) over (a) the direct-socket P2PCommunicator and (b) a
 minimal TCPStore-KV relay identical to the round-3 transport. Prints
 MB/s for both — the VERDICT r3 item-6 'measured MB/s' artifact.
 
-Run: PYTHONPATH=/root/repo python tools/pp_p2p_bench.py
+Run: python tools/pp_p2p_bench.py
 """
 from __future__ import annotations
 
@@ -18,7 +18,8 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 MB = 1 << 20
 SIZES = [(4 * MB, 16), (64 * MB, 4)]  # (bytes per tensor, reps)
