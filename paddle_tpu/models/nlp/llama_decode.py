@@ -18,6 +18,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from ...jax_compat import device_put_sharded, make_mesh
@@ -2054,12 +2055,20 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
             raise ValueError(f"resume_from {resume_from} must be a "
                              f"chunk multiple ({C})")
         resume = min(resume_from, T - C)
-        x_last = jnp.zeros((B, cfg.hidden_size), dtype)
+        # the shim's three host steps, named in the profiler's trace
+        # (free while no session records): each is a dispatch of its
+        # own that the device may sit out
+        with TraceAnnotation("factory:prefill.slice"):
+            x_last = jnp.zeros((B, cfg.hidden_size), dtype)
         for s in range(resume, T, C):  # static count; ONE compiled fn
-            x_last, pools = _prefill_chunk(
-                outer, layers, tokens[:, s:s + C], s, page_tables,
-                lengths, pools, x_last, lora)
-        return _finish_prefill(outer, x_last, grammar), pools
+            with TraceAnnotation("factory:prefill.slice"):
+                chunk = tokens[:, s:s + C]
+            with TraceAnnotation("factory:prefill.chunk"):
+                x_last, pools = _prefill_chunk(
+                    outer, layers, chunk, s, page_tables, lengths,
+                    pools, x_last, lora)
+        with TraceAnnotation("factory:prefill.finish"):
+            return _finish_prefill(outer, x_last, grammar), pools
 
     # the shim itself is plain python; expose the jitted programs it
     # drives so the serving engine's recompile detector (obs layer:

@@ -10,7 +10,10 @@ Two halves, both dependency-free and import-light (no jax):
   request that caused them. ``ServingEngine(trace=...)`` threads one
   through the serving lifecycle; ``tools/trace_report.py`` summarizes
   the export (per-request waterfall, top recompiles, shed timeline,
-  slot occupancy).
+  slot occupancy). ``HostPhases`` is its wall-clock half: the
+  engine's host phases and each device call's seam/dispatch/wait
+  split, summed into ``ServeResult.overhead`` and written into the
+  jax profiler's trace as ``engine:<span>`` annotations.
 - ``obs.metrics``: counters / gauges / fixed-bucket histograms with
   Prometheus text exposition (``REGISTRY.expose_text()``) and JSONL
   snapshots (``REGISTRY.write_jsonl(path)``). Counters stay live even
@@ -59,5 +62,5 @@ from .metrics import (REGISTRY, Counter, Gauge,  # noqa: F401
 from .slo import (BurnRateRule, HeartbeatRule,  # noqa: F401
                   Incident, IncidentLog, SLOMonitor, ThresholdRule,
                   default_serving_rules, load_incidents)
-from .trace import (Tracer, activate, active,  # noqa: F401
+from .trace import (HostPhases, Tracer, activate, active,  # noqa: F401
                     deactivate, get_trace_id, trace_scope, use)

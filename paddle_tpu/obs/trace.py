@@ -75,7 +75,6 @@ class Tracer:
         self._clock = clock or time.perf_counter
         self._events: List[dict] = []
         self._tracks: Dict[str, int] = {}
-        self._mirror_profiler = True
         # the mirror seam: an optional per-event sink (the incident
         # flight recorder's bounded ring) fed alongside the event
         # list — one is-None check per recorded event, nothing when
@@ -123,8 +122,6 @@ class Tracer:
                     "dur": max(dur, 0.0),
                     "tid": self.track(track),
                     "args": self._args(attrs)})
-        if self._mirror_profiler:
-            self._to_profiler(name, t0, dur)
 
     @contextmanager
     def span(self, name: str, track: str = "main", **attrs):
@@ -168,17 +165,6 @@ class Tracer:
                     "tid": self.track(track),
                     "args": self._args(attrs)})
 
-    def _to_profiler(self, name, t0, dur):
-        # feed the profiler's span store while a Profiler is recording
-        # (its `enabled` flag); import lazily — profiler pulls in jax
-        try:
-            import sys
-            prof = sys.modules.get("paddle_tpu.profiler")
-            if prof is not None and prof._spans.enabled:
-                prof._spans.add(name, t0, dur, self.track("main"))
-        except Exception:
-            pass
-
     # --- introspection / export -------------------------------------------
     def __len__(self) -> int:
         return len(self._events)
@@ -220,6 +206,151 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(pid, process_name), f)
         return path
+
+
+# --- host phases on the wall clock ---------------------------------------
+class _OpenSpan:
+    """A span while it is open; its own context manager. On exit it
+    files one plain tuple with ``HostPhases`` (tuples of numbers and
+    strings cost the collector nothing, objects that point at each
+    other would)."""
+    __slots__ = ("name", "rid", "id", "t0", "t1", "parent", "turn",
+                 "child_s", "_hp", "_ann")
+
+    def __init__(self, hp: "HostPhases", name: str, rid):
+        self.name = name
+        self.rid = rid
+        self.child_s = 0.0
+        self._hp = hp
+        self._ann = None
+
+    def __enter__(self):
+        hp = self._hp
+        self.parent = hp._open
+        hp._open = self
+        if self.name == "turn":
+            hp.turns += 1
+        self.turn = hp.turns
+        self.id = hp._next_id
+        hp._next_id += 1
+        if hp._recording():     # a profiler session records
+            self._ann = hp._annotation("engine:" + self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        hp = self._hp
+        parent = hp._open = self.parent
+        if parent is not None:
+            parent.child_s += t1 - self.t0
+        if hp.keep:
+            hp.spans.append((self.name, self.t0, t1, self.id,
+                             None if parent is None else parent.id,
+                             self.turn, self.rid, self.child_s))
+        return False
+
+
+class HostPhases:
+    """The host spans of one engine run, in memory until the run ends.
+
+    ``span(name, rid)`` opens a child of whatever span is open; a span
+    named ``turn`` starts the next turn. A closed span is the tuple
+    ``(name, t0, t1, id, parent id, turn, rid, child_s)``: times on
+    ``time.perf_counter``, ``parent id`` None for a root, ``child_s``
+    the seconds its children cover (self time is ``t1 - t0 -
+    child_s``). ``call(...)`` files one device call's four stamps so
+    that ``summary`` can split it into seam, dispatch and wait.
+    ``keep=False`` (fixed-clock runs, whose length is unbounded and
+    whose ``overhead`` is None) times and annotates spans and stores
+    none."""
+
+    FIELDS = ("name", "t0", "t1", "id", "parent", "turn", "rid",
+              "child_s")
+
+    def __init__(self, keep: bool = True):
+        from jax.profiler import TraceAnnotation  # obs imports no jax
+        self._annotation = TraceAnnotation
+        self._recording = TraceAnnotation.is_enabled
+        self.keep = keep
+        self.spans: List[tuple] = []    # closed spans, children first
+        self.calls: List[tuple] = []    # (kind, rows, w0, t_in, t_enq, w1)
+        self.turns = 0
+        self._next_id = 0
+        self._open: Optional[_OpenSpan] = None
+
+    def span(self, name: str, rid=None) -> _OpenSpan:
+        return _OpenSpan(self, name, rid)
+
+    def call(self, kind: str, rows: int, call: _OpenSpan,
+             dispatch: _OpenSpan):
+        """File a closed ``call.<kind>`` span and its ``dispatch``
+        child: the clock's own code ran from ``call.t0`` to
+        ``dispatch.t0`` (the seam), the wrapped function to
+        ``dispatch.t1`` (uploads and enqueue), the wait for the
+        result to ``call.t1``."""
+        if self.keep:
+            self.calls.append((kind, rows, call.t0, dispatch.t0,
+                               dispatch.t1, call.t1))
+
+    def records(self) -> List[dict]:
+        """The closed spans as dicts keyed by ``FIELDS``."""
+        return [dict(zip(self.FIELDS, s)) for s in self.spans]
+
+    def summary(self, t_zero: float) -> dict:
+        """The run's accounting: ``phases`` (self seconds by name, the
+        ``turn`` roots and the calls left out), ``calls`` (per kind,
+        one entry a call: start since ``t_zero``, then the seam's, the
+        dispatch's and the wait's seconds), ``idle_wait_s``,
+        ``unaccounted_s`` (the turns' self time) and ``root_s`` (the
+        seconds under root spans). They conserve: phases + calls +
+        unaccounted == root_s."""
+        phases: Dict[str, dict] = {}
+        unaccounted = root_s = 0.0
+        for name, t0, t1, _, parent, _, _, child_s in self.spans:
+            self_s = t1 - t0 - child_s
+            if parent is None:
+                root_s += t1 - t0
+            if name == "turn":
+                unaccounted += self_s
+            elif not name.startswith(("call.", "dispatch.")):
+                row = phases.setdefault(
+                    name, {"n": 0, "self_s": 0.0, "max_s": 0.0})
+                row["n"] += 1
+                row["self_s"] += self_s
+                row["max_s"] = max(row["max_s"], self_s)
+        calls: Dict[str, dict] = {}
+        for kind, rows, w0, t_in, t_enq, w1 in self.calls:
+            row = calls.setdefault(
+                kind, {"n": 0, "rows": 0, "start_s": [], "seam_s": [],
+                       "dispatch_s": [], "wait_s": []})
+            row["n"] += 1
+            row["rows"] += rows
+            row["start_s"].append(w0 - t_zero)
+            row["seam_s"].append(t_in - w0)
+            row["dispatch_s"].append(t_enq - t_in)
+            row["wait_s"].append(w1 - t_enq)
+        idle = phases.get("idle_wait")
+        return {"turns": self.turns, "phases": phases, "calls": calls,
+                "idle_wait_s": 0.0 if idle is None else idle["self_s"],
+                "unaccounted_s": unaccounted, "root_s": root_s}
+
+    def to_tracer(self, tracer: "Tracer", t_zero: float):
+        """The spans as complete events on the ``engine.host`` track
+        of a tracer whose clock is wall seconds since ``t_zero``."""
+        names = {s[3]: s[0] for s in self.spans}
+        for name, t0, t1, _, parent, turn, rid, _ in sorted(
+                self.spans, key=lambda s: s[1]):
+            attrs = {"turn": turn}
+            if parent is not None:
+                attrs["parent"] = names[parent]
+            if rid is not None:
+                attrs["rid"] = rid
+            tracer.add_span(name, t0 - t_zero, t1 - t0,
+                            track="engine.host", **attrs)
 
 
 # --- the process-global active tracer -----------------------------------

@@ -34,7 +34,15 @@ compute show up honestly without sleeping through arrival gaps) or by
 fixed per-action costs (``clock="fixed"``, the deterministic test mode:
 same trace -> same completion order, timestamps, slot occupancy).
 Replay a trace twice with the same engine to exclude compile time: the
-first pass warms every program shape.
+first pass warms every program shape. ``clock="wall"`` is real time
+instead (``now()`` reads ``time.perf_counter``, an idle engine sleeps
+until the next arrival): what a live server runs on.
+
+Whatever the clock, every stage of a turn is a host span on
+``time.perf_counter`` (``_phase``; ``obs.trace.HostPhases``) and every
+device call is split into seam, dispatch and wait (``_timed``);
+``ServeResult.overhead`` sums them, and a profiler session sees them
+as ``engine:<span>`` annotations on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -72,25 +80,38 @@ from .workload import Request, iter_jsonl_tolerant
 
 
 class EngineClock:
-    """Virtual time. ``measured``: each timed action adds its wall
-    duration (block_until_ready'd). ``fixed``: each action adds
-    ``costs[kind]`` (default 1.0) — fully deterministic."""
+    """The engine's time. ``measured`` (virtual): each timed action
+    adds its wall duration (block_until_ready'd). ``fixed`` (virtual):
+    each action adds ``costs[kind]`` (default 1.0) — fully
+    deterministic. ``wall``: ``now()`` reads ``time.perf_counter``
+    since the clock was made and ``advance_to`` sleeps until then —
+    what a live server runs on, host time between calls included."""
 
     def __init__(self, mode: str = "measured", costs: dict | None = None):
-        if mode not in ("measured", "fixed"):
-            raise ValueError(f"clock {mode!r}: use 'measured' or 'fixed'")
+        if mode not in ("measured", "fixed", "wall"):
+            raise ValueError(
+                f"clock {mode!r}: use 'measured', 'fixed' or 'wall'")
         self.mode = mode
         self.costs = costs or {}
         self.t = 0.0
-        # measured mode: cumulative wall seconds spent inside timed
-        # actions (the run's device-dispatch time, read by the engine's
-        # host-overhead decomposition); fixed mode never touches it
+        self.t_zero = time.perf_counter()   # wall mode's origin
+        # measured and wall modes: cumulative wall seconds spent inside
+        # timed actions (the run's device-dispatch time, read by the
+        # engine's host-overhead decomposition); fixed mode never
+        # touches it
         self.dev_wall = 0.0
 
     def now(self) -> float:
+        if self.mode == "wall":
+            return time.perf_counter() - self.t_zero
         return self.t
 
     def advance_to(self, t: float):
+        if self.mode == "wall":
+            wait = t - self.now()
+            if wait > 0:
+                time.sleep(wait)
+            return
         self.t = max(self.t, t)
 
     def timed(self, kind: str, fn, units: Optional[int] = None,
@@ -353,11 +374,14 @@ class ServeResult:
     # actuation flip log + pages compacted) when the engine carried
     # kv_quant=; None otherwise — the result shape every pre-quant
     # consumer sees is unchanged
-    overhead: Optional[Dict] = None  # measured-clock runs only: the
-    # host-overhead decomposition {run_wall_s, device_wall_s,
-    # engine_host_frac} — the fraction of run wall time NOT covered by
-    # in-flight device work (dispatch-ahead shrinks it). None on fixed
-    # clocks and sessions; never serialized by save_log, so logs stay
+    overhead: Optional[Dict] = None  # measured and wall clocks only:
+    # the run's wall-clock accounting (``_overhead_row``):
+    # {run_wall_s, device_wall_s, engine_host_frac} — the fraction of
+    # run wall time NOT covered by in-flight device work
+    # (dispatch-ahead shrinks it) — plus the host phases' self
+    # seconds and every device call's seam/dispatch/wait split
+    # (turns, slots, phases, calls, idle_wait_s, unaccounted_s). None
+    # on fixed clocks; never serialized by save_log, so logs stay
     # byte-identical either way
     hostmem_stats: Optional[Dict] = None  # the host-DRAM arena tier's
     # per-run evidence (arena census + transfer counts, preempt/restore
@@ -1142,9 +1166,17 @@ class ServingEngine:
         self.n_pool_pages = n_pool_pages
         self.W = max_len // page_size  # fixed page-table width
         self.chunk_C = serving.chunked_prefill_
-        if clock not in ("measured", "fixed"):
-            raise ValueError(f"clock {clock!r}: use 'measured' or "
-                             "'fixed'")
+        # ``clock``: "measured" | "fixed" (virtual time), "wall"
+        # (real time: what a live server runs on), or an EngineClock
+        # instance, which every run and session of this engine then
+        # uses as it is
+        self._clock_given = clock if isinstance(clock, EngineClock) \
+            else None
+        if self._clock_given is not None:
+            clock = clock.mode
+        elif clock not in ("measured", "fixed", "wall"):
+            raise ValueError(f"clock {clock!r}: use 'measured', "
+                             "'fixed', 'wall' or an EngineClock")
         self.policy = make_policy(policy)
         # scheduler=None is the FIFO default and replays PR-2 traces
         # BYTE-IDENTICALLY (the determinism promise above); "qos" or a
@@ -1384,7 +1416,17 @@ class ServingEngine:
                 and not isinstance(ledger, obs_ledger.CostLedger):
             raise ValueError("ledger= takes None, True or an "
                              "obs.ledger.CostLedger")
+        if ledger is not None and (clock == "wall"
+                                   or self._clock_given is not None):
+            # the ledger's audit is attributed + idle == elapsed on a
+            # clock it books itself; wall time between calls is
+            # neither
+            raise ValueError("ledger= needs clock='measured' or "
+                             "'fixed'")
         self._ledger = ledger
+        # the host spans of the run (or session turn) in progress;
+        # run() and EngineSession put their own here
+        self._phases = obs_trace.HostPhases(keep=False)
         self.eos_token_id = eos_token_id
         self._expect_churn = expect_churn
         self._dense = dense_parts
@@ -1742,11 +1784,17 @@ class ServingEngine:
             t.clear()   # each run() is one trace
         else:
             t = obs_trace.Tracer()
-        t.set_clock(clock.now)  # spans live in VIRTUAL time
+        t.set_clock(clock.now)  # spans live in the CLOCK's time
         return t
 
-    def _close_trace(self, tr: Optional[obs_trace.Tracer]):
-        if tr is not None and isinstance(self._trace_spec, str):
+    def _close_trace(self, tr: Optional[obs_trace.Tracer], clock):
+        """Run end: the host spans join the trace where they share its
+        time base (a wall clock alone), then a path spec exports."""
+        if tr is None:
+            return
+        if clock.mode == "wall":
+            self._phases.to_tracer(tr, clock.t_zero)
+        if isinstance(self._trace_spec, str):
             tr.export(self._trace_spec)
 
     def _make_monitor(self, fresh: bool = True) \
@@ -1991,10 +2039,12 @@ class ServingEngine:
         return [i for i in mon.log.incidents if i.source == mon.source]
 
     def _make_clock(self, label: str = "engine") -> EngineClock:
-        """This run's virtual clock: plain (byte-identical) without a
-        ledger, ledger-booking with one — ``label`` names the
-        per-engine conservation book (the replica name in cluster
-        runs)."""
+        """This run's clock: the instance the constructor was given,
+        else a new one — plain (byte-identical) without a ledger,
+        ledger-booking with one; ``label`` names the per-engine
+        conservation book (the replica name in cluster runs)."""
+        if self._clock_given is not None:
+            return self._clock_given
         if self._ledger is None:
             return EngineClock(self.clock_mode, self.fixed_costs)
         return _LedgerClock(self.clock_mode, self.fixed_costs,
@@ -2062,6 +2112,13 @@ class ServingEngine:
         compiled — the ``jit.compile`` instant names the site and the
         wall cost, the counter feeds the metrics registry.
 
+        Always: a ``call.<kind>`` host span around the clock's
+        ``timed`` and a ``dispatch.<kind>`` child around ``fn``
+        itself, which split the call's wall time, whatever clock is in
+        the seam, into the clock's own code before ``fn`` (the seam),
+        ``fn`` (argument uploads and enqueue) and the wait for its
+        result.
+
         ``rids`` (batched dispatches) is the cost ledger's attribution
         vector: the charge splits pro-rata across the rows — by the
         per-row ``cost`` list when the call priced one (the ragged
@@ -2074,43 +2131,81 @@ class ServingEngine:
         if setter is not None:
             setter(rid, rids,
                    cost if isinstance(cost, (list, tuple)) else None)
-        if tr is None:
-            # no trace: recompile COUNTING stays live (the obs
-            # contract — counters record when nobody traces) unless
-            # the registry kill-switch is down (the no-obs arm);
-            # detection is two cache-size reads around the call
-            if jitfn is None or not obs_metrics.REGISTRY.enabled:
-                return clock.timed(kind, fn, units, cost)
-            c0 = _jit_cache_size(jitfn)
-            out = clock.timed(kind, fn, units, cost)
-            if c0 is not None:
-                c1 = _jit_cache_size(jitfn)
-                if c1 is not None and c1 > c0:
-                    self._ctr_compiles.inc()
-            return out
+        hp = self._phases
+        dispatch = hp.span("dispatch." + kind, rid)
+
+        def enqueue():
+            # the wrapped call alone: argument uploads and enqueue.
+            # What the clock does before it is the seam, what it
+            # waits for after it the wait
+            with dispatch:
+                return fn()
+        # recompile COUNTING stays live when nobody traces (the obs
+        # contract) unless the registry kill-switch is down (the
+        # no-obs arm); detection is two cache-size reads around the
+        # call
+        c0 = _jit_cache_size(jitfn) if jitfn is not None and (
+            tr is not None or obs_metrics.REGISTRY.enabled) else None
         t0 = clock.now()
-        w0 = time.perf_counter()
-        c0 = _jit_cache_size(jitfn) if jitfn is not None else None
-        scope = obs_trace.trace_scope(rid) if rid is not None else None
-        if scope is not None:
-            with scope:
-                out = clock.timed(kind, fn, units, cost)
-        else:
-            out = clock.timed(kind, fn, units, cost)
-        wall = time.perf_counter() - w0
+        with hp.span("call." + kind, rid) as call:
+            if tr is not None and rid is not None:
+                with obs_trace.trace_scope(rid):
+                    out = clock.timed(kind, enqueue, units, cost)
+            else:
+                out = clock.timed(kind, enqueue, units, cost)
+        hp.call(kind, len(rids) if rids else 1, call, dispatch)
+        compiled = False
+        if c0 is not None:
+            c1 = _jit_cache_size(jitfn)
+            compiled = c1 is not None and c1 > c0
+            if compiled:
+                self._ctr_compiles.inc()
+        if tr is None:
+            return out
+        wall = call.t1 - call.t0
         if rid is not None:
             attrs["rid"] = rid
         tr.add_span(kind, t0, clock.now() - t0, track="engine",
                     wall_s=round(wall, 6), **attrs)
-        if c0 is not None:
-            c1 = _jit_cache_size(jitfn)
-            if c1 is not None and c1 > c0:
-                self._ctr_compiles.inc()
-                inst = {"site": kind, "wall_s": round(wall, 6)}
-                if rid is not None:
-                    inst["rid"] = rid
-                tr.instant("jit.compile", t=t0, track="jit", **inst)
+        if compiled:
+            inst = {"site": kind, "wall_s": round(wall, 6)}
+            if rid is not None:
+                inst["rid"] = rid
+            tr.instant("jit.compile", t=t0, track="jit", **inst)
         return out
+
+    def _phase(self, name: str, rid=None):
+        """A host span of the turn in progress (``obs.trace.
+        HostPhases``): timed on ``time.perf_counter`` under any
+        clock, and an ``engine:<name>`` annotation in the profiler's
+        trace while a session records."""
+        return self._phases.span(name, rid)
+
+    def _idle_wait(self, clock, t: float):
+        """Nothing can progress before ``t``: a virtual clock jumps
+        there, a wall clock sleeps."""
+        with self._phase("idle_wait"):
+            clock.advance_to(t)
+
+    def _turn_tail(self, book, m, clock, tr, qst, acache, gcache,
+                   census: Tuple[bool, bool, bool]):
+        """What ends every turn: the pressure tier's turn, the three
+        pool censuses (ANDed into ``census``: pages, adapter slots,
+        grammar slots) and the ledger's occupancy sample."""
+        with self._phase("tail"):
+            self._quant_turn(book, m, clock, tr, qst)
+            inv_ok, a_inv, g_inv = census
+            inv_ok &= book.census_ok()
+            if acache is not None:
+                a_inv &= acache.census_ok()
+            if gcache is not None:
+                g_inv &= gcache.census_ok()
+            if self._ledger is not None:
+                self._ledger.sample_occupancy(
+                    clock.label, book=book, acache=acache,
+                    gcache=gcache,
+                    arena=getattr(book, "_arena", None))
+        return inv_ok, a_inv, g_inv
 
     # --- helpers ----------------------------------------------------------
     def _pad_len(self, n: int) -> int:
@@ -2217,7 +2312,7 @@ class ServingEngine:
         spst = self._make_spec_state()
         qst = self._make_quant_state()
         ahst = self._make_ahead_state()
-        run_w0 = time.perf_counter()
+        run_w0 = self._open_phases(clock)
         pages_total = len(book._free)
         pending = deque(sorted(trace, key=lambda r: (r.arrival, r.rid)))
         waiting: List[Request] = []
@@ -2244,120 +2339,118 @@ class ServingEngine:
             obs_trace.activate(tr)
         try:
             while pending or waiting or active or lane:
-                now = clock.now()
-                while pending and pending[0].arrival <= now + 1e-12:
-                    r = pending.popleft()
-                    waiting.append(r)
-                    # QoS fields ride along so a FIFO baseline run on a
-                    # QoS trace still reports deadline attainment/goodput;
-                    # on a plain trace they are all None and the metrics
-                    # record stays byte-identical to PR 2
-                    m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
-                                 priority=r.priority,
-                                 deadline_ms=r.deadline_ms)
-                    self._ctr_arrived.inc()
-                    self._req_open(tr, r)
-                m.on_queue_depth(now, len(waiting))
-                if tr is not None:
-                    tr.counter("queue_depth", len(waiting), t=now)
+                with self._phase("turn"):
+                    with self._phase("intake"):
+                        now = clock.now()
+                        while pending and pending[0].arrival <= now + 1e-12:
+                            r = pending.popleft()
+                            waiting.append(r)
+                            # QoS fields ride along so a FIFO baseline
+                            # run on a QoS trace still reports deadline
+                            # attainment/goodput; on a plain trace they
+                            # are all None and the metrics record stays
+                            # byte-identical to PR 2
+                            m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
+                                         priority=r.priority,
+                                         deadline_ms=r.deadline_ms)
+                            self._ctr_arrived.inc()
+                            self._req_open(tr, r)
+                        m.on_queue_depth(now, len(waiting))
+                        if tr is not None:
+                            tr.counter("queue_depth", len(waiting), t=now)
 
-                progressed = False
-                if waiting and self._admission_ready(waiting, pending,
-                                                     active, clock):
-                    wave = waiting[:self.admission.max_batch]
-                    groups = [r.prefix_group for r in wave
-                              if r.prefix_group is not None]
-                    shared = (len(groups) != len(set(groups))
-                              or any(g in seen_groups for g in groups))
-                    ctx = dict(ctx_base, shared_prefix=shared,
-                               active_paged=len(active)
-                               + (len(lane) if lane else 0))
-                    backend, reason = self.policy.route(wave, ctx)
-                    decision = {
-                        "t": round(clock.now(), 6), "wave": len(wave),
-                        "prompt_lens": [len(r.prompt) for r in wave],
-                        "backend": backend, "rule": reason}
-                    if backend == "dense":
-                        decisions.append(decision)
-                        self._wave_instant(tr, decision)
-                        del waiting[:len(wave)]
-                        seen_groups.update(g for g in groups)
-                        self._run_dense_wave(wave, clock, m, outputs,
-                                             tr=tr)
+                    progressed = False
+                    with self._phase("admit"):
+                        if waiting and self._admission_ready(waiting, pending,
+                                                             active, clock):
+                            wave = waiting[:self.admission.max_batch]
+                            groups = [r.prefix_group for r in wave
+                                      if r.prefix_group is not None]
+                            shared = (len(groups) != len(set(groups))
+                                      or any(g in seen_groups for g in groups))
+                            ctx = dict(ctx_base, shared_prefix=shared,
+                                       active_paged=len(active)
+                                       + (len(lane) if lane else 0))
+                            backend, reason = self.policy.route(wave, ctx)
+                            decision = {
+                                "t": round(clock.now(), 6), "wave": len(wave),
+                                "prompt_lens": [len(r.prompt) for r in wave],
+                                "backend": backend, "rule": reason}
+                            if backend == "dense":
+                                decisions.append(decision)
+                                self._wave_instant(tr, decision)
+                                del waiting[:len(wave)]
+                                seen_groups.update(g for g in groups)
+                                self._run_dense_wave(wave, clock, m, outputs,
+                                                     tr=tr)
+                                progressed = True
+                            else:
+                                # only the paged ADMISSION order is cache-
+                                # reordered (routing and the decision log keep
+                                # arrival order)
+                                wave = self._order_wave(wave)
+                                n_adm, _, ptoks = self._admit_paged(
+                                    wave, book, clock, m, active, free_slots,
+                                    slot_log, prefix_cached, seen_groups,
+                                    outputs, tr=tr, lane=lane, acache=acache,
+                                    spst=spst, hst=hst, gcache=gcache)
+                                prefill_tokens += ptoks
+                                for r in wave[:n_adm]:  # maybe reordered:
+                                    waiting.remove(r)   # remove by identity
+                                progressed = n_adm > 0
+                                if n_adm:
+                                    # a BLOCKED wave (no slots/pages yet)
+                                    # is not a decision — it will re-route
+                                    # once something frees; logging every
+                                    # retry turn would inflate the per-wave
+                                    # statistics the bench reports
+                                    decision["admitted"] = n_adm
+                                    # prompt_lens above is ARRIVAL order;
+                                    # the cache reorder means the first-n
+                                    # slice no longer names the admitted
+                                    # set — the rids do
+                                    decision["admit_rids"] = \
+                                        [r.rid for r in wave[:n_adm]]
+                                    decisions.append(decision)
+                                    self._wave_instant(tr, decision)
+                                elif not active and not lane:
+                                    raise RuntimeError(
+                                        f"pool/slot config too small for "
+                                        f"{wave[0].rid} (free pages "
+                                        f"{len(book._free)}, free slots "
+                                        f"{len(free_slots)})")
+
+                    if active:
+                        self._paged_chunk(book, clock, m, active, free_slots,
+                                          slot_log, outputs, tr=tr,
+                                          acache=acache, spst=spst,
+                                          ahst=ahst, gcache=gcache)
                         progressed = True
-                    else:
-                        # only the paged ADMISSION order is cache-
-                        # reordered (routing and the decision log keep
-                        # arrival order)
-                        wave = self._order_wave(wave)
-                        n_adm, _, ptoks = self._admit_paged(
-                            wave, book, clock, m, active, free_slots,
-                            slot_log, prefix_cached, seen_groups,
-                            outputs, tr=tr, lane=lane, acache=acache,
-                            spst=spst, hst=hst, gcache=gcache)
+
+                    if lane:
+                        # the async lane: decode ran FIRST — pending
+                        # prefill gets at most prefill_chunk_budget chunks
+                        # of this turn, so TPOT is independent of how much
+                        # prefill is queued
+                        _, ptoks = self._lane_step(
+                            lane, book, clock, m, active, free_slots,
+                            slot_log, outputs, prefix_cached, seen_groups,
+                            tr=tr, acache=acache, spst=spst,
+                            gcache=gcache)
                         prefill_tokens += ptoks
-                        for r in wave[:n_adm]:  # possibly reordered —
-                            waiting.remove(r)   # remove by identity
-                        progressed = n_adm > 0
-                        if n_adm:
-                            # a BLOCKED wave (no slots/pages yet) is not a
-                            # decision — it will re-route once something
-                            # frees; logging every retry turn would inflate
-                            # the per-wave statistics the bench reports
-                            decision["admitted"] = n_adm
-                            # prompt_lens above is ARRIVAL order; the
-                            # cache reorder means the first-n slice no
-                            # longer names the admitted set — the rids do
-                            decision["admit_rids"] = \
-                                [r.rid for r in wave[:n_adm]]
-                            decisions.append(decision)
-                            self._wave_instant(tr, decision)
-                        elif not active and not lane:
-                            raise RuntimeError(
-                                f"pool/slot config too small for "
-                                f"{wave[0].rid} (free pages "
-                                f"{len(book._free)}, free slots "
-                                f"{len(free_slots)})")
+                        progressed = True
 
-                if active:
-                    self._paged_chunk(book, clock, m, active, free_slots,
-                                      slot_log, outputs, tr=tr,
-                                      acache=acache, spst=spst,
-                                      ahst=ahst, gcache=gcache)
-                    progressed = True
-
-                if lane:
-                    # the async lane: decode ran FIRST — pending
-                    # prefill gets at most prefill_chunk_budget chunks
-                    # of this turn, so TPOT is independent of how much
-                    # prefill is queued
-                    _, ptoks = self._lane_step(
-                        lane, book, clock, m, active, free_slots,
-                        slot_log, outputs, prefix_cached, seen_groups,
-                        tr=tr, acache=acache, spst=spst,
-                        gcache=gcache)
-                    prefill_tokens += ptoks
-                    progressed = True
-
-                if not progressed and not active:
-                    targets = []
-                    if pending:
-                        targets.append(pending[0].arrival)
-                    if waiting:
-                        targets.append(waiting[0].arrival
-                                       + self.admission.max_delay)
-                    clock.advance_to(min(targets))
-                self._quant_turn(book, m, clock, tr, qst)
-                inv_ok &= book.census_ok()
-                if acache is not None:
-                    a_inv &= acache.census_ok()
-                if gcache is not None:
-                    g_inv &= gcache.census_ok()
-                if self._ledger is not None:
-                    self._ledger.sample_occupancy(
-                        clock.label, book=book, acache=acache,
-                        gcache=gcache,
-                        arena=getattr(book, "_arena", None))
+                    if not progressed and not active:
+                        targets = []
+                        if pending:
+                            targets.append(pending[0].arrival)
+                        if waiting:
+                            targets.append(waiting[0].arrival
+                                           + self.admission.max_delay)
+                        self._idle_wait(clock, min(targets))
+                    inv_ok, a_inv, g_inv = self._turn_tail(
+                        book, m, clock, tr, qst, acache, gcache,
+                        (inv_ok, a_inv, g_inv))
         finally:
             if tr is not None:
                 if prev_tr is not None:
@@ -2365,7 +2458,7 @@ class ServingEngine:
                 else:
                     obs_trace.deactivate()
         cost_stats = self._cost_result(clock, tr, m)
-        self._close_trace(tr)
+        self._close_trace(tr, clock)
         self._stitch_resumes(outputs, hst)
         return ServeResult(policy=self.policy.name, outputs=outputs,
                            metrics=m, decisions=decisions,
@@ -2398,21 +2491,43 @@ class ServingEngine:
                                     invariant_ok=g_inv)),
                            cost_stats=cost_stats)
 
-    def _overhead_row(self, clock, run_w0) -> Optional[Dict]:
-        """The measured-clock host-overhead decomposition:
+    def _open_phases(self, clock) -> float:
+        """A new host-span store for the run that starts now (kept
+        only where ``overhead`` is reported: a fixed clock's replay
+        may be of any length); returns its start on
+        ``time.perf_counter``."""
+        self._phases = obs_trace.HostPhases(keep=clock.mode != "fixed")
+        return time.perf_counter()
+
+    def _overhead_row(self, clock, run_w0,
+                      whole: bool = True) -> Optional[Dict]:
+        """The run's wall-clock accounting (measured and wall clocks;
+        None on fixed clocks — their results stay byte-identical).
         ``engine_host_frac`` is the fraction of the run's wall time
         NOT covered by in-flight device work (timed dispatch waits,
         plus the overlapped span of every dispatched-ahead batch that
-        was served). Dispatch-ahead exists to shrink it. None on
-        fixed clocks — their results stay byte-identical."""
-        if self.clock_mode != "measured":
+        was served); dispatch-ahead exists to shrink it. The rest is
+        ``HostPhases.summary`` since ``run_w0``: ``turns``,
+        ``phases`` {name: n, self_s, max_s}, ``calls`` {kind: n,
+        rows, and per call start_s, seam_s, dispatch_s, wait_s},
+        ``idle_wait_s`` and ``unaccounted_s`` (the turns' self time),
+        which conserve: phases + calls + unaccounted = ``run_wall_s``
+        less what ran outside every turn. ``whole=False`` (a session,
+        which is driven from outside): ``run_wall_s`` is the time
+        under its own turns and waits."""
+        if clock.mode == "fixed":
             return None
-        run_wall = time.perf_counter() - run_w0
+        run_wall = time.perf_counter() - run_w0    # before the summing
+        acct = self._phases.summary(run_w0)
+        root_s = acct.pop("root_s")
+        if not whole:
+            run_wall = root_s
         dev = min(clock.dev_wall, run_wall)
         frac = 1.0 - dev / run_wall if run_wall > 0 else 0.0
-        return {"run_wall_s": round(run_wall, 6),
-                "device_wall_s": round(dev, 6),
-                "engine_host_frac": round(max(0.0, frac), 6)}
+        return dict(acct, run_wall_s=round(run_wall, 6),
+                    device_wall_s=round(dev, 6),
+                    engine_host_frac=round(max(0.0, frac), 6),
+                    slots=self.slots)
 
     def _cost_result(self, clock, tr=None, m=None) -> Optional[Dict]:
         """Bank the cost ledger's run-end evidence for this engine's
@@ -2490,7 +2605,7 @@ class ServingEngine:
         spst = self._make_spec_state()
         qst = self._make_quant_state()
         ahst = self._make_ahead_state()
-        run_w0 = time.perf_counter()
+        run_w0 = self._open_phases(clock)
         pages_total = len(book._free)
         pending = deque(sorted(trace, key=lambda r: (r.arrival, r.rid)))
         active: Dict[str, _PagedRow] = {}
@@ -2541,159 +2656,152 @@ class ServingEngine:
             obs_trace.activate(tr)
         try:
             while pending or sched.waiting() or active or lane:
-                now = clock.now()
-                while pending and pending[0].arrival <= now + 1e-12:
-                    r = pending.popleft()
-                    m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
-                                 priority=r.priority,
-                                 deadline_ms=r.deadline_ms)
-                    self._ctr_arrived.inc()
-                    self._req_open(tr, r)
-                    _shed(sched.enqueue(r, now))
-                m.on_queue_depth(now, sched.waiting())
-                if tr is not None:
-                    tr.counter("queue_depth", sched.waiting(), t=now)
-                progressed = _shed(sched.shed_expired(now))
+                with self._phase("turn"):
+                    with self._phase("intake"):
+                        now = clock.now()
+                        while pending and pending[0].arrival <= now + 1e-12:
+                            r = pending.popleft()
+                            m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
+                                         priority=r.priority,
+                                         deadline_ms=r.deadline_ms)
+                            self._ctr_arrived.inc()
+                            self._req_open(tr, r)
+                            _shed(sched.enqueue(r, now))
+                        m.on_queue_depth(now, sched.waiting())
+                        if tr is not None:
+                            tr.counter("queue_depth", sched.waiting(), t=now)
+                    with self._phase("admit"):
+                        progressed = _shed(sched.shed_expired(now))
 
-                if sched.waiting() and self._sched_ready(sched, pending,
-                                                         active, clock):
-                    dec = sched.select(now,
-                                       max_batch=self.admission.max_batch,
-                                       est=est,
-                                       decode_chunk=self.decode_chunk,
-                                       match_prefix=(book.match_prefix
-                                                     if self.prefix_cache
-                                                     else None),
-                                       backlog_cost=(
-                                           self._lane_backlog_cost(
-                                               lane, est)
-                                           if lane else 0.0))
-                    progressed |= _shed(dec.shed)
-                    # the scheduler's priority/WFQ order is kept as-is:
-                    # its feasibility estimates assumed it, and a cache
-                    # reorder could hand a scarce slot to a lower class
-                    # (cache awareness is in the select() pricing)
-                    wave = dec.wave
-                    if wave:
-                        groups = [r.prefix_group for r in wave
-                                  if r.prefix_group is not None]
-                        shared = (len(groups) != len(set(groups))
-                                  or any(g in seen_groups
-                                         for g in groups))
-                        ctx = dict(ctx_base, shared_prefix=shared,
-                                   active_paged=len(active)
-                                   + (len(lane) if lane else 0))
-                        backend, reason = self.policy.route(wave, ctx)
-                        decision = {
-                            "t": round(clock.now(), 6), "wave": len(wave),
-                            "prompt_lens": [len(r.prompt) for r in wave],
-                            "backend": backend, "rule": reason,
-                            "rids": [r.rid for r in wave]}
-                        if backend == "dense":
-                            decisions.append(decision)
-                            self._wave_instant(tr, decision)
-                            seen_groups.update(g for g in groups)
-                            self._commit_wave(wave, dec, sched, m,
-                                              tr=tr, t=clock.now())
-                            self._run_dense_wave(wave, clock, m, outputs,
-                                                 timeouts=True, tr=tr)
-                            progressed = True
-                        else:
-                            t0 = clock.now()
-                            n_adm, n_chunks, ptoks = self._admit_paged(
-                                wave, book, clock, m, active, free_slots,
-                                slot_log, prefix_cached, seen_groups,
-                                outputs, tr=tr, lane=lane,
-                                acache=acache, spst=spst, hst=hst,
-                                gcache=gcache)
-                            prefill_tokens += ptoks
-                            if n_adm:
-                                dt = clock.now() - t0
-                                est.observe("prefill", dt / n_adm)
-                                if n_chunks and "prefill_unit" \
-                                        in est.costs:
-                                    est.observe("prefill_unit",
-                                                dt / n_chunks)
-                                self._commit_wave(wave[:n_adm], dec,
-                                                  sched, m, tr=tr,
-                                                  t=clock.now())
-                                decision["admitted"] = n_adm
-                                decisions.append(decision)
-                                self._wave_instant(tr, decision)
-                                progressed = True
-                            elif hst is not None and active \
-                                    and self._preempt_turn(
-                                        wave[0], book, clock, m,
-                                        active, free_slots, slot_log,
-                                        sched, hst, _shed, tr=tr,
-                                        acache=acache, gcache=gcache):
-                                # the rung between degrade and shed:
-                                # a fully blocked wave swaps ONE
-                                # lower-priority running row out to
-                                # the arena; the blocked request
-                                # stays queued and admits next turn
-                                # into the freed slot/pages
-                                progressed = True
-                            elif not active and not lane:
-                                raise RuntimeError(
-                                    f"pool/slot config too small for "
-                                    f"{wave[0].rid} (free pages "
-                                    f"{len(book._free)}, free slots "
-                                    f"{len(free_slots)})")
+                        if sched.waiting() and self._sched_ready(
+                                sched, pending, active, clock):
+                            dec = sched.select(
+                                now, max_batch=self.admission.max_batch,
+                                est=est, decode_chunk=self.decode_chunk,
+                                match_prefix=(book.match_prefix
+                                              if self.prefix_cache
+                                              else None),
+                                backlog_cost=(
+                                    self._lane_backlog_cost(lane, est)
+                                    if lane else 0.0))
+                            progressed |= _shed(dec.shed)
+                            # the scheduler's priority/WFQ order is kept
+                            # as-is: its feasibility estimates assumed
+                            # it, and a cache reorder could hand a scarce
+                            # slot to a lower class (cache awareness is
+                            # in the select() pricing)
+                            wave = dec.wave
+                            if wave:
+                                groups = [r.prefix_group for r in wave
+                                          if r.prefix_group is not None]
+                                shared = (len(groups) != len(set(groups))
+                                          or any(g in seen_groups
+                                                 for g in groups))
+                                ctx = dict(ctx_base, shared_prefix=shared,
+                                           active_paged=len(active)
+                                           + (len(lane) if lane else 0))
+                                backend, reason = self.policy.route(wave, ctx)
+                                decision = {
+                                    "t": round(clock.now(), 6),
+                                    "wave": len(wave),
+                                    "prompt_lens": [len(r.prompt)
+                                                    for r in wave],
+                                    "backend": backend, "rule": reason,
+                                    "rids": [r.rid for r in wave]}
+                                if backend == "dense":
+                                    decisions.append(decision)
+                                    self._wave_instant(tr, decision)
+                                    seen_groups.update(g for g in groups)
+                                    self._commit_wave(wave, dec, sched, m,
+                                                      tr=tr, t=clock.now())
+                                    self._run_dense_wave(
+                                        wave, clock, m, outputs,
+                                        timeouts=True, tr=tr)
+                                    progressed = True
+                                else:
+                                    t0 = clock.now()
+                                    n_adm, n_chunks, ptoks = \
+                                        self._admit_paged(
+                                            wave, book, clock, m, active,
+                                            free_slots, slot_log,
+                                            prefix_cached, seen_groups,
+                                            outputs, tr=tr, lane=lane,
+                                            acache=acache, spst=spst,
+                                            hst=hst, gcache=gcache)
+                                    prefill_tokens += ptoks
+                                    if n_adm:
+                                        dt = clock.now() - t0
+                                        est.observe("prefill", dt / n_adm)
+                                        if n_chunks and "prefill_unit" \
+                                                in est.costs:
+                                            est.observe("prefill_unit",
+                                                        dt / n_chunks)
+                                        self._commit_wave(wave[:n_adm], dec,
+                                                          sched, m, tr=tr,
+                                                          t=clock.now())
+                                        decision["admitted"] = n_adm
+                                        decisions.append(decision)
+                                        self._wave_instant(tr, decision)
+                                        progressed = True
+                                    elif hst is not None and active \
+                                            and self._preempt_turn(
+                                                wave[0], book, clock, m,
+                                                active, free_slots, slot_log,
+                                                sched, hst, _shed, tr=tr,
+                                                acache=acache, gcache=gcache):
+                                        # the rung between degrade and shed:
+                                        # a fully blocked wave swaps ONE
+                                        # lower-priority running row out to
+                                        # the arena; the blocked request
+                                        # stays queued and admits next turn
+                                        # into the freed slot/pages
+                                        progressed = True
+                                    elif not active and not lane:
+                                        raise RuntimeError(
+                                            f"pool/slot config too small "
+                                            f"for {wave[0].rid} (free pages "
+                                            f"{len(book._free)}, free slots "
+                                            f"{len(free_slots)})")
 
-                if active:
-                    t0 = clock.now()
-                    self._paged_chunk(book, clock, m, active, free_slots,
-                                      slot_log, outputs, tr=tr,
-                                      acache=acache, spst=spst,
-                                      ahst=ahst, gcache=gcache)
-                    est.observe("decode", clock.now() - t0)
-                    t = clock.now()
-                    for sid in list(active):
-                        dl = active[sid].req.deadline_time()
-                        if dl is not None and t > dl + 1e-9:
-                            self._finish_paged(sid, book, clock, m,
-                                               active, free_slots,
-                                               slot_log, outputs,
-                                               timeout=True, tr=tr,
-                                               acache=acache,
-                                               gcache=gcache)
-                    progressed = True
+                    if active:
+                        t0 = clock.now()
+                        self._paged_chunk(book, clock, m, active, free_slots,
+                                          slot_log, outputs, tr=tr,
+                                          acache=acache, spst=spst,
+                                          ahst=ahst, gcache=gcache)
+                        est.observe("decode", clock.now() - t0)
+                        self._row_timeouts(book, clock, m, active,
+                                           free_slots, slot_log, outputs,
+                                           tr=tr, acache=acache,
+                                           gcache=gcache)
+                        progressed = True
 
-                if lane:
-                    _, ptoks = self._lane_step(
-                        lane, book, clock, m, active, free_slots,
-                        slot_log, outputs, prefix_cached, seen_groups,
-                        tr=tr, acache=acache, spst=spst,
-                        gcache=gcache)
-                    prefill_tokens += ptoks
-                    self._lane_timeouts(lane, book, clock, m,
-                                        free_slots, slot_log, outputs,
-                                        tr=tr, acache=acache,
-                                        gcache=gcache)
-                    progressed = True
+                    if lane:
+                        _, ptoks = self._lane_step(
+                            lane, book, clock, m, active, free_slots,
+                            slot_log, outputs, prefix_cached, seen_groups,
+                            tr=tr, acache=acache, spst=spst,
+                            gcache=gcache)
+                        prefill_tokens += ptoks
+                        self._lane_timeouts(lane, book, clock, m,
+                                            free_slots, slot_log, outputs,
+                                            tr=tr, acache=acache,
+                                            gcache=gcache)
+                        progressed = True
 
-                if not progressed and not active:
-                    targets = []
-                    if pending:
-                        targets.append(pending[0].arrival)
-                    if sched.waiting():
-                        targets.append(sched.oldest_arrival()
-                                       + self.admission.max_delay)
-                    if not targets:
-                        break  # everything left this turn was shed
-                    clock.advance_to(min(targets))
-                self._quant_turn(book, m, clock, tr, qst)
-                inv_ok &= book.census_ok()
-                if acache is not None:
-                    a_inv &= acache.census_ok()
-                if gcache is not None:
-                    g_inv &= gcache.census_ok()
-                if self._ledger is not None:
-                    self._ledger.sample_occupancy(
-                        clock.label, book=book, acache=acache,
-                        gcache=gcache,
-                        arena=getattr(book, "_arena", None))
+                    if not progressed and not active:
+                        targets = []
+                        if pending:
+                            targets.append(pending[0].arrival)
+                        if sched.waiting():
+                            targets.append(sched.oldest_arrival()
+                                           + self.admission.max_delay)
+                        if not targets:
+                            break  # everything left this turn was shed
+                        self._idle_wait(clock, min(targets))
+                    inv_ok, a_inv, g_inv = self._turn_tail(
+                        book, m, clock, tr, qst, acache, gcache,
+                        (inv_ok, a_inv, g_inv))
         finally:
             if tr is not None:
                 if prev_tr is not None:
@@ -2701,7 +2809,7 @@ class ServingEngine:
                 else:
                     obs_trace.deactivate()
         cost_stats = self._cost_result(clock, tr, m)
-        self._close_trace(tr)
+        self._close_trace(tr, clock)
         self._stitch_resumes(outputs, hst)
         return ServeResult(policy=self.policy.name, outputs=outputs,
                            metrics=m, decisions=decisions,
@@ -2865,229 +2973,230 @@ class ServingEngine:
             if not free_slots:
                 break
             sid = r.rid
-            # adapter residency FIRST (it is the cheapest refusal):
-            # pin-while-in-flight guarantees the bank slot outlives
-            # this row; a rolled-back page allocate below releases
-            # the pin so the requeue retries from a clean slate
-            aslot, a_up = 0, False
-            if acache is not None and r.adapter is not None:
-                try:
-                    # a miss's host->device upload runs INSIDE the
-                    # timed wrapper: paced per upload on the fixed
-                    # clock, real transfer time attributed to the
-                    # adapter_upload span on the measured one (a
-                    # later page-refusal retry HITS and never
-                    # re-pays). Hit/upload COUNTING waits for the
-                    # admission to actually succeed — see
-                    # took_upload below.
-                    aslot, a_up = acache.acquire(
-                        r.adapter, sid,
-                        timed=lambda f: self._timed(
-                            tr, clock, "adapter_upload", f, rid=sid,
-                            adapter=r.adapter))
-                except MemoryError:
-                    break  # every slot pinned: requeue, retry as
-                    # rows finish and release their pins
-            # grammar residency SECOND (same pin discipline, one tier
-            # over): a resident automaton is a free hit, a miss pays
-            # one paced grammar_compile (host DFA compile + mask-bank
-            # upload), and a bank whose every slot is pinned requeues
-            # the wave — rolling back the adapter pin first
-            gname = self._schema_of(r) if gcache is not None else None
-            gslot, g_up, gaut = 0, False, None
-            if gname is not None:
-                try:
-                    gslot, g_up = gcache.acquire(
-                        gname, sid,
-                        timed=lambda f: self._timed(
-                            tr, clock, "grammar_compile", f, rid=sid,
-                            schema=gname))
-                except MemoryError:
-                    if acache is not None and r.adapter is not None:
-                        acache.note_rollback(r.adapter, sid, a_up)
-                    break
-                gaut = gcache.automaton(gname)
-            # AUTOMATIC prefix acquisition: every request probes the
-            # pool's chain-hashed page cache (page-aligned exact match
-            # gives token-level sharing with no trace tag;
-            # prefix_group stays a routing hint only). A failed
-            # allocate below MUST release these shared refs — the
-            # free() in the except arm is the leak-proof rollback,
-            # returning revived pages to the evictable pool so the
-            # requeue retries from a clean slate.
-            n_cached = 0
-            if self.prefix_cache:
-                n_cached = book.acquire_prefix(sid, list(r.prompt))
-                if hst is not None:
-                    # PRICED page-in: the spilled extension of the
-                    # resident match swaps back into fresh device
-                    # pages (one kv_pagein each) and counts as cached
-                    # — the prefill resumes past it exactly as past a
-                    # resident hit. A preempted request's swapped
-                    # chain restores through this same path.
-                    n_cached += book.page_in(
-                        sid, list(r.prompt), n_cached,
-                        lambda p, e, _s=sid: self._pagein_page(
-                            p, e, _s, clock, m, tr, hst))
-            ev0 = book._stats["evictions"]
-            try:
-                book.allocate(sid, self._footprint(r))
-            except MemoryError:
-                if self.prefix_cache:
-                    # shared refs released, revived pages re-parked,
-                    # hit/lookup stats unwound (the requeue must not
-                    # inflate hit_rate)
-                    book.rollback_acquire(sid, list(r.prompt))
-                else:
-                    book.free(sid)
+            with self._phase("admit", sid):
+                # adapter residency FIRST (it is the cheapest refusal):
+                # pin-while-in-flight guarantees the bank slot outlives
+                # this row; a rolled-back page allocate below releases
+                # the pin so the requeue retries from a clean slate
+                aslot, a_up = 0, False
                 if acache is not None and r.adapter is not None:
-                    # the adapter pin rolls back too; the upload — if
-                    # one ran — stays resident (the retry hits) and
-                    # is REMEMBERED so the successful admission still
-                    # reports it as this request's upload
-                    acache.note_rollback(r.adapter, sid, a_up)
+                    try:
+                        # a miss's host->device upload runs INSIDE the
+                        # timed wrapper: paced per upload on the fixed
+                        # clock, real transfer time attributed to the
+                        # adapter_upload span on the measured one (a
+                        # later page-refusal retry HITS and never
+                        # re-pays). Hit/upload COUNTING waits for the
+                        # admission to actually succeed — see
+                        # took_upload below.
+                        aslot, a_up = acache.acquire(
+                            r.adapter, sid,
+                            timed=lambda f: self._timed(
+                                tr, clock, "adapter_upload", f, rid=sid,
+                                adapter=r.adapter))
+                    except MemoryError:
+                        break  # every slot pinned: requeue, retry as
+                        # rows finish and release their pins
+                # grammar residency SECOND (same pin discipline, one tier
+                # over): a resident automaton is a free hit, a miss pays
+                # one paced grammar_compile (host DFA compile + mask-bank
+                # upload), and a bank whose every slot is pinned requeues
+                # the wave — rolling back the adapter pin first
+                gname = self._schema_of(r) if gcache is not None else None
+                gslot, g_up, gaut = 0, False, None
                 if gname is not None:
-                    # same discipline for the automaton pin: the
-                    # compile — if one ran — stays resident and is
-                    # remembered for the retry's attribution
-                    gcache.note_rollback(gname, sid, g_up)
-                break
-            d_ev = book._stats["evictions"] - ev0
-            if d_ev:
-                self._ctr_prefix_evictions.inc(d_ev)
-                if tr is not None:
-                    tr.instant("prefix_evict", t=clock.now(),
-                               track="engine", pages=d_ev, rid=sid)
-            book.lengths[sid] = len(r.prompt)
-            if hst is not None and sid in hst["preempted"]:
-                # the preempted request is BACK: leftover pinned pages
-                # demote to ordinary spilled cache (the page-ins above
-                # already priced the swap-in; whatever the pool could
-                # not take re-prefills below, same tokens either way)
-                hst["preempted"].discard(sid)
-                book.unpin_spilled_owner(sid)
-                hst["restores"] += 1
-                self._ctr_restores.inc()
-                m.on_restore(sid, clock.now())
-                if tr is not None:
-                    tr.instant("restore", t=clock.now(),
-                               track="scheduler", rid=sid,
-                               tenant=r.tenant)
-            slot = free_slots.pop(0)
-            T = self._pad_len(len(r.prompt))
-            toks = np.zeros((1, T), np.int32)
-            toks[0, :len(r.prompt)] = r.prompt
-            pt = np.zeros((1, self.W), np.int32)
-            table = book.tables[sid]
-            pt[0, :len(table)] = table
-            lens = np.asarray([len(r.prompt)], np.int32)
-            resume = (n_cached // self.chunk_C) * self.chunk_C
-            # the factory clamps resume so the FINAL chunk always runs
-            # (last-position logits) — charge the clock for what it
-            # actually computes
-            n_chunks = (T - min(resume, T - self.chunk_C)) \
-                // self.chunk_C
-            # per-request adaptive spec verdict, decided ONCE at
-            # admission (the policy's spec_route rule): the row's
-            # route for its whole lifetime, modulo the run-level
-            # enable gate
-            sp = False
-            if spst is not None:
-                sp, _sp_rule = self.policy.spec_route(r, spst.cfg)
-            if gaut is not None:
-                # a constrained row always decodes PLAIN: the draft
-                # proposes unmasked tokens the verify would reject
-                # almost surely, and acceptance bookkeeping under a
-                # mask would fork the emission rule — free rows in
-                # the same wave keep their spec verdict
+                    try:
+                        gslot, g_up = gcache.acquire(
+                            gname, sid,
+                            timed=lambda f: self._timed(
+                                tr, clock, "grammar_compile", f, rid=sid,
+                                schema=gname))
+                    except MemoryError:
+                        if acache is not None and r.adapter is not None:
+                            acache.note_rollback(r.adapter, sid, a_up)
+                        break
+                    gaut = gcache.automaton(gname)
+                # AUTOMATIC prefix acquisition: every request probes the
+                # pool's chain-hashed page cache (page-aligned exact match
+                # gives token-level sharing with no trace tag;
+                # prefix_group stays a routing hint only). A failed
+                # allocate below MUST release these shared refs — the
+                # free() in the except arm is the leak-proof rollback,
+                # returning revived pages to the evictable pool so the
+                # requeue retries from a clean slate.
+                n_cached = 0
+                if self.prefix_cache:
+                    n_cached = book.acquire_prefix(sid, list(r.prompt))
+                    if hst is not None:
+                        # PRICED page-in: the spilled extension of the
+                        # resident match swaps back into fresh device
+                        # pages (one kv_pagein each) and counts as cached
+                        # — the prefill resumes past it exactly as past a
+                        # resident hit. A preempted request's swapped
+                        # chain restores through this same path.
+                        n_cached += book.page_in(
+                            sid, list(r.prompt), n_cached,
+                            lambda p, e, _s=sid: self._pagein_page(
+                                p, e, _s, clock, m, tr, hst))
+                ev0 = book._stats["evictions"]
+                try:
+                    book.allocate(sid, self._footprint(r))
+                except MemoryError:
+                    if self.prefix_cache:
+                        # shared refs released, revived pages re-parked,
+                        # hit/lookup stats unwound (the requeue must not
+                        # inflate hit_rate)
+                        book.rollback_acquire(sid, list(r.prompt))
+                    else:
+                        book.free(sid)
+                    if acache is not None and r.adapter is not None:
+                        # the adapter pin rolls back too; the upload — if
+                        # one ran — stays resident (the retry hits) and
+                        # is REMEMBERED so the successful admission still
+                        # reports it as this request's upload
+                        acache.note_rollback(r.adapter, sid, a_up)
+                    if gname is not None:
+                        # same discipline for the automaton pin: the
+                        # compile — if one ran — stays resident and is
+                        # remembered for the retry's attribution
+                        gcache.note_rollback(gname, sid, g_up)
+                    break
+                d_ev = book._stats["evictions"] - ev0
+                if d_ev:
+                    self._ctr_prefix_evictions.inc(d_ev)
+                    if tr is not None:
+                        tr.instant("prefix_evict", t=clock.now(),
+                                   track="engine", pages=d_ev, rid=sid)
+                book.lengths[sid] = len(r.prompt)
+                if hst is not None and sid in hst["preempted"]:
+                    # the preempted request is BACK: leftover pinned pages
+                    # demote to ordinary spilled cache (the page-ins above
+                    # already priced the swap-in; whatever the pool could
+                    # not take re-prefills below, same tokens either way)
+                    hst["preempted"].discard(sid)
+                    book.unpin_spilled_owner(sid)
+                    hst["restores"] += 1
+                    self._ctr_restores.inc()
+                    m.on_restore(sid, clock.now())
+                    if tr is not None:
+                        tr.instant("restore", t=clock.now(),
+                                   track="scheduler", rid=sid,
+                                   tenant=r.tenant)
+                slot = free_slots.pop(0)
+                T = self._pad_len(len(r.prompt))
+                toks = np.zeros((1, T), np.int32)
+                toks[0, :len(r.prompt)] = r.prompt
+                pt = np.zeros((1, self.W), np.int32)
+                table = book.tables[sid]
+                pt[0, :len(table)] = table
+                lens = np.asarray([len(r.prompt)], np.int32)
+                resume = (n_cached // self.chunk_C) * self.chunk_C
+                # the factory clamps resume so the FINAL chunk always runs
+                # (last-position logits) — charge the clock for what it
+                # actually computes
+                n_chunks = (T - min(resume, T - self.chunk_C)) \
+                    // self.chunk_C
+                # per-request adaptive spec verdict, decided ONCE at
+                # admission (the policy's spec_route rule): the row's
+                # route for its whole lifetime, modulo the run-level
+                # enable gate
                 sp = False
-            # DFA state the first emitted token is masked by: the
-            # start state, or — for a preempted request swapping back
-            # in — the state its already-served tokens walked to (the
-            # resume prefix is exactly the emitted stream)
-            gstate = 0
-            if gaut is not None:
-                gstate = gaut.start
-                if hst is not None and hst["resume_prefix"].get(sid):
-                    gstate = gaut.walk(hst["resume_prefix"][sid])
-            t_admit = clock.now()
-            m.on_admit(sid, t_admit, "paged")
-            if gname is not None:
-                # one hit-or-compile event per ADMISSION, the
-                # took_upload discipline: a compile paid by a
-                # rolled-back earlier acquire is attributed here
-                g_up = gcache.took_compile(sid, g_up)
-                (self._ctr_grammar_compiles if g_up
-                 else self._ctr_grammar_hits).inc()
-                m.on_grammar(sid, gname, hit=not g_up)
-            if acache is not None and r.adapter is not None:
-                # one hit-or-upload event per ADMISSION: an upload
-                # paid by a rolled-back earlier acquire is attributed
-                # here, so every counter surface (registry, report,
-                # cache_stats) tells the same story
-                a_up = acache.took_upload(sid, a_up)
-                (self._ctr_adapter_uploads if a_up
-                 else self._ctr_adapter_hits).inc()
-                m.on_adapter(sid, r.adapter, hit=not a_up)
-            if tr is not None:
-                attrs = {} if r.adapter is None \
-                    else {"adapter": r.adapter}
                 if spst is not None:
-                    # the admit instant carries the verdict ONLY on
-                    # spec-configured runs, so plain traces keep
-                    # their event args exactly
-                    attrs["spec"] = sp
+                    sp, _sp_rule = self.policy.spec_route(r, spst.cfg)
+                if gaut is not None:
+                    # a constrained row always decodes PLAIN: the draft
+                    # proposes unmasked tokens the verify would reject
+                    # almost surely, and acceptance bookkeeping under a
+                    # mask would fork the emission rule — free rows in
+                    # the same wave keep their spec verdict
+                    sp = False
+                # DFA state the first emitted token is masked by: the
+                # start state, or — for a preempted request swapping back
+                # in — the state its already-served tokens walked to (the
+                # resume prefix is exactly the emitted stream)
+                gstate = 0
+                if gaut is not None:
+                    gstate = gaut.start
+                    if hst is not None and hst["resume_prefix"].get(sid):
+                        gstate = gaut.walk(hst["resume_prefix"][sid])
+                t_admit = clock.now()
+                m.on_admit(sid, t_admit, "paged")
                 if gname is not None:
-                    # schema tag ONLY on constrained rows — free rows
-                    # and grammar-less runs keep their event args
-                    # exactly (the trace_report waterfall reads it)
-                    attrs["schema"] = gname
-                tr.instant("admit", t=t_admit,
-                           track=self._tenant_track(r), rid=sid,
-                           backend="paged", slot=slot, cached=n_cached,
-                           **attrs)
-            if lane is not None:
-                lane.append(_PrefillingRow(r, slot, t_admit, n_cached,
-                                           resume, T, self.chunk_C,
-                                           toks, pt, aslot=aslot,
-                                           spec=sp, gslot=gslot,
-                                           gname=gname, gaut=gaut,
-                                           gstate=gstate))
-                admitted += 1
-                continue
+                    # one hit-or-compile event per ADMISSION, the
+                    # took_upload discipline: a compile paid by a
+                    # rolled-back earlier acquire is attributed here
+                    g_up = gcache.took_compile(sid, g_up)
+                    (self._ctr_grammar_compiles if g_up
+                     else self._ctr_grammar_hits).inc()
+                    m.on_grammar(sid, gname, hit=not g_up)
+                if acache is not None and r.adapter is not None:
+                    # one hit-or-upload event per ADMISSION: an upload
+                    # paid by a rolled-back earlier acquire is attributed
+                    # here, so every counter surface (registry, report,
+                    # cache_stats) tells the same story
+                    a_up = acache.took_upload(sid, a_up)
+                    (self._ctr_adapter_uploads if a_up
+                     else self._ctr_adapter_hits).inc()
+                    m.on_adapter(sid, r.adapter, hit=not a_up)
+                if tr is not None:
+                    attrs = {} if r.adapter is None \
+                        else {"adapter": r.adapter}
+                    if spst is not None:
+                        # the admit instant carries the verdict ONLY on
+                        # spec-configured runs, so plain traces keep
+                        # their event args exactly
+                        attrs["spec"] = sp
+                    if gname is not None:
+                        # schema tag ONLY on constrained rows — free rows
+                        # and grammar-less runs keep their event args
+                        # exactly (the trace_report waterfall reads it)
+                        attrs["schema"] = gname
+                    tr.instant("admit", t=t_admit,
+                               track=self._tenant_track(r), rid=sid,
+                               backend="paged", slot=slot, cached=n_cached,
+                               **attrs)
+                if lane is not None:
+                    lane.append(_PrefillingRow(r, slot, t_admit, n_cached,
+                                               resume, T, self.chunk_C,
+                                               toks, pt, aslot=aslot,
+                                               spec=sp, gslot=gslot,
+                                               gname=gname, gaut=gaut,
+                                               gstate=gstate))
+                    admitted += 1
+                    continue
 
-            def _call(toks=toks, pt=pt, lens=lens, resume=resume,
-                      aslot=aslot, gslot=gslot, gstate=gstate):
-                arr = self._arr
-                kw = {}
-                if acache is not None:
-                    kw["lora"] = self._lora_arg(acache, [aslot])
-                if gcache is not None:
-                    kw["grammar"] = self._grammar_arg(
-                        gcache, [gcache.flat_id(gslot, gstate)
-                                 if gslot else 0])
-                return self._p_prefill(
-                    self._p_outer, self._p_layers, arr(toks),
-                    arr(pt), arr(lens), self._pools,
-                    resume_from=resume, **kw)
-            first, self._pools = self._timed(
-                tr, clock, "prefill", _call, jitfn=self._p_prefill,
-                rid=sid, units=n_chunks, resume=resume,
-                cached=n_cached, **self._tp_attr)
-            first_tok = int(np.asarray(first)[0])
-            chunks_done += n_chunks
-            tokens_done += n_chunks * self.chunk_C
-            self._prefill_complete(r, slot, first_tok, n_cached,
-                                   resume, T, book, clock, m, active,
-                                   free_slots, slot_log, outputs,
-                                   prefix_cached, seen_groups, tr=tr,
-                                   t0=t_admit, t_admit=t_admit,
-                                   sink=sink, acache=acache,
-                                   aslot=aslot, spst=spst,
-                                   spec_row=sp, gcache=gcache,
-                                   gslot=gslot, gname=gname,
-                                   gaut=gaut, gstate=gstate)
-            admitted += 1
+                def _call(toks=toks, pt=pt, lens=lens, resume=resume,
+                          aslot=aslot, gslot=gslot, gstate=gstate):
+                    arr = self._arr
+                    kw = {}
+                    if acache is not None:
+                        kw["lora"] = self._lora_arg(acache, [aslot])
+                    if gcache is not None:
+                        kw["grammar"] = self._grammar_arg(
+                            gcache, [gcache.flat_id(gslot, gstate)
+                                     if gslot else 0])
+                    return self._p_prefill(
+                        self._p_outer, self._p_layers, arr(toks),
+                        arr(pt), arr(lens), self._pools,
+                        resume_from=resume, **kw)
+                first, self._pools = self._timed(
+                    tr, clock, "prefill", _call, jitfn=self._p_prefill,
+                    rid=sid, units=n_chunks, resume=resume,
+                    cached=n_cached, **self._tp_attr)
+                first_tok = int(np.asarray(first)[0])
+                chunks_done += n_chunks
+                tokens_done += n_chunks * self.chunk_C
+                self._prefill_complete(r, slot, first_tok, n_cached,
+                                       resume, T, book, clock, m, active,
+                                       free_slots, slot_log, outputs,
+                                       prefix_cached, seen_groups, tr=tr,
+                                       t0=t_admit, t_admit=t_admit,
+                                       sink=sink, acache=acache,
+                                       aslot=aslot, spst=spst,
+                                       spec_row=sp, gcache=gcache,
+                                       gslot=gslot, gname=gname,
+                                       gaut=gaut, gstate=gstate)
+                admitted += 1
         if admitted:
             self._g_resident.set(float(len(book._refs)))
             self._note_adapters(acache, m, clock.now())
@@ -3215,23 +3324,24 @@ class ServingEngine:
         flat = self.clock_mode == "fixed" \
             and "prefill_unit" not in (self.fixed_costs or {})
         while lane and chunks_run < self.prefill_chunk_budget:
-            oldest = min(lane, key=lambda x: (x.t_admit, x.req.rid))
-            if oldest.skipped >= self._LANE_STARVE_LIMIT:
-                e = oldest
-            else:
-                e = min(lane, key=lambda x: (x.remaining_chunks(),
-                                             x.t_admit, x.req.rid))
-            if e is oldest:
-                oldest.skipped = 0
-            else:
-                oldest.skipped += 1
-            sid = e.req.rid
-            k = e.next_chunk
-            final = (k + 1 == e.n_chunks)
-            toks = e.toks[:, :(k + 1) * C]
-            lens = np.asarray(
-                [len(e.req.prompt) if final else (k + 1) * C],
-                np.int32)
+            with self._phase("lane.pick") as pick:
+                oldest = min(lane, key=lambda x: (x.t_admit, x.req.rid))
+                if oldest.skipped >= self._LANE_STARVE_LIMIT:
+                    e = oldest
+                else:
+                    e = min(lane, key=lambda x: (x.remaining_chunks(),
+                                                 x.t_admit, x.req.rid))
+                if e is oldest:
+                    oldest.skipped = 0
+                else:
+                    oldest.skipped += 1
+                sid = pick.rid = e.req.rid
+                k = e.next_chunk
+                final = (k + 1 == e.n_chunks)
+                toks = e.toks[:, :(k + 1) * C]
+                lens = np.asarray(
+                    [len(e.req.prompt) if final else (k + 1) * C],
+                    np.int32)
 
             def _call(toks=toks, pt=e.pt, lens=lens, resume=k * C,
                       aslot=e.aslot, gslot=e.gslot, gstate=e.gstate):
@@ -3261,19 +3371,20 @@ class ServingEngine:
             tokens_run += C
             if not final:
                 continue
-            lane.remove(e)
-            t_done = clock.now()
-            if tr is not None:
-                tr.add_span(sid, e.t_admit, t_done - e.t_admit,
-                            track="prefill_lane", cached=e.n_cached)
-            self._prefill_complete(
-                e.req, e.slot, int(np.asarray(first)[0]), e.n_cached,
-                e.resume, e.T, book, clock, m, active, free_slots,
-                slot_log, outputs, prefix_cached, seen_groups, tr=tr,
-                t0=t_done, t_admit=e.t_admit, sink=sink,
-                acache=acache, aslot=e.aslot, spst=spst,
-                spec_row=e.spec, gcache=gcache, gslot=e.gslot,
-                gname=e.gname, gaut=e.gaut, gstate=e.gstate)
+            with self._phase("lane.complete", sid):
+                lane.remove(e)
+                t_done = clock.now()
+                if tr is not None:
+                    tr.add_span(sid, e.t_admit, t_done - e.t_admit,
+                                track="prefill_lane", cached=e.n_cached)
+                self._prefill_complete(
+                    e.req, e.slot, int(np.asarray(first)[0]),
+                    e.n_cached, e.resume, e.T, book, clock, m, active,
+                    free_slots, slot_log, outputs, prefix_cached,
+                    seen_groups, tr=tr, t0=t_done, t_admit=e.t_admit,
+                    sink=sink, acache=acache, aslot=e.aslot, spst=spst,
+                    spec_row=e.spec, gcache=gcache, gslot=e.gslot,
+                    gname=e.gname, gaut=e.gaut, gstate=e.gstate)
         if self._g_lane_depth is not None:
             self._g_lane_depth.set(float(len(lane)))
         m.on_lane_depth(clock.now(), len(lane))
@@ -3311,34 +3422,35 @@ class ServingEngine:
         flat = self.clock_mode == "fixed" \
             and "prefill_unit" not in (self.fixed_costs or {})
         while lane and dispatches < self.prefill_chunk_budget:
-            picked = sorted(lane, key=lambda x: (x.t_admit, x.req.rid))
-            toks = np.zeros((R, C), np.int32)
-            starts = np.zeros((R,), np.int32)
-            pt = np.zeros((R, self.W), np.int32)
-            # idle rows ride as plain causal garbage over the reserved
-            # page 0 (length C, start 0) — NOT length 0, which would
-            # fully mask their attention rows
-            lens = np.full((R,), C, np.int32)
-            aids = np.zeros((R,), np.int32) if acache is not None \
-                else None
-            gids = np.zeros((R,), np.int32) if gcache is not None \
-                else None
-            finals = []
-            for e in picked:
-                e.skipped = 0
-                k = e.next_chunk
-                final = (k + 1 == e.n_chunks)
-                toks[e.slot] = e.toks[0, k * C:(k + 1) * C]
-                starts[e.slot] = k * C
-                pt[e.slot] = e.pt[0]
-                lens[e.slot] = len(e.req.prompt) if final \
-                    else (k + 1) * C
-                if aids is not None:
-                    aids[e.slot] = e.aslot
-                if gids is not None and e.gslot:
-                    gids[e.slot] = gcache.flat_id(e.gslot, e.gstate)
-                if final:
-                    finals.append(e)
+            with self._phase("lane.pick"):
+                picked = sorted(lane, key=lambda x: (x.t_admit, x.req.rid))
+                toks = np.zeros((R, C), np.int32)
+                starts = np.zeros((R,), np.int32)
+                pt = np.zeros((R, self.W), np.int32)
+                # idle rows ride as plain causal garbage over the reserved
+                # page 0 (length C, start 0) — NOT length 0, which would
+                # fully mask their attention rows
+                lens = np.full((R,), C, np.int32)
+                aids = np.zeros((R,), np.int32) if acache is not None \
+                    else None
+                gids = np.zeros((R,), np.int32) if gcache is not None \
+                    else None
+                finals = []
+                for e in picked:
+                    e.skipped = 0
+                    k = e.next_chunk
+                    final = (k + 1 == e.n_chunks)
+                    toks[e.slot] = e.toks[0, k * C:(k + 1) * C]
+                    starts[e.slot] = k * C
+                    pt[e.slot] = e.pt[0]
+                    lens[e.slot] = len(e.req.prompt) if final \
+                        else (k + 1) * C
+                    if aids is not None:
+                        aids[e.slot] = e.aslot
+                    if gids is not None and e.gslot:
+                        gids[e.slot] = gcache.flat_id(e.gslot, e.gstate)
+                    if final:
+                        finals.append(e)
 
             def _call(toks=toks, starts=starts, pt=pt, lens=lens,
                       aids=aids, gids=gids):
@@ -3364,33 +3476,50 @@ class ServingEngine:
             if self._ledger is not None:
                 for e in picked:
                     self._ledger.tag(e.req.rid, "ragged")
-            firsts = np.asarray(firsts)
-            for e in picked:
-                e.next_chunk += 1
-            dispatches += 1
-            tokens_run += C * len(picked)
-            t_done = clock.now()
-            for e in finals:
-                sid = e.req.rid
-                lane.remove(e)
-                if tr is not None:
-                    tr.add_span(sid, e.t_admit, t_done - e.t_admit,
-                                track="prefill_lane",
-                                cached=e.n_cached)
-                self._prefill_complete(
-                    e.req, e.slot, int(firsts[e.slot]), e.n_cached,
-                    e.resume, e.T, book, clock, m, active, free_slots,
-                    slot_log, outputs, prefix_cached, seen_groups,
-                    tr=tr, t0=t_done, t_admit=e.t_admit, sink=sink,
-                    acache=acache, aslot=e.aslot, spst=spst,
-                    spec_row=e.spec, gcache=gcache, gslot=e.gslot,
-                    gname=e.gname, gaut=e.gaut, gstate=e.gstate)
+            with self._phase("lane.complete"):
+                firsts = np.asarray(firsts)
+                for e in picked:
+                    e.next_chunk += 1
+                dispatches += 1
+                tokens_run += C * len(picked)
+                t_done = clock.now()
+                for e in finals:
+                    sid = e.req.rid
+                    with self._phase("lane.complete", sid):
+                        lane.remove(e)
+                        if tr is not None:
+                            tr.add_span(sid, e.t_admit, t_done - e.t_admit,
+                                        track="prefill_lane",
+                                        cached=e.n_cached)
+                        self._prefill_complete(
+                            e.req, e.slot, int(firsts[e.slot]), e.n_cached,
+                            e.resume, e.T, book, clock, m, active, free_slots,
+                            slot_log, outputs, prefix_cached, seen_groups,
+                            tr=tr, t0=t_done, t_admit=e.t_admit, sink=sink,
+                            acache=acache, aslot=e.aslot, spst=spst,
+                            spec_row=e.spec, gcache=gcache, gslot=e.gslot,
+                            gname=e.gname, gaut=e.gaut, gstate=e.gstate)
         if self._g_lane_depth is not None:
             self._g_lane_depth.set(float(len(lane)))
         m.on_lane_depth(clock.now(), len(lane))
         if tr is not None:
             tr.counter("prefill_lane_depth", len(lane), t=clock.now())
         return dispatches, tokens_run
+
+    def _row_timeouts(self, book, clock, m, active, free_slots,
+                      slot_log, outputs, tr=None, acache=None,
+                      gcache=None):
+        """A RUNNING row past its deadline is evicted through the
+        path ``cancel_after`` uses (QoS-scheduled loops only)."""
+        with self._phase("timeouts"):
+            t = clock.now()
+            for sid in list(active):
+                dl = active[sid].req.deadline_time()
+                if dl is not None and t > dl + 1e-9:
+                    self._finish_paged(sid, book, clock, m, active,
+                                       free_slots, slot_log, outputs,
+                                       timeout=True, tr=tr,
+                                       acache=acache, gcache=gcache)
 
     def _lane_timeouts(self, lane, book, clock, m, free_slots,
                        slot_log, outputs, tr=None, acache=None,
@@ -3401,30 +3530,31 @@ class ServingEngine:
         reach (its prefill is atomic at admission), so only the
         QoS-scheduled async lane scans for it. The stream is empty:
         no token was ever produced."""
-        t = clock.now()
-        for e in list(lane):
-            dl = e.req.deadline_time()
-            if dl is None or t <= dl + 1e-9:
-                continue
-            lane.remove(e)
-            sid = e.req.rid
-            book.free(sid)
-            self._g_resident.set(float(len(book._refs)))
-            if acache is not None and e.req.adapter is not None:
-                acache.release(e.req.adapter, sid)
-                self._note_adapters(acache, m, t)
-            if gcache is not None and e.gname is not None:
-                gcache.release(e.gname, sid)
-            free_slots.append(e.slot)
-            free_slots.sort()
-            slot_log.append((round(t, 6), "release", sid, e.slot))
-            outputs[sid] = []
-            m.on_finish(sid, t, evicted=True, reason="timeout")
-            self._ctr_finished["timeout"].inc()
-            if tr is not None:
-                tr.add_span(sid, e.t_admit, t - e.t_admit,
-                            track="prefill_lane", timeout=True)
-            self._req_close(tr, e.req, t, "timeout", 0)
+        with self._phase("timeouts"):
+            t = clock.now()
+            for e in list(lane):
+                dl = e.req.deadline_time()
+                if dl is None or t <= dl + 1e-9:
+                    continue
+                lane.remove(e)
+                sid = e.req.rid
+                book.free(sid)
+                self._g_resident.set(float(len(book._refs)))
+                if acache is not None and e.req.adapter is not None:
+                    acache.release(e.req.adapter, sid)
+                    self._note_adapters(acache, m, t)
+                if gcache is not None and e.gname is not None:
+                    gcache.release(e.gname, sid)
+                free_slots.append(e.slot)
+                free_slots.sort()
+                slot_log.append((round(t, 6), "release", sid, e.slot))
+                outputs[sid] = []
+                m.on_finish(sid, t, evicted=True, reason="timeout")
+                self._ctr_finished["timeout"].inc()
+                if tr is not None:
+                    tr.add_span(sid, e.t_admit, t - e.t_admit,
+                                track="prefill_lane", timeout=True)
+                self._req_close(tr, e.req, t, "timeout", 0)
 
     @staticmethod
     def _lane_backlog_cost(lane, est) -> float:
@@ -3622,27 +3752,28 @@ class ServingEngine:
         token feed, page tables, lengths, adapter ids, grammar flat
         state ids — the inputs a decode_n dispatch is a pure function
         of."""
-        toks = np.zeros((self.slots,), np.int32)
-        pt = np.zeros((self.slots, self.W), np.int32)
-        lens = np.zeros((self.slots,), np.int32)
-        # per-slot adapter ids (0 = identity slot): built only when
-        # multi-model serving is on — this is the engine's hottest
-        # loop and single-model replays never read it
-        aids = np.zeros((self.slots,), np.int32) \
-            if acache is not None else None
-        # per-slot grammar flat ids (0 = the all-allow identity row):
-        # free rows and empty slots mask with row 0 by construction
-        gids = np.zeros((self.slots,), np.int32) \
-            if gcache is not None else None
-        for st in rows:
-            table = book.tables[st.req.rid]
-            pt[st.slot, :len(table)] = table
-            lens[st.slot] = book.lengths[st.req.rid]
-            toks[st.slot] = st.tok
-            if aids is not None:
-                aids[st.slot] = st.aslot
-            if gids is not None and st.gaut is not None:
-                gids[st.slot] = gcache.flat_id(st.gslot, st.gstate)
+        with self._phase("decode.build"):
+            toks = np.zeros((self.slots,), np.int32)
+            pt = np.zeros((self.slots, self.W), np.int32)
+            lens = np.zeros((self.slots,), np.int32)
+            # per-slot adapter ids (0 = identity slot): built only when
+            # multi-model serving is on — this is the engine's hottest
+            # loop and single-model replays never read it
+            aids = np.zeros((self.slots,), np.int32) \
+                if acache is not None else None
+            # per-slot grammar flat ids (0 = the all-allow identity row):
+            # free rows and empty slots mask with row 0 by construction
+            gids = np.zeros((self.slots,), np.int32) \
+                if gcache is not None else None
+            for st in rows:
+                table = book.tables[st.req.rid]
+                pt[st.slot, :len(table)] = table
+                lens[st.slot] = book.lengths[st.req.rid]
+                toks[st.slot] = st.tok
+                if aids is not None:
+                    aids[st.slot] = st.aslot
+                if gids is not None and st.gaut is not None:
+                    gids[st.slot] = gcache.flat_id(st.gslot, st.gstate)
         return toks, pt, lens, aids, gids
 
     @staticmethod
@@ -3682,7 +3813,7 @@ class ServingEngine:
             # fixed clock prices it exactly like a fresh dispatch, so
             # virtual-clock replays are byte-identical.
             stash = (ahst.emits, None, self._pools)
-            if clock.mode == "measured":
+            if clock.mode != "fixed":
                 # the overlapped device span started at dispatch, not
                 # at this serve — credit the hidden part to dev_wall
                 # so the host-overhead decomposition sees the overlap
@@ -3709,45 +3840,46 @@ class ServingEngine:
             tr, clock, "decode", _call, jitfn=self._p_decode_n,
             n=n, rows=len(rows),
             rids=[st.req.rid for st in rows], **attrs)
-        emits = np.asarray(emits)  # (n, slots) greedy tokens
-        t = clock.now()
-        for st in rows:
-            sid = st.req.rid
-            taken = 0
-            for k in range(n):
-                if len(st.out) >= st.eff or st.done:
-                    break
-                tok = int(emits[k, st.slot])
-                st.out.append(tok)
-                taken += 1
-                if st.gaut is not None:
-                    # the mask the device just applied came from
-                    # gstate; account it, then advance to the state
-                    # the NEXT turn will mask with
-                    mf = st.gaut.masked_frac(st.gstate)
-                    st.gmasked += mf
-                    m.on_grammar_tokens(1, mf)
-                    st.gstate = st.gaut.step(st.gstate, tok)
-                    if st.gaut.accepts_at(st.gstate):
+        with self._phase("decode.emit"):
+            emits = np.asarray(emits)  # (n, slots) greedy tokens
+            t = clock.now()
+            for st in rows:
+                sid = st.req.rid
+                taken = 0
+                for k in range(n):
+                    if len(st.out) >= st.eff or st.done:
+                        break
+                    tok = int(emits[k, st.slot])
+                    st.out.append(tok)
+                    taken += 1
+                    if st.gaut is not None:
+                        # the mask the device just applied came from
+                        # gstate; account it, then advance to the state
+                        # the NEXT turn will mask with
+                        mf = st.gaut.masked_frac(st.gstate)
+                        st.gmasked += mf
+                        m.on_grammar_tokens(1, mf)
+                        st.gstate = st.gaut.step(st.gstate, tok)
+                        if st.gaut.accepts_at(st.gstate):
+                            st.done = True
+                            m.on_grammar_accept(sid, t)
+                            if tr is not None:
+                                tr.instant(
+                                    "grammar_accept", t=t,
+                                    track=self._tenant_track(st.req),
+                                    rid=sid, schema=st.gname)
+                    if tok == self.eos_token_id:
                         st.done = True
-                        m.on_grammar_accept(sid, t)
-                        if tr is not None:
-                            tr.instant(
-                                "grammar_accept", t=t,
-                                track=self._tenant_track(st.req),
-                                rid=sid, schema=st.gname)
-                if tok == self.eos_token_id:
-                    st.done = True
-            st.tok = int(emits[-1, st.slot])
-            book.lengths[sid] += n  # all n K/V writes happened
-            if taken:
-                m.on_tokens(sid, t, taken)
-                self._ctr_tokens.inc(taken)
-            if st.done or len(st.out) >= st.eff:
-                self._finish_paged(sid, book, clock, m, active,
-                                   free_slots, slot_log, outputs,
-                                   tr=tr, acache=acache,
-                                   gcache=gcache)
+                st.tok = int(emits[-1, st.slot])
+                book.lengths[sid] += n  # all n K/V writes happened
+                if taken:
+                    m.on_tokens(sid, t, taken)
+                    self._ctr_tokens.inc(taken)
+                if st.done or len(st.out) >= st.eff:
+                    self._finish_paged(sid, book, clock, m, active,
+                                       free_slots, slot_log, outputs,
+                                       tr=tr, acache=acache,
+                                       gcache=gcache)
         if ahst is not None:
             self._dispatch_ahead_turn(ahst, book, active, acache, n)
 
@@ -3763,20 +3895,21 @@ class ServingEngine:
         be rewritten anyway) and the reserved page 0; a roster change
         discards the stash and re-dispatches. The donated pool buffer
         is rebound immediately, exactly like a synchronous call."""
-        ahst.clear()
-        nxt = sorted(active.values(), key=lambda s: s.slot)
-        if not nxt or any(st.spec for st in nxt):
-            return
-        toks, pt, lens, aids, _ = self._decode_batch(nxt, book, acache)
-        ahst.wall0 = time.perf_counter()
-        arr = self._arr
-        emits, _, self._pools = self._p_decode_n(
-            self._p_outer, self._p_layers, arr(toks), arr(pt),
-            arr(lens), self._pools, n,
-            **({} if acache is None else
-               {"lora": self._lora_arg(acache, aids)}))
-        ahst.emits = emits
-        ahst.fp = self._roster_fp(nxt, book)
+        with self._phase("decode.ahead"):
+            ahst.clear()
+            nxt = sorted(active.values(), key=lambda s: s.slot)
+            if not nxt or any(st.spec for st in nxt):
+                return
+            toks, pt, lens, aids, _ = self._decode_batch(nxt, book, acache)
+            ahst.wall0 = time.perf_counter()
+            arr = self._arr
+            emits, _, self._pools = self._p_decode_n(
+                self._p_outer, self._p_layers, arr(toks), arr(pt),
+                arr(lens), self._pools, n,
+                **({} if acache is None else
+                   {"lora": self._lora_arg(acache, aids)}))
+            ahst.emits = emits
+            ahst.fp = self._roster_fp(nxt, book)
 
     def _spec_decode_rows(self, rows, book, clock, m, active,
                           free_slots, slot_log, outputs,
@@ -3792,17 +3925,18 @@ class ServingEngine:
         K/V — in both pools — sits beyond the advanced length and is
         overwritten by later writes, the PR-1 rollback-free
         invariant."""
-        k = spst.cfg.n_draft
-        prev = np.zeros((self.slots,), np.int32)
-        toks = np.zeros((self.slots,), np.int32)
-        pt = np.zeros((self.slots, self.W), np.int32)
-        lens = np.zeros((self.slots,), np.int32)
-        for st in rows:
-            table = book.tables[st.req.rid]
-            pt[st.slot, :len(table)] = table
-            lens[st.slot] = book.lengths[st.req.rid]
-            toks[st.slot] = st.tok
-            prev[st.slot] = st.prev
+        with self._phase("decode.build"):
+            k = spst.cfg.n_draft
+            prev = np.zeros((self.slots,), np.int32)
+            toks = np.zeros((self.slots,), np.int32)
+            pt = np.zeros((self.slots, self.W), np.int32)
+            lens = np.zeros((self.slots,), np.int32)
+            for st in rows:
+                table = book.tables[st.req.rid]
+                pt[st.slot, :len(table)] = table
+                lens[st.slot] = book.lengths[st.req.rid]
+                toks[st.slot] = st.tok
+                prev[st.slot] = st.prev
         s_outer, s_layers = self._spec_parts[0], self._spec_parts[1]
         s_step = self._spec_parts[4]
 
@@ -3816,92 +3950,94 @@ class ServingEngine:
             tr, clock, "spec_decode", _call, jitfn=s_step, k=k,
             rows=len(rows),
             rids=[st.req.rid for st in rows], **self._tp_attr)
-        counts = np.asarray(counts)
-        cands = np.asarray(cands)
-        t = clock.now()
-        turn_prop = turn_acc = 0
-        for st in rows:
-            sid = st.req.rid
-            n = int(counts[st.slot])
-            cand = cands[st.slot]
-            taken = 0
-            for i in range(n + 1):
-                if len(st.out) >= st.eff or st.done:
-                    break
-                tok = int(cand[i])
-                st.out.append(tok)
-                taken += 1
-                if tok == self.eos_token_id:
-                    st.done = True
-            # position bookkeeping: all n+1 verified positions hold
-            # real K/V (position L took st.tok, L+1+i took d_i for
-            # i < n); the new last token t_n sits at position L+n+1,
-            # not yet written — exactly decode_n's lengths discipline
-            st.prev = int(cand[n - 1]) if n >= 1 else st.tok
-            st.tok = int(cand[n])
-            book.lengths[sid] += n + 1
-            st.sprop += k
-            st.sacc += n
-            turn_prop += k
-            turn_acc += n
-            if taken:
-                m.on_tokens(sid, t, taken)
-                self._ctr_tokens.inc(taken)
-            if st.done or len(st.out) >= st.eff:
-                self._finish_paged(sid, book, clock, m, active,
-                                   free_slots, slot_log, outputs,
-                                   tr=tr)
-        spst.note(len(rows), turn_prop, turn_acc)
-        m.on_spec(len(rows), turn_prop, turn_acc)
-        self._ctr_spec_rounds.inc(len(rows))
-        self._ctr_draft_proposed.inc(turn_prop)
-        self._ctr_draft_accepted.inc(turn_acc)
+        with self._phase("decode.emit"):
+            counts = np.asarray(counts)
+            cands = np.asarray(cands)
+            t = clock.now()
+            turn_prop = turn_acc = 0
+            for st in rows:
+                sid = st.req.rid
+                n = int(counts[st.slot])
+                cand = cands[st.slot]
+                taken = 0
+                for i in range(n + 1):
+                    if len(st.out) >= st.eff or st.done:
+                        break
+                    tok = int(cand[i])
+                    st.out.append(tok)
+                    taken += 1
+                    if tok == self.eos_token_id:
+                        st.done = True
+                # position bookkeeping: all n+1 verified positions hold
+                # real K/V (position L took st.tok, L+1+i took d_i for
+                # i < n); the new last token t_n sits at position L+n+1,
+                # not yet written — exactly decode_n's lengths discipline
+                st.prev = int(cand[n - 1]) if n >= 1 else st.tok
+                st.tok = int(cand[n])
+                book.lengths[sid] += n + 1
+                st.sprop += k
+                st.sacc += n
+                turn_prop += k
+                turn_acc += n
+                if taken:
+                    m.on_tokens(sid, t, taken)
+                    self._ctr_tokens.inc(taken)
+                if st.done or len(st.out) >= st.eff:
+                    self._finish_paged(sid, book, clock, m, active,
+                                       free_slots, slot_log, outputs,
+                                       tr=tr)
+            spst.note(len(rows), turn_prop, turn_acc)
+            m.on_spec(len(rows), turn_prop, turn_acc)
+            self._ctr_spec_rounds.inc(len(rows))
+            self._ctr_draft_proposed.inc(turn_prop)
+            self._ctr_draft_accepted.inc(turn_acc)
 
     def _finish_paged(self, sid, book, clock, m, active, free_slots,
                       slot_log, outputs, timeout: bool = False,
                       tr=None, acache=None, gcache=None):
-        st = active.pop(sid)
-        book.free(sid)
-        self._g_resident.set(float(len(book._refs)))
-        if acache is not None and st.req.adapter is not None:
-            # unpin: the adapter is RETAINED evictable (the next
-            # sharer hits), reclaimed only under bank pressure
-            acache.release(st.req.adapter, sid)
-            self._note_adapters(acache, m, clock.now())
-        if gcache is not None and st.gname is not None:
-            # same retention discipline as adapters: the automaton
-            # stays resident-evictable for the schema's next sharer
-            gcache.release(st.gname, sid)
-        free_slots.append(st.slot)
-        free_slots.sort()
-        slot_log.append((round(clock.now(), 6), "release", sid, st.slot))
-        outputs[sid] = st.out
-        r = st.req
-        evicted = (r.cancel_after is not None
-                   and st.eff == r.cancel_after
-                   and st.eff < r.max_new_tokens and not st.done)
-        # a deadline timeout is the same eviction path as client churn
-        # (cancel_after): stop decoding, free pages, mark evicted —
-        # only the recorded reason differs
-        t_fin = clock.now()
-        m.on_finish(sid, t_fin, evicted=evicted or timeout,
-                    reason="timeout" if timeout
-                    else ("cancel" if evicted else None))
-        outcome = "timeout" if timeout else (
-            "cancel" if evicted else "completed")
-        self._ctr_finished[outcome].inc()
-        if tr is not None:
-            tr.add_span(sid, st.t0, t_fin - st.t0,
-                        track=f"slot/{st.slot}", backend="paged")
-            if st.sprop > 0:
-                # per-request spec evidence for trace_report's
-                # accept=a/p waterfall column — emitted ONLY when the
-                # row actually ran spec rounds, so plain traces keep
-                # their event set exactly
-                tr.instant("spec", t=t_fin,
-                           track=self._tenant_track(r), rid=sid,
-                           proposed=st.sprop, accepted=st.sacc)
-        self._req_close(tr, r, t_fin, outcome, len(st.out))
+        with self._phase("finish", sid):
+            st = active.pop(sid)
+            book.free(sid)
+            self._g_resident.set(float(len(book._refs)))
+            if acache is not None and st.req.adapter is not None:
+                # unpin: the adapter is RETAINED evictable (the next
+                # sharer hits), reclaimed only under bank pressure
+                acache.release(st.req.adapter, sid)
+                self._note_adapters(acache, m, clock.now())
+            if gcache is not None and st.gname is not None:
+                # same retention discipline as adapters: the automaton
+                # stays resident-evictable for the schema's next sharer
+                gcache.release(st.gname, sid)
+            free_slots.append(st.slot)
+            free_slots.sort()
+            slot_log.append((round(clock.now(), 6), "release", sid, st.slot))
+            outputs[sid] = st.out
+            r = st.req
+            evicted = (r.cancel_after is not None
+                       and st.eff == r.cancel_after
+                       and st.eff < r.max_new_tokens and not st.done)
+            # a deadline timeout is the same eviction path as client churn
+            # (cancel_after): stop decoding, free pages, mark evicted —
+            # only the recorded reason differs
+            t_fin = clock.now()
+            m.on_finish(sid, t_fin, evicted=evicted or timeout,
+                        reason="timeout" if timeout
+                        else ("cancel" if evicted else None))
+            outcome = "timeout" if timeout else (
+                "cancel" if evicted else "completed")
+            self._ctr_finished[outcome].inc()
+            if tr is not None:
+                tr.add_span(sid, st.t0, t_fin - st.t0,
+                            track=f"slot/{st.slot}", backend="paged")
+                if st.sprop > 0:
+                    # per-request spec evidence for trace_report's
+                    # accept=a/p waterfall column — emitted ONLY when the
+                    # row actually ran spec rounds, so plain traces keep
+                    # their event set exactly
+                    tr.instant("spec", t=t_fin,
+                               track=self._tenant_track(r), rid=sid,
+                               proposed=st.sprop, accepted=st.sacc)
+            self._req_close(tr, r, t_fin, outcome, len(st.out))
 
     def session(self, *, tracer=None, replica: Optional[str] = None,
                 expect_churn: bool = False, role: str = "both",
@@ -4101,6 +4237,10 @@ class EngineSession:
         # into the router's census like handoff_stats
         self.handoff_resharded: Dict[str, int] = {}
         self.clock = eng._make_clock(replica or "engine")
+        # this session's host spans: every turn points the engine's
+        # ``_phase`` at them (one session drives an engine at a time)
+        self._w0 = eng._open_phases(self.clock)
+        self.phases = eng._phases
         self.tr = tracer
         self.slo = slo
         self.m = MetricsCollector(monitor=slo)
@@ -4809,36 +4949,51 @@ class EngineSession:
             targets.append(min(future))
         return min(targets) if targets else None
 
+    def _wait(self, t: float):
+        """An ``idle_wait`` of this session's own, between its turns."""
+        self.eng._phases = self.phases
+        self.eng._idle_wait(self.clock, t)
+
     def _turn(self) -> bool:
         """One scheduler turn: admission attempt + decode chunk —
         run()'s / _run_scheduled's loop body minus arrival ingestion
         (the router owns arrivals)."""
         eng = self.eng
+        eng._phases = self.phases
+        with eng._phase("turn"):
+            return self._turn_body()
+
+    def _turn_body(self) -> bool:
+        """``_turn`` under its ``turn`` span."""
+        eng = self.eng
         clock, tr, m = self.clock, self.tr, self.m
-        now = clock.now()
-        m.on_queue_depth(now, self.queued())
-        # decode-slot utilization (busy slots / capacity), sampled
-        # once per turn like queue depth: the live gauge any scrape
-        # reads, and — through the collector — the SLO-watchable
-        # `replica_busy_frac` signal the autoscaler's drain decision
-        # stands on (`ThresholdRule(signal="replica_busy_frac")`)
-        busy = (eng.slots - self.free_slot_count()) / eng.slots
-        m.on_busy_frac(now, busy)
-        if self._g_busy is not None:
-            self._g_busy.set(busy)
-        if tr is not None:
-            tr.counter("queue_depth", self.queued(), t=now)
+        with eng._phase("intake"):
+            now = clock.now()
+            m.on_queue_depth(now, self.queued())
+            # decode-slot utilization (busy slots / capacity), sampled
+            # once per turn like queue depth: the live gauge any scrape
+            # reads, and — through the collector — the SLO-watchable
+            # `replica_busy_frac` signal the autoscaler's drain
+            # decision stands on
+            # (`ThresholdRule(signal="replica_busy_frac")`)
+            busy = (eng.slots - self.free_slot_count()) / eng.slots
+            m.on_busy_frac(now, busy)
+            if self._g_busy is not None:
+                self._g_busy.set(busy)
+            if tr is not None:
+                tr.counter("queue_depth", self.queued(), t=now)
         progressed = False
-        if self.import_queue:
-            # adopt deliverable handoffs first, so the imported row
-            # joins this turn's decode batch
-            progressed |= self._import_handoffs()
-        if self.sched is not None:
-            progressed |= self._shed(self.sched.shed_expired(now))
-            if self.sched.waiting() and self._ready():
-                progressed |= self._qos_wave(now)
-        elif self.waiting and self._ready():
-            progressed |= self._fifo_wave()
+        with eng._phase("admit"):
+            if self.import_queue:
+                # adopt deliverable handoffs first, so the imported
+                # row joins this turn's decode batch
+                progressed |= self._import_handoffs()
+            if self.sched is not None:
+                progressed |= self._shed(self.sched.shed_expired(now))
+                if self.sched.waiting() and self._ready():
+                    progressed |= self._qos_wave(now)
+            elif self.waiting and self._ready():
+                progressed |= self._fifo_wave()
         if self.active:
             t0 = clock.now()
             try:
@@ -4866,18 +5021,11 @@ class EngineSession:
                 # turn completed or aborted — an expired row must not
                 # survive an extra chunk just because another slot's
                 # fault forfeited this turn
-                t = clock.now()
-                for sid in list(self.active):
-                    dl = self.active[sid].req.deadline_time()
-                    if dl is not None and t > dl + 1e-9:
-                        eng._finish_paged(sid, self.book, clock, m,
-                                          self.active,
-                                          self.free_slots,
-                                          self.slot_log,
-                                          self.outputs,
-                                          timeout=True, tr=tr,
-                                          acache=self.acache,
-                                          gcache=self.gcache)
+                eng._row_timeouts(self.book, clock, m, self.active,
+                                  self.free_slots, self.slot_log,
+                                  self.outputs, tr=tr,
+                                  acache=self.acache,
+                                  gcache=self.gcache)
             progressed = True
         if self.lane:
             sink = self._handoff_sink if self.role == "prefill" \
@@ -4896,17 +5044,9 @@ class EngineSession:
                                    acache=self.acache,
                                    gcache=self.gcache)
             progressed = True
-        eng._quant_turn(self.book, m, clock, tr, self.qst)
-        self.inv_ok &= self.book.census_ok()
-        if self.acache is not None:
-            self.a_inv_ok &= self.acache.census_ok()
-        if self.gcache is not None:
-            self.g_inv_ok &= self.gcache.census_ok()
-        if eng._ledger is not None:
-            eng._ledger.sample_occupancy(
-                clock.label, book=self.book, acache=self.acache,
-                gcache=self.gcache,
-                arena=getattr(self.book, "_arena", None))
+        self.inv_ok, self.a_inv_ok, self.g_inv_ok = eng._turn_tail(
+            self.book, m, clock, tr, self.qst, self.acache,
+            self.gcache, (self.inv_ok, self.a_inv_ok, self.g_inv_ok))
         return progressed
 
     def _route_ctx(self, wave):
@@ -5045,7 +5185,7 @@ class EngineSession:
         while True:
             if self.queued() == 0 and not self.active \
                     and not self.lane and not self.import_queue:
-                self.clock.advance_to(t)
+                self._wait(t)
                 return
             if self.clock.now() >= t - 1e-12:
                 return
@@ -5053,9 +5193,9 @@ class EngineSession:
             if not progressed and not self.active and not self.lane:
                 target = self._idle_target()
                 if target is not None and target <= t:
-                    self.clock.advance_to(target)
+                    self._wait(target)
                 else:
-                    self.clock.advance_to(t)
+                    self._wait(t)
                     return
 
     def finish(self) -> ServeResult:
@@ -5082,8 +5222,11 @@ class EngineSession:
                 target = self._idle_target()
                 if target is None:
                     break  # everything left this turn was shed
-                self.clock.advance_to(target)
+                self._wait(target)
         ServingEngine._stitch_resumes(self.outputs, self.hst)
+        self.eng._phases = self.phases
+        if self.tr is not None and self.clock.mode == "wall":
+            self.phases.to_tracer(self.tr, self.clock.t_zero)
         self._finished = ServeResult(
             policy=self.eng.policy.name, outputs=self.outputs,
             metrics=self.m, decisions=self.decisions,
@@ -5107,6 +5250,8 @@ class EngineSession:
                         else self.spst.stats()),
             kv_quant_stats=self.eng._quant_result(self.book,
                                                   self.qst),
+            overhead=self.eng._overhead_row(self.clock, self._w0,
+                                            whole=False),
             hostmem_stats=self.eng._hostmem_result(self.book,
                                                    self.hst),
             pages_spilled=(

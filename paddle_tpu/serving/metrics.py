@@ -363,6 +363,18 @@ class MetricsCollector:
         self._req.pop(rid, None)
 
     # --- views -----------------------------------------------------------
+    def token_times(self, rid: str) -> List[float]:
+        """One stamp per emitted token of ``rid``, on the run's clock
+        (a copy; empty for a request this collector never saw)."""
+        r = self._req.get(rid)
+        return [] if r is None else list(r.token_times)
+
+    def admit_time(self, rid: str) -> Optional[float]:
+        """When ``rid`` was admitted, on the run's clock (None while
+        it waits, or for a request this collector never saw)."""
+        r = self._req.get(rid)
+        return None if r is None else r.admit
+
     def request_rows(self) -> List[dict]:
         """Every request's view (``request()`` dict plus its ``rid``),
         arrival-ordered — the public surface a cluster rollup

@@ -342,7 +342,7 @@ def test_dispatch_ahead_measured_overhead_row():
             decode_chunk=4, dispatch_ahead=ahead)
     for ahead in (False, True):
         ov = eng(ahead).run(trace).overhead
-        assert set(ov) == {"run_wall_s", "device_wall_s",
+        assert set(ov) >= {"run_wall_s", "device_wall_s",
                            "engine_host_frac"}
         assert 0.0 <= ov["engine_host_frac"] <= 1.0
         assert ov["device_wall_s"] <= ov["run_wall_s"]
