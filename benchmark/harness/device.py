@@ -60,13 +60,17 @@ def device_info(devices) -> dict:
 
 
 class Marks:
-    """Seconds between named points of set-up, for the information line."""
+    """Seconds between named points of set-up, and the fullest chip's memory
+    peak at each, for the information line: a process's peak never falls
+    again, so the marks say which part set it."""
 
-    def __init__(self, t0: float):
-        self.last, self.parts = t0, {}
+    def __init__(self, t0: float, devices=()):
+        self.last, self.parts, self.peak_bytes, self.devices = t0, {}, {}, devices
 
     def add(self, name: str):
         import time
         now = time.perf_counter()
         self.parts[name] = round(self.parts.get(name, 0.0) + now - self.last, 3)
         self.last = now
+        if self.devices:
+            self.peak_bytes[name] = memory_peak_bytes(self.devices)

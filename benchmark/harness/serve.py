@@ -6,15 +6,18 @@ import time
 
 import jax
 
-from . import device, program, serve_check, traffic
-from .. import flops
+from . import device, serve_check, traffic, weights as W
 from .checks import Checks
 from .clock import WallClock
 from .gclog import GcLog
-from .trace import TraceWindow, custom_calls
+from .trace import TraceWindow, custom_calls, serving_stretch
 
 
-def build_engine(config: dict, seed: int, trace_window=None, marks=None):
+class NoTrace(RuntimeError):
+    pass
+
+
+def build_engine(family, config: dict, seed: int, trace_window=None, marks=None):
     from paddle_tpu.serving import ServingEngine
 
     class BenchEngine(ServingEngine):
@@ -27,9 +30,9 @@ def build_engine(config: dict, seed: int, trace_window=None, marks=None):
 
     mark = (lambda name: None) if marks is None else marks.add
     args = dict(config["engine"])
-    net = program.empty_model(config["model"], args["max_len"])
+    net = family.serving_program(config["model"], args)
     mark("program_model_object")
-    program.load_weights(net, config["model"], seed)
+    family.load_weights(net, W.make_weights(family, config["model"], seed))
     jax.block_until_ready(net.tree_flatten_params())
     mark("weights_from_seed")
     eng = BenchEngine(net, clock="measured", **args)
@@ -44,8 +47,13 @@ def to_requests(reqs: list[dict]):
                     prefix_group=r["prefix_group"]) for r in reqs]
 
 
-def observe(reqs, res, clock, model, peak) -> dict:
-    """What the window did, in plain lists: every reader works from this."""
+def observe(reqs, res, clock, family, model, peak) -> dict:
+    """What the window did, in plain lists: every reader works from this.
+    ``engine_record`` hands on, untouched, what the engine kept for that
+    request beyond tokens and stamps (``ServeResult.request_records``, where
+    an engine has it): a family whose step does not yield one token a
+    sequence counts its passes and lines its tokens up from it."""
+    records = getattr(res, "request_records", None) or {}
     rows = []
     for r in reqs:
         rec = res.metrics._req.get(r["rid"])
@@ -58,7 +66,8 @@ def observe(reqs, res, clock, model, peak) -> dict:
                      "admit": None if rec is None else rec.admit,
                      "token_times": stamps, "done": bool(done),
                      "cached": int(res.prefix_cached.get(r["rid"], 0)),
-                     "output": [int(t) for t in out]})
+                     "output": [int(t) for t in out],
+                     "engine_record": records.get(r["rid"])})
     first_due = min(r["arrival"] for r in rows)
     last_token = max((t for r in rows for t in r["token_times"]), default=first_due)
     window_s = last_token - first_due
@@ -66,10 +75,8 @@ def observe(reqs, res, clock, model, peak) -> dict:
     for r in rows:
         if not r["token_times"]:
             continue
-        new = r["prompt_len"] - r["cached"]
-        work += flops.forward_flops(model, new, r["cached"], head_tokens=1)
-        n_dec = len(r["token_times"]) - 1
-        work += flops.forward_flops(model, n_dec, r["prompt_len"])
+        for part in family.request_flops(model, r):
+            work += part
     return {"kind": "serve", "requests": rows, "spans": list(clock.spans),
             "first_due_s": first_due, "window_s": window_s,
             "oversleep_s": list(clock.oversleep_s),
@@ -104,15 +111,15 @@ def stalls(clock, gc_log, top=5) -> dict:
                 for g, d, at in sorted(gc_log.events, key=lambda e: -e[1])[:top]])}
 
 
-def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
+def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
         control=None, fault=None) -> dict:
-    config, mix = cell["config_spec"], cell["traffic_spec"]
+    family, config, mix = cell["family"], cell["config_spec"], cell["traffic_spec"]
     model = config["model"]
     vocab = model["vocab_size"]
-    marks = device.Marks(t_process)
-    tw = TraceWindow(spec.root.parent / ".bench_trace", seconds) if trace else None
-    marks.add("imports")
-    eng = build_engine(config, seed, tw, marks)
+    marks = device.Marks(t_setup, devices)
+    marks.add("python_imports")
+    tw = TraceWindow(spec.root.parent / ".bench_trace") if trace else None
+    eng = build_engine(family, config, seed, tw, marks)
     warm = traffic.warmup_requests(mix, vocab, eng.chunk_C)
     eng.run(to_requests(warm))
     marks.add("warm_up")
@@ -121,9 +128,11 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
     gc.collect()
     marks.add("traffic")
     compiles_before = counter.count
-    setup_s = time.perf_counter() - t_process
+    setup_s = time.perf_counter() - t_setup
 
     if tw is not None:
+        tw.place(*serving_stretch([r["arrival"] for r in reqs], int(mix.get("burst", 1)),
+                                  seconds))
         tw.arm()
     gc_log = GcLog()
     res = eng.run(trace_reqs)
@@ -133,7 +142,7 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
     compiles_in_window = counter.count - compiles_before
     clock = eng.bench_clock
     peak = spec.peak(devices[0].device_kind) if devices[0].platform == "tpu" else None
-    obs = observe(reqs, res, clock, model, peak)
+    obs = observe(reqs, res, clock, family, model, peak)
     if fault == "token_altered":    # tests only: a served token changed where it is produced
         victim = max(obs["requests"], key=lambda r: r["prompt_len"] + len(r["output"]))
         victim["output"][len(victim["output"]) // 2] ^= 1
@@ -148,8 +157,8 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
     t_ref = time.perf_counter()
     sample = serve_check.pick_sample(obs["requests"], reqs, seed, mix)
     out_rows = int(mix["output"]["max"])
-    gaps = serve_check.served_gaps(model, seed, sample, serve_check.pad_length(mix),
-                                   out_rows=out_rows)
+    pad_to = family.pad_length(mix)
+    gaps = serve_check.served_gaps(family, model, seed, sample, pad_to, out_rows=out_rows)
     checks = Checks(cell["limits"])
     checks.add("served_gap_max", gaps["max"])
     checks.add("served_gap_mean", gaps["mean"])
@@ -161,6 +170,7 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
             "samples": {"requests": len(reqs), "token_gaps": sum(
                 max(len(r["token_times"]) - 1, 0) for r in obs["requests"])},
             "setup_s": setup_s, "setup_parts": marks.parts,
+            "memory_peak_at": marks.peak_bytes,
             "reference_s": time.perf_counter() - t_ref,
             "tokens_compared": gaps["tokens"], "requests_compared": len(sample),
             "ref_logit_absmax": gaps["ref_absmax"],
@@ -170,18 +180,33 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
             "wall_minus_engine_s": obs["window_s"] - obs["engine_dev_wall_s"],
             "stalls": stalled}
     if control is not None:     # readings for the limits, never in a benchmark run
-        lower = serve_check.served_gaps(model, seed, sample,
-                                        serve_check.pad_length(mix), control, out_rows)
+        lower = serve_check.served_gaps(family, model, seed, sample, pad_to, control, out_rows)
         info["control"] = {"served_gap_max": lower["max"], "served_gap_mean": lower["mean"]}
     if tw is not None:
         t_red = time.perf_counter()
         obs["device_trace"] = tw.reduce()
+        if obs["device_trace"] is None:
+            raise NoTrace(no_trace_message(tw, clock, reqs))
         obs["trace_interval"] = tw.interval
         info["trace_reduce_s"] = time.perf_counter() - t_red
         info["trace_costs"] = tw.costs
-        if obs["device_trace"]:
-            info["custom_calls"] = custom_calls(obs["device_trace"])
+        info["trace_stretch"] = {"placed": [tw.start_at, tw.stop_at],
+                                 "ran": [x if x != float("inf") else None for x in tw.interval],
+                                 "arrival_wait_s": obs["device_trace"]["arrival_wait_s"]}
+        info["custom_calls"] = custom_calls(obs["device_trace"])
     return {"obs": obs, "checks": checks, "info": info,
             "attempted": len(reqs),
             "failed": sum(not r["done"] for r in obs["requests"]),
             "memory_peak_bytes": memory_peak}
+
+
+def no_trace_message(tw, clock, reqs) -> str:
+    """Why a traced stretch caught no device operation, with what places
+    it: the stretch, the calls' times and the arrivals' times."""
+    r3 = lambda xs: [round(x, 3) for x in xs]
+    starts = [a for _, a, _, _ in clock.spans]
+    ran = None if tw.interval is None else r3(x for x in tw.interval if x is not None)
+    return ("the traced stretch caught no device operation: placed at "
+            f"{r3([tw.start_at, tw.stop_at])} s of the window, profiler ran over {ran}; "
+            f"{len(starts)} calls, the first at {r3(starts[:3])} and the last at "
+            f"{r3(starts[-3:])} s; arrivals due at {r3(sorted({r['arrival'] for r in reqs}))} s")

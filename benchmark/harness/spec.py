@@ -16,13 +16,34 @@ ROOT = Path(__file__).resolve().parents[1]          # benchmark/
 REPO = ROOT.parent
 
 
-MODEL_KEYS = ("vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
-              "num_attention_heads", "num_key_value_heads", "head_dim", "rms_norm_eps",
-              "rope_theta", "tie_word_embeddings", "sliding_window")
-
-
 class SpecError(ValueError):
     pass
+
+
+def load_module(path: Path):
+    """A family, a reference or a metric reader is a file of its own, loaded
+    by its path, so that a root elsewhere (a test's, a later PR's) brings
+    its own."""
+    path = Path(path).resolve()
+    key = f"_bench_file_{abs(hash(str(path)))}"
+    if key not in sys.modules:
+        if not path.is_file():
+            raise SpecError(f"missing benchmark file: {path}")
+        loaded = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(loaded)
+        sys.modules[key] = mod
+        try:
+            loaded.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return sys.modules[key]
+
+
+def reference_module(family_file: str, name: str):
+    """``benchmark/reference/<name>.py`` of the root that holds the family's
+    own file: how a family names its plain reference."""
+    return load_module(Path(family_file).resolve().parents[1] / "reference" / f"{name}.py")
 
 
 def _load(path: Path) -> dict:
@@ -44,11 +65,24 @@ class Spec:
     def cell(self, name: str) -> dict:
         cell = _load(self.root / "workloads" / f"{name}.json")
         cell["name"] = name
-        config = _load(self.root / "configs" / f"{cell['config']}.json")
-        config["model"] = {k: config[k] for k in MODEL_KEYS}
+        path = self.root / "configs" / f"{cell['config']}.json"
+        config = _load(path)
+        if "family" not in config:
+            raise SpecError(f"{path} names no family")
+        family = self.family(config["family"])
+        missing = [k for k in family.MODEL_KEYS if k not in config]
+        if missing:
+            raise SpecError(f"{path} lacks {missing}, which family {config['family']!r} reads")
+        config["model"] = {k: config[k] for k in family.MODEL_KEYS}
         cell["config_spec"] = config
+        cell["family"] = family
         cell["traffic_spec"] = _load(self.root / "traffic" / f"{cell['traffic']}.json")
         return cell
+
+    def family(self, name: str):
+        """All that depends on the model's shape: ``families/<name>.py``
+        (benchmark/README.md lists what it has to give)."""
+        return load_module(self.root / "families" / f"{name}.py")
 
     # -- metrics ----------------------------------------------------------
     def _reports(self, entry: dict, cell: str, e2e_of_cell: set | None) -> bool:
@@ -72,23 +106,9 @@ class Spec:
         """The metric's reader: ``reader(obs, params) -> float | None``."""
         spec = self.metric_file(name)
         mod_name, _, fn_name = spec["reader"].partition(":")
-        fn = getattr(self._reader_module(mod_name), fn_name)
+        fn = getattr(load_module(self.root / "metrics" / "readers" / f"{mod_name}.py"), fn_name)
         params = spec.get("params", {})
         return lambda obs: fn(obs, params)
-
-    def _reader_module(self, mod_name: str):
-        """A reader is a file of its own, loaded by its path, so that a root
-        elsewhere (a test's, a later PR's) brings its own."""
-        path = self.root / "metrics" / "readers" / f"{mod_name}.py"
-        key = f"_bench_reader_{abs(hash(str(path)))}"
-        if key not in sys.modules:
-            if not path.is_file():
-                raise SpecError(f"missing metric reader: {path}")
-            loaded = importlib.util.spec_from_file_location(key, path)
-            mod = importlib.util.module_from_spec(loaded)
-            sys.modules[key] = mod
-            loaded.loader.exec_module(mod)
-        return sys.modules[key]
 
     def read_metrics(self, entries: list[dict], obs: dict) -> dict:
         out = {}

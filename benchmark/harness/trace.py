@@ -1,11 +1,14 @@
 """The device trace of a short stretch of the window, and its reduction.
 
 ``TraceWindow`` starts and stops JAX's profiler from the measuring thread
-at fixed offsets into the window.  ``reduce_xplane`` turns the profiler's
-``.xplane.pb`` into the few tables the per-layer readers use: busy seconds
-(the union of the intervals in which an operation ran, averaged over the
-chips), seconds per (program, operation), and the idle gaps by what the
-host was doing.
+over a stretch of the window that ``serving_stretch`` places from the
+schedule of arrivals alone, before the window starts: on seconds in which
+work is certain whatever the program's speed.  ``reduce_xplane`` turns the
+profiler's ``.xplane.pb`` into the few tables the per-layer readers use:
+busy seconds (the union of the intervals in which an operation ran,
+averaged over the chips), seconds per (program, operation), and the idle
+gaps by what the host was doing, the engine's waits for the next arrival
+named apart.
 """
 from __future__ import annotations
 
@@ -19,26 +22,45 @@ from collections import defaultdict
 
 TRACE_SECONDS = 4.0
 HOST_PREFIX = "bench:"
+ARRIVAL_WAIT = "engine:idle_wait"       # the engine's span around a wait for the next arrival
+WAITING = "engine waiting for an arrival"
+
+
+def serving_stretch(arrivals: list[float], burst: int, seconds: float,
+                    trace_seconds: float = TRACE_SECONDS) -> tuple[float, float]:
+    """(start, stop) of the traced stretch on the window's clock, from the
+    schedule alone.  One arrival at a time: the last ``trace_seconds`` of
+    arrivals, so that stopping the profiler stalls only the drain.  Bursts:
+    from the due time of the last burst, whose work no program can have done
+    before; a fast program has drained every earlier burst long before the
+    arrivals end and would make no call there at all."""
+    last = max(arrivals)
+    if burst > 1:
+        return last, last + trace_seconds
+    return min(max(0.0, seconds - trace_seconds), last), seconds
 
 
 class TraceWindow:
-    def __init__(self, directory, seconds: float, trace_seconds: float = TRACE_SECONDS):
+    def __init__(self, directory):
         self.dir = str(directory)
-        self.stop_at = seconds                  # the stretch ends with the arrivals,
-        self.start_at = max(0.0, seconds - trace_seconds)   # so stopping stalls only the drain
+        self.start_at, self.stop_at = 0.0, float("inf")     # a serving window places them
         self.costs = {}
         self.active = False
         self.done = False
         self.armed = False
         self.interval = None        # (start, stop) on the window's clock
 
+    def place(self, start_at: float, stop_at: float):
+        self.start_at, self.stop_at = start_at, stop_at
+
     def arm(self):
         shutil.rmtree(self.dir, ignore_errors=True)
         self.armed = True
 
     def tick(self, now: float):
-        """Called at every call boundary of a serving window: starts and
-        stops the profiler from the measuring thread."""
+        """Called at every call boundary of a serving window: the first call
+        at or after ``start_at`` starts the profiler, the first at or after
+        ``stop_at`` stops it (``finish`` does where the engine is done first)."""
         if self.done or not self.armed:
             return
         if not self.active and now >= self.start_at:
@@ -96,6 +118,31 @@ def _union(intervals):
     return busy, gaps
 
 
+def name_gaps(gaps, call_spans, wait_spans) -> dict:
+    """Idle gaps ``(start, end)`` by what the host was doing, in the gaps'
+    own unit.  The part of a gap that lies inside one of ``wait_spans`` (the
+    engine waiting for the next arrival: sorted, not overlapping) is named
+    so: a program that has nothing to do is not a lazy one.  The rest of the
+    gap goes to the benchmark's call span ``(start, end, kind)`` that holds
+    the gap's midpoint, or to the host between two calls."""
+    call_starts = [c[0] for c in call_spans]
+    wait_ends = [w[1] for w in wait_spans]
+    named = defaultdict(float)
+    for a, b in gaps:
+        waited = 0
+        for wa, wb in wait_spans[bisect_right(wait_ends, a):]:
+            if wa >= b:
+                break
+            waited += min(b, wb) - max(a, wa)
+        if waited > 0:
+            named[WAITING] += waited
+        mid = (a + b) / 2
+        i = bisect_right(call_starts, mid) - 1
+        inside = i >= 0 and mid < call_spans[i][1]
+        named[f"in {call_spans[i][2]} call" if inside else "host between calls"] += b - a - waited
+    return {k: v for k, v in named.items() if v > 0}
+
+
 CONTAINERS = ("while", "conditional", "call")
 
 
@@ -128,7 +175,7 @@ def _short(name: str) -> str:
 def reduce_xplane(path: str) -> dict:
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
-    devices, host_spans = [], []
+    devices, host_spans, wait_spans = [], [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {ln.name: ln for ln in plane.lines}
@@ -140,10 +187,12 @@ def reduce_xplane(path: str) -> dict:
                     if ev.name.startswith(HOST_PREFIX):
                         host_spans.append((ev.start_ns, ev.start_ns + ev.duration_ns,
                                            ev.name[len(HOST_PREFIX):]))
+                    elif ev.name == ARRIVAL_WAIT:
+                        wait_spans.append((ev.start_ns, ev.start_ns + ev.duration_ns))
     if not devices:
         return None
     host_spans.sort()
-    host_starts = [s[0] for s in host_spans]
+    wait_spans.sort()
     busy_all, window_all = [], []
     per_op = defaultdict(lambda: [0.0, 0])       # (module, op) -> [seconds, count]
     per_module = defaultdict(lambda: [0.0, 0])
@@ -174,12 +223,8 @@ def reduce_xplane(path: str) -> dict:
         busy_all.append(busy_ns * 1e-9)
         window_all.append((hi - lo) * 1e-9)
         if lines is devices[0]:
-            for a, b in gaps:
-                mid = (a + b) / 2
-                i = bisect_right(host_starts, mid) - 1
-                inside = i >= 0 and mid < host_spans[i][1]
-                what = f"in {host_spans[i][2]} call" if inside else "host between calls"
-                gap_by_host[what] += (b - a) * 1e-9
+            for what, ns in name_gaps(gaps, host_spans, wait_spans).items():
+                gap_by_host[what] += ns * 1e-9
     if not busy_all:
         return None
     ops = sorted(((f"{m}/{o}", s, n) for (m, o), (s, n) in per_op.items()),
@@ -187,6 +232,7 @@ def reduce_xplane(path: str) -> dict:
     return {"busy_s": sum(busy_all) / len(busy_all),
             "window_s": sum(window_all) / len(window_all),
             "chips_traced": len(busy_all),
+            "arrival_wait_s": gap_by_host.get(WAITING, 0.0),
             "ops": [{"name": n, "seconds": s, "count": c} for n, s, c in ops],
             "modules": {k: {"seconds": v[0], "count": v[1]} for k, v in per_module.items()},
             "idle_gaps": sorted(gap_by_host.items(), key=lambda kv: -kv[1])}

@@ -1,4 +1,4 @@
-"""A training cell: the compiled step of ``llama_train_step_factory``.
+"""A training cell: the compiled step its configuration's family builds.
 
 Set-up builds one object, the compiled step with its state, drives it from
 the seed through its first steps on the window's own call and feed, and
@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import device, program, train_check, weights as W
-from .. import flops
+from . import device, train_check, weights as W
 from .checks import Checks
 from .gclog import GcLog
 from .trace import TraceWindow, custom_calls
@@ -40,24 +39,19 @@ class Trainer:
     """The compiled step, its state and its feed: one object for set-up's
     first steps and for the window."""
 
-    def __init__(self, config: dict, job: dict, seed: int, devices, fault=None, marks=None):
-        from paddle_tpu.models.nlp.llama import llama_train_step_factory, param_shardings
-        self.model, self.job, self.seed = config["model"], job, seed
+    def __init__(self, family, config: dict, job: dict, seed: int, devices, fault=None,
+                 marks=None):
+        self.family, self.model, self.job, self.seed = family, config["model"], job, seed
         self.mesh = build_mesh(devices, job)
         mark = (lambda name: None) if marks is None else marks.add
-        net = program.empty_model(self.model, job["seq"])
+        net = family.training_program(self.model, job)
         mark("program_model_object")
-        shardings = param_shardings(net, self.mesh)
-        program.load_weights(net, self.model, seed, shardings)
+        shardings = family.param_shardings(net, self.mesh)
+        family.load_weights(net, W.make_weights(family, self.model, seed, shardings))
         jax.block_until_ready(net.tree_flatten_params())
         mark("weights_from_seed")
-        hp = job["optimizer"]
-        self.params, self.opt, self.step, batch_sh = llama_train_step_factory(
-            net, self.mesh, learning_rate=hp["learning_rate"],
-            weight_decay=hp["weight_decay"], beta1=hp["beta1"], beta2=hp["beta2"],
-            eps=hp["eps"], accum_dtype=jnp.dtype(job["moments_dtype"]),
-            remat=job["remat"])
-        program.drop_weights(net)       # the step holds its own copy
+        self.params, self.opt, self.step, batch_sh = family.train_step(net, self.mesh, job)
+        family.drop_weights(net)        # the step holds its own copy
         mark("step_factory")
         tokens, labels = W.make_batches(
             seed, job["batch"], job["seq"], self.model["vocab_size"], batch_sh)
@@ -139,13 +133,13 @@ def traced_window(trainer: Trainer, tw: TraceWindow, steps: int):
     return t0, done_at, losses
 
 
-def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
+def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
         fault=None, optional_checks=True, run_ahead=RUN_AHEAD, freeze=True) -> dict:
-    config, job = cell["config_spec"], cell["traffic_spec"]
+    family, config, job = cell["family"], cell["config_spec"], cell["traffic_spec"]
     model = config["model"]
-    marks = device.Marks(t_process)
-    marks.add("imports")
-    trainer = Trainer(config, job, seed, devices, fault, marks)
+    marks = device.Marks(t_setup, devices)
+    marks.add("python_imports")
+    trainer = Trainer(family, config, job, seed, devices, fault, marks)
     first = train_check.first_steps(trainer, CHECK_STEPS, optional_checks, marks)
     for _ in range(RUN_AHEAD + 1):      # step 4 onward: the state as the window finds it
         trainer.advance().block_until_ready()
@@ -154,11 +148,11 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
         gc.freeze()
     marks.add("steps_to_window")
     compiles_before = counter.count
-    setup_s = time.perf_counter() - t_process
+    setup_s = time.perf_counter() - t_setup
 
     gc_log = GcLog()
     if trace:
-        tw = TraceWindow(spec.root.parent / ".bench_trace", seconds)
+        tw = TraceWindow(spec.root.parent / ".bench_trace")
         t0, done_at, losses = traced_window(trainer, tw, TRACED_STEPS)
     else:
         t0, done_at, losses = window(trainer, seconds, run_ahead)
@@ -174,7 +168,8 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
     obs = {"kind": "train", "steps": len(done_at), "window_s": window_s,
            "step_ends_s": [t - t0 for t in done_at], "step_stats": stats,
            "tokens_per_step": job["batch"] * job["seq"], "chips": chips,
-           "model_flops": len(done_at) * flops.train_step_flops(model, job["batch"], job["seq"]),
+           "model_flops": len(done_at) * family.train_step_flops(model, job["batch"],
+                                                                 job["seq"]),
            "peak": peak, "setup_s": setup_s, "model": model, "job": job}
 
     mesh = trainer.mesh
@@ -182,13 +177,14 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_process,
     gc.unfreeze()
     gc.collect()
     t_ref = time.perf_counter()
-    numbers, extra = train_check.compare(model, job, seed, first, mesh)
+    numbers, extra = train_check.compare(family, model, job, seed, first, mesh)
     checks = Checks(cell["limits"])
     for name, value in numbers.items():
         checks.add(name, value)
     checks.add("loss_not_finite", sum(not np.isfinite(x) for x in losses + first["losses"]))
     checks.add("compiles_in_window", compiles_in_window)
     info = {"window_s": window_s, "setup_s": setup_s, "setup_parts": marks.parts,
+            "memory_peak_at": marks.peak_bytes,
             "reference_s": time.perf_counter() - t_ref, "steps": stats,
             "losses_first": first["losses"], "ref_losses": extra["ref_losses"],
             "last_loss": losses[-1], "worst_leaves": extra["worst_leaves"],

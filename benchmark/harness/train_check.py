@@ -18,29 +18,28 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import weights as W
-from ..reference import mistral as R
 
 NEGLIGIBLE_GRAD = 1e-3     # of the median leaf's: such a leaf moves by round-off alone
 
 
-def _leaf_norms(tree):
-    return {k: R._norm(v) for k, v in tree.items()}
+def _leaf_norms(family, tree):
+    return {k: family.R.leaf_norm(v) for k, v in tree.items()}
 
 
-@partial(jax.jit, static_argnums=0)
-def _change_norms_of(model_items, key, params):
+@partial(jax.jit, static_argnums=(0, 1))
+def _change_norms_of(family, model_items, key, params):
     model = dict(model_items)
-    return {k: R._norm(p.astype(jnp.float32)
-                       - W.initial_leaf(model, key, k).astype(jnp.float32))
+    return {k: family.R.leaf_norm(p.astype(jnp.float32)
+                                  - W.initial_leaf(family, model, key, k).astype(jnp.float32))
             for k, p in params.items()}
 
 
-def _change_norms(model, seed):
+def _change_norms(family, model, seed):
     """Per-leaf norm of (params - their initial values), the initial values
     drawn again from the seed inside the program, leaf by leaf.  The key is
     an argument, not a constant: one compiled program serves every seed."""
     items = tuple(sorted((k, v) for k, v in model.items()))
-    return lambda params: _change_norms_of(items, W.key_of(seed, 1), params)
+    return lambda params: _change_norms_of(family, items, W.key_of(seed, 1), params)
 
 
 def _host(tree) -> dict:
@@ -56,43 +55,43 @@ def first_steps(trainer, steps: int, keep_first_moment: bool, marks=None) -> dic
     losses = [float(trainer.advance())]
     mark("first_step")
     gnorm = {k: v / (1 - beta1) for k, v in
-             _host(jax.jit(_leaf_norms)(trainer.opt["m"])).items()}
+             _host(jax.jit(partial(_leaf_norms, trainer.family))(trainer.opt["m"])).items()}
     m1 = jax.device_get(trainer.opt["m"]) if keep_first_moment else None
     mark("first_moment_to_host")
     for _ in range(steps - 1):
         losses.append(float(trainer.advance()))
-    change = _host(_change_norms(trainer.model, trainer.seed)(trainer.params))
+    change = _host(_change_norms(trainer.family, trainer.model, trainer.seed)(trainer.params))
     mark("steps_2_3_and_change")
     return {"losses": losses, "gnorm": gnorm, "change": change, "m1_host": m1}
 
 
-def reference_shardings(model: dict, mesh):
+def reference_shardings(family, model: dict, mesh):
     """The reference's leaves spread over every chip the cell has (first
     dimension), so that its float32 gradients fit beside its state."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     n = mesh.size
     axes = tuple(mesh.axis_names)
     out = {}
-    for name, shape in W.leaf_shapes(model).items():
+    for name, shape in family.leaf_shapes(model).items():
         spec = P(axes) if n > 1 and shape[0] % n == 0 else P()
         out[name] = NamedSharding(mesh, spec)
     return out
 
 
-def reference_first_steps(model, job, seed, mesh, quant=None, m1_other=None,
+def reference_first_steps(family, model, job, seed, mesh, quant=None, m1_other=None,
                           keep_first_moment=False, steps=3) -> dict:
     """The reference's first steps (or, with ``quant``, the control's):
     the same record as ``first_steps`` gives for the program, and with
     ``m1_other`` the per-leaf norms of (the other side's first gradient
     minus this one's)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    hp = job["optimizer"]
-    sh = reference_shardings(model, mesh)
+    R, hp = family.R, job["optimizer"]
+    sh = reference_shardings(family, model, mesh)
     spread_batch = mesh.size > 1 and job["batch"] % mesh.size == 0
     rep = NamedSharding(mesh, P(tuple(mesh.axis_names)) if spread_batch else P())
-    params = W.make_weights(model, seed, sh)
+    params = W.make_weights(family, model, seed, sh)
     zeros = jax.jit(lambda: {k: jnp.zeros(s, jnp.dtype(job["moments_dtype"]))
-                             for k, s in W.leaf_shapes(model).items()},
+                             for k, s in family.leaf_shapes(model).items()},
                     out_shardings=sh)
     tokens, labels = W.make_batches(seed, job["batch"], job["seq"],
                                     model["vocab_size"], rep)
@@ -117,14 +116,14 @@ def reference_first_steps(model, job, seed, mesh, quant=None, m1_other=None,
                                            jnp.float32(i + 1))
         if i == 0 and keep_first_moment:
             m1 = jax.device_get(m)
-    change = _host(_change_norms(model, seed)(params))
+    change = _host(_change_norms(family, model, seed)(params))
     return {"losses": losses, "gnorm": gnorm, "change": change, "diff": diff,
             "m1_host": m1}
 
 
-def compare(model, job, seed, first: dict, mesh):
+def compare(family, model, job, seed, first: dict, mesh):
     """The numbers compared for ``correct`` and what else the line prints."""
-    ref = reference_first_steps(model, job, seed, mesh, None, first.get("m1_host"),
+    ref = reference_first_steps(family, model, job, seed, mesh, None, first.get("m1_host"),
                                 steps=len(first["losses"]))
     numbers = {}
     for i, (a, b) in enumerate(zip(first["losses"], ref["losses"]), 1):

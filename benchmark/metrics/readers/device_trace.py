@@ -14,10 +14,16 @@ def _trace(obs):
 
 
 def idle_share(obs, params):
+    """Share of the traced stretch in which no operation ran, the engine's
+    waits for the next arrival taken out of the idle seconds and of the
+    stretch alike: having nothing to do is not idling."""
     t = _trace(obs)
-    if not t or t["window_s"] <= 0:
+    if not t:
         return None
-    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
+    stretch = t["window_s"] - t.get("arrival_wait_s", 0.0)
+    if stretch <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / stretch)
 
 
 def _op_seconds(t, module, kind=None, shape=None, prefixes=None):

@@ -24,29 +24,38 @@ sys.path.insert(0, str(HERE.parent))
 
 
 def run_cell(spec, name: str, seed: int, seconds: float, trace: bool,
-             require_chip: bool = True, t_process: float | None = None, **kw) -> dict:
-    """Drive one cell and return the result line's object."""
+             require_chip: bool = True, **kw) -> dict:
+    """Drive one cell and return the result line's object.  Set-up leaves out
+    the one call that brings the chips up (``chip_start``: the machine's
+    runtime, 5.6 to 12.4 s from run to run on one machine, PERF.md section 2):
+    it counts the imports before that call and everything after it, and
+    ``start_to_chip_s`` prints both parts of what lies before the chips."""
     import jax
     from benchmark.harness import device, serve, train
     from benchmark.harness.trace import breakdown
 
     cell = spec.cell(name)
     device.enable_cache(spec.root.parent)
+    t_imported = time.perf_counter()
     if require_chip:
         devices = device.require_chips(cell["chips"])
     else:
         devices = jax.devices()[:cell["chips"]]
+    t_chips = time.perf_counter()
     counter = device.CompileCounter()
     kind = cell["traffic_spec"]["kind"]
     runner = {"serve_open_loop": serve.run, "train_packed": train.run}[kind]
-    out = runner(spec, cell, seed, seconds, trace, devices, counter,
-                 T_PROCESS if t_process is None else t_process, **kw)
+    t_setup = t_chips - (t_imported - T_PROCESS)     # set-up's origin: as if the chips came up at once
+    out = runner(spec, cell, seed, seconds, trace, devices, counter, t_setup, **kw)
 
     obs = out["obs"]
     entries = spec.per_layer(name) if trace else spec.end_to_end(name)
     metrics = spec.read_metrics(entries, obs)
     info = dict(out["info"], workload=name, seed=seed, seconds=seconds,
-                not_compared=out["checks"].not_compared)
+                not_compared=out["checks"].not_compared,
+                start_to_chip_s={"total": t_chips - T_PROCESS,
+                                 "python_imports": t_imported - T_PROCESS,
+                                 "chip_start": t_chips - t_imported})
     print("info " + json.dumps(info), flush=True)
     dev = dict(device.device_info(devices), memory_peak_bytes=out["memory_peak_bytes"])
     result = {"correct": out["checks"].correct, "attempted": out["attempted"],

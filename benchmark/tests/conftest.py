@@ -34,8 +34,9 @@ def _dump(path: Path, obj):
 
 @pytest.fixture(scope="session")
 def tiny_root(tmp_path_factory) -> Path:
-    """A copy of the benchmark elsewhere with three toy cells ADDED to it as
-    files and as entries: nothing that was there is edited."""
+    """A copy of the benchmark elsewhere with three toy cells of the family
+    that is there, and a second family with a serving and a training cell,
+    ADDED to it as files and as entries: nothing that was there is edited."""
     top = tmp_path_factory.mktemp("bench")
     root = top / "benchmark"
     shutil.copytree(REPO / "benchmark", root,
@@ -54,14 +55,24 @@ def tiny_root(tmp_path_factory) -> Path:
         job = json.loads((root / f"traffic/{like}.json").read_text())
         job.update(seq=512)
         _dump(root / f"traffic/{name}.json", job)
+    here = Path(__file__).parent
+    shutil.copy(here / "toy_family.py", root / "families/toy.py")
+    shutil.copy(here / "toy_reference.py", root / "reference/toy.py")
+    toy = dict(cfg, family="toy", final_gain=True, engine=dict(TINY_ENGINE, decode_chunk=2))
+    _dump(root / "configs/toy.json", toy)
+    _dump(root / "configs/toy_wide.json", dict(toy, num_attention_heads=6))   # 6 x 64 != 256
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    for name, traffic, like, chips in (("tiny_serve", "tiny_chat", "serve_chat_steady", 1),
-                                       ("tiny_train", "tiny_job", "train_s4096", 1),
-                                       ("tiny_train4", "tiny_job4", "train_2x2_s4096", 4)):
+    for name, config, traffic, like, chips in (
+            ("tiny_serve", "tiny", "tiny_chat", "serve_chat_steady", 1),
+            ("tiny_train", "tiny", "tiny_job", "train_s4096", 1),
+            ("tiny_train4", "tiny", "tiny_job4", "train_2x2_s4096", 4),
+            ("toy_serve", "toy", "tiny_chat", "serve_chat_steady", 1),
+            ("toy_train", "toy", "tiny_job", "train_s4096", 1),
+            ("toy_wide_serve", "toy_wide", "tiny_chat", "serve_chat_steady", 1)):
         cell = json.loads((root / f"workloads/{like}.json").read_text())
-        cell.update(config="tiny", traffic=traffic, chips=chips)
+        cell.update(config=config, traffic=traffic, chips=chips)
         _dump(root / f"workloads/{name}.json", cell)
-        bench["workloads"].append({"name": name, "config": "tiny", "traffic": traffic,
+        bench["workloads"].append({"name": name, "config": config, "traffic": traffic,
                                    "chips": chips, "why": "toy"})
         for m in bench["end_to_end"] + bench["per_layer"]:
             if like in m.get("workloads", []):

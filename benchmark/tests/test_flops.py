@@ -1,7 +1,11 @@
-"""benchmark/flops.py against counts made by hand for one Mistral-7B layer."""
+"""benchmark/flops.py and the Mistral family's counts against counts made by
+hand for one Mistral-7B layer."""
 import pytest
 
 from benchmark import flops
+from benchmark.harness.spec import Spec
+
+F = Spec().family("mistral")
 
 CFG = dict(hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
            num_key_value_heads=8, head_dim=128, num_hidden_layers=1, vocab_size=32768)
@@ -10,8 +14,8 @@ CFG = dict(hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
 def test_layer_parameters_by_hand():
     # q 4096x4096, k and v 4096x1024 each, o 4096x4096, gate/up/down 4096x14336 each
     by_hand = 4096 * 4096 + 2 * 4096 * 1024 + 4096 * 4096 + 3 * 4096 * 14336
-    assert flops.layer_matmul_params(CFG) == by_hand == 218_103_808
-    assert flops.matmul_params(CFG) == by_hand + 4096 * 32768
+    assert F.layer_matmul_params(CFG) == by_hand == 218_103_808
+    assert F.matmul_params(CFG) == by_hand + 4096 * 32768
 
 
 def test_forward_of_one_sequence_by_hand():
@@ -19,9 +23,9 @@ def test_forward_of_one_sequence_by_hand():
     matmuls = 2 * 218_103_808 * S + 2 * 4096 * 32768 * S
     # token i sees i+1 keys; scores and weighted sum: 2 x 2 x keys x 32 heads x 128
     attention = 4 * 32 * 128 * (S * (S + 1) // 2)
-    assert flops.forward_flops(CFG, S, 0) == pytest.approx(matmuls + attention, rel=1e-12)
-    assert flops.train_step_flops(CFG, 2, S) == pytest.approx(6 * (matmuls + attention), rel=1e-12)
-    one = flops.forward_flops(CFG, 1, 1000)      # a decode step at 1000 cached tokens
+    assert F.forward_flops(CFG, S, 0) == pytest.approx(matmuls + attention, rel=1e-12)
+    assert F.train_step_flops(CFG, 2, S) == pytest.approx(6 * (matmuls + attention), rel=1e-12)
+    one = F.forward_flops(CFG, 1, 1000)      # a decode step at 1000 cached tokens
     assert one == pytest.approx(2 * 218_103_808 + 2 * 4096 * 32768 + 4 * 32 * 128 * 1001)
 
 

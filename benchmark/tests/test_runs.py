@@ -53,14 +53,15 @@ def test_train_control_fails_a_number(tiny_spec):
     import jax
     from benchmark.harness import train, train_check
     cell = tiny_spec.cell("tiny_train")
-    model, job = cell["config_spec"]["model"], cell["traffic_spec"]
+    family, model, job = cell["family"], cell["config_spec"]["model"], cell["traffic_spec"]
     mesh = train.build_mesh(jax.devices()[:1], job)
-    first = train_check.reference_first_steps(model, job, 11, mesh, quant="int8",
+    first = train_check.reference_first_steps(family, model, job, 11, mesh, quant="int8",
                                               keep_first_moment=True)
-    numbers, _ = train_check.compare(model, job, 11, first, mesh)
+    numbers, _ = train_check.compare(family, model, job, 11, first, mesh)
     assert numbers["grad_diff_worst_leaf"] > cell["limits"]["grad_diff_worst_leaf"]
-    sound = train_check.reference_first_steps(model, job, 11, mesh, keep_first_moment=True)
-    numbers, _ = train_check.compare(model, job, 11, sound, mesh)
+    sound = train_check.reference_first_steps(family, model, job, 11, mesh,
+                                              keep_first_moment=True)
+    numbers, _ = train_check.compare(family, model, job, 11, sound, mesh)
     assert all(numbers[k] <= cell["limits"][k] for k in numbers if k in cell["limits"])
 
 

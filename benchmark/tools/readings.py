@@ -42,7 +42,7 @@ def main() -> int:
     cell = spec.cell(args.workload)
     device.enable_cache(HERE.parent)
     devices = device.require_chips(cell["chips"])
-    model, job = cell["config_spec"]["model"], cell["traffic_spec"]
+    family, model, job = cell["family"], cell["config_spec"]["model"], cell["traffic_spec"]
 
     if args.kind == "serve":
         out = serve.run(spec, cell, args.seed, args.seconds, False, devices,
@@ -58,15 +58,15 @@ def main() -> int:
         if args.side == "control":
             mesh = train.build_mesh(devices, job)
             first = train_check.reference_first_steps(
-                model, job, seed, mesh, quant="int8", keep_first_moment=True)
+                family, model, job, seed, mesh, quant="int8", keep_first_moment=True)
         else:
             fault = None if args.side == "program" else args.side
-            trainer = train.Trainer(cell["config_spec"], job, seed, devices, fault)
+            trainer = train.Trainer(family, cell["config_spec"], job, seed, devices, fault)
             mesh = trainer.mesh
             first = train_check.first_steps(trainer, train.CHECK_STEPS, True)
             del trainer
         gc.collect()
-        numbers, extra = train_check.compare(model, job, seed, first, mesh)
+        numbers, extra = train_check.compare(family, model, job, seed, first, mesh)
         print("readings " + json.dumps({
             "seed": seed, "side": args.side, "numbers": numbers,
             "worst": extra["worst_leaves"], "left_out": extra["left_out_of_change"],

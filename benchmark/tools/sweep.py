@@ -37,17 +37,17 @@ def main() -> int:
     cell = spec.cell(args.workload)
     device.enable_cache(HERE.parent)
     devices = device.require_chips(cell["chips"])
-    config, mix = cell["config_spec"], cell["traffic_spec"]
+    family, config, mix = cell["family"], cell["config_spec"], cell["traffic_spec"]
     model = config["model"]
     t0 = time.perf_counter()
-    eng = serve.build_engine(config, args.seed)
+    eng = serve.build_engine(family, config, args.seed)
     eng.run(serve.to_requests(traffic.warmup_requests(mix, model["vocab_size"], eng.chunk_C)))
     print(f"set-up {time.perf_counter() - t0:.1f}s", flush=True)
     for rate in [float(r) for r in args.rates.split(",")]:
         reqs = traffic.serve_requests(dict(mix, rate_per_s=rate), args.seconds, args.seed,
                                       model["vocab_size"])
         res = eng.run(serve.to_requests(reqs))
-        obs = serve.observe(reqs, res, eng.bench_clock, model, None)
+        obs = serve.observe(reqs, res, eng.bench_clock, family, model, None)
         rows = sorted(obs["requests"], key=lambda r: r["arrival"])
         ttft = np.array([r["token_times"][0] - r["arrival"] for r in rows])
         third = max(1, len(rows) // 3)
