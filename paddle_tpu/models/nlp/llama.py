@@ -458,6 +458,12 @@ class LlamaForCausalLM(nn.Layer):
                           transpose_y=True)
         return self.lm_head(h)
 
+    def serving_decode_factory(self, **build):
+        """What ``ServingEngine`` asks a model for: its serving factory
+        (``llama_serving_decode_factory``, which documents ``build``)."""
+        from .llama_decode import llama_serving_decode_factory
+        return llama_serving_decode_factory(self, **build)
+
     # -- generation (greedy, incremental) ----------------------------------
     def generate(self, input_ids, max_new_tokens=16):
         from ...autograd import no_grad

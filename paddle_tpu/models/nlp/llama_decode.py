@@ -1580,6 +1580,93 @@ def llama_speculative_decode_factory(target: LlamaForCausalLM,
 
 # --- paged decode (continuous batching) ------------------------------------
 
+def emit_fn(emit: str):
+    """What a paged program hands back for a row's last position:
+    ``"token"`` the greedy token, ``"logits"`` the float32 logits (the
+    serving loop then owns sampling)."""
+    if emit not in ("token", "logits"):
+        raise ValueError(f"emit {emit!r}: use 'token' or 'logits'")
+
+    def _emit(logits):
+        return jnp.argmax(logits, -1) if emit == "token" \
+            else logits.astype(jnp.float32)
+    return _emit
+
+
+def chunked_prefill_shim(prefill_chunk, finish_prefill, C: int,
+                         hidden: int, dtype):
+    """The chunked-prefill walk every paged factory shares: plain
+    python over ONE compiled chunk program
+    (``prefill_chunk(outer, layers, chunk, start, page_tables, lengths,
+    pools, x_last, lora) -> (x_last, pools)``) and the finishing
+    program (``finish_prefill(outer, x_last, grammar)``)."""
+
+    def prefill_chunked(outer, layers, tokens, page_tables, lengths,
+                        pools, resume_from: int = 0, lora=None,
+                        grammar=None):
+        """``resume_from`` (a chunk multiple): skip chunks whose pages
+        already hold real K/V — the prefix-cache path
+        (PagedKVCache.acquire_prefix returns the cached token count;
+        pass the MINIMUM across the batch, rounded DOWN to a chunk
+        multiple — a larger value would skip chunks that are
+        uninitialized for the less-cached sequences). The final chunk
+        always runs so the last-position logits exist; its page writes
+        rewrite identical content when the tail was cached.
+        ``lora``: optional ``(adapter_bank, adapter_ids)`` deltas,
+        threaded into every chunk call."""
+        B, T = tokens.shape
+        if T % C:
+            raise ValueError(
+                f"chunked prefill: padded prompt length {T} must be a "
+                f"multiple of the chunk size {C}")
+        if resume_from % C:
+            raise ValueError(f"resume_from {resume_from} must be a "
+                             f"chunk multiple ({C})")
+        resume = min(resume_from, T - C)
+        # the shim's three host steps, named in the profiler's trace
+        # (free while no session records): each is a dispatch of its
+        # own that the device may sit out
+        with TraceAnnotation("factory:prefill.slice"):
+            x_last = jnp.zeros((B, hidden), dtype)
+        for s in range(resume, T, C):  # static count; ONE compiled fn
+            with TraceAnnotation("factory:prefill.slice"):
+                chunk = tokens[:, s:s + C]
+            with TraceAnnotation("factory:prefill.chunk"):
+                x_last, pools = prefill_chunk(
+                    outer, layers, chunk, s, page_tables, lengths,
+                    pools, x_last, lora)
+        with TraceAnnotation("factory:prefill.finish"):
+            return finish_prefill(outer, x_last, grammar), pools
+
+    # the shim itself is plain python; expose the jitted programs it
+    # drives so the serving engine's recompile detector (obs layer:
+    # program-cache growth across a call) can watch prefill too
+    prefill_chunked._jit_inner = (prefill_chunk, finish_prefill)
+    return prefill_chunked
+
+
+def decode_scan(step, tok, lengths, pools, n: int):
+    """``n`` decode steps as ONE ``lax.scan`` (traced inside the
+    caller's jit). ``step(tok, lens, pools) -> (emit, pools, *extra)``;
+    the feedback token is the emission, or its greedy argmax where the
+    step emits logits. Returns ``((emits, *extras) each stacked (n,
+    ...), next_tok (B,), pools')``."""
+    def body(carry, _):
+        tok, lens, pools = carry
+        nxt, pools, *extra = step(tok, lens, pools)
+        step_tok = nxt if nxt.ndim == 1 else jnp.argmax(
+            nxt, -1).astype(jnp.int32)
+        return (step_tok.astype(jnp.int32), lens + 1, pools), \
+            (nxt, *extra)
+    # int32 up front: with emit="logits" callers derive the seed
+    # token themselves (e.g. np.argmax -> int64) and a dtype drift
+    # would break the scan carry structure
+    (tok, _, pools), ys = jax.lax.scan(
+        body, (jnp.asarray(tok, jnp.int32), lengths, pools), None,
+        length=n)
+    return ys, tok, pools
+
+
 def llama_paged_decode_factory(model: LlamaForCausalLM,
                                page_size: int = 64,
                                n_pool_pages: int = 256,
@@ -1717,15 +1804,10 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
                 "the (P,) page-tier mask is a whole-pool jit input "
                 "with no kv-head axis to shard — use kv_quant='int8' "
                 "(scales shard with their kv heads per tp_pool_spec)")
-    if emit not in ("token", "logits"):
-        raise ValueError(f"emit {emit!r}: use 'token' or 'logits'")
+    _emit = emit_fn(emit)
     if prefill_attention not in ("gather", "kernel"):
         raise ValueError(f"prefill_attention {prefill_attention!r}: "
                          "use 'gather' or 'kernel'")
-
-    def _emit(logits):
-        return jnp.argmax(logits, -1) if emit == "token" \
-            else logits.astype(jnp.float32)
 
     _paged_kernel = partial(paged_kernel_call, mesh=tp_mesh,
                             axis=tp.axis if tp is not None else None)
@@ -2032,48 +2114,9 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         x = _rms(x_last, outer["model.norm.weight"], cfg.rms_norm_eps)
         return _emit(_gmask(_logits(cfg, outer, x), grammar))
 
-    def prefill_chunked(outer, layers, tokens, page_tables, lengths,
-                        pools, resume_from: int = 0, lora=None,
-                        grammar=None):
-        """``resume_from`` (a chunk multiple): skip chunks whose pages
-        already hold real K/V — the prefix-cache path
-        (PagedKVCache.acquire_prefix returns the cached token count;
-        pass the MINIMUM across the batch, rounded DOWN to a chunk
-        multiple — a larger value would skip chunks that are
-        uninitialized for the less-cached sequences). The final chunk
-        always runs so the last-position logits exist; its page writes
-        rewrite identical content when the tail was cached.
-        ``lora``: optional ``(adapter_bank, adapter_ids)`` deltas,
-        threaded into every chunk call."""
-        C = chunked_prefill
-        B, T = tokens.shape
-        if T % C:
-            raise ValueError(
-                f"chunked prefill: padded prompt length {T} must be a "
-                f"multiple of the chunk size {C}")
-        if resume_from % C:
-            raise ValueError(f"resume_from {resume_from} must be a "
-                             f"chunk multiple ({C})")
-        resume = min(resume_from, T - C)
-        # the shim's three host steps, named in the profiler's trace
-        # (free while no session records): each is a dispatch of its
-        # own that the device may sit out
-        with TraceAnnotation("factory:prefill.slice"):
-            x_last = jnp.zeros((B, cfg.hidden_size), dtype)
-        for s in range(resume, T, C):  # static count; ONE compiled fn
-            with TraceAnnotation("factory:prefill.slice"):
-                chunk = tokens[:, s:s + C]
-            with TraceAnnotation("factory:prefill.chunk"):
-                x_last, pools = _prefill_chunk(
-                    outer, layers, chunk, s, page_tables, lengths,
-                    pools, x_last, lora)
-        with TraceAnnotation("factory:prefill.finish"):
-            return _finish_prefill(outer, x_last, grammar), pools
-
-    # the shim itself is plain python; expose the jitted programs it
-    # drives so the serving engine's recompile detector (obs layer:
-    # program-cache growth across a call) can watch prefill too
-    prefill_chunked._jit_inner = (_prefill_chunk, _finish_prefill)
+    prefill_chunked = chunked_prefill_shim(
+        _prefill_chunk, _finish_prefill, chunked_prefill,
+        cfg.hidden_size, dtype)
 
     def _write_chunk_ragged(pool_l, kv, page_tables, starts, C):
         """kv (R, nkv, C, hd) written at PER-ROW absolute positions
@@ -2226,19 +2269,11 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         (the serving engine clamps exactly this; ``n`` is static, so
         the clamp costs at most one extra cache entry, flat in the
         number of schemas)."""
-        def body(carry, _):
-            tok, lens, pools = carry
-            nxt, pools = decode_step(outer, layers, tok, page_tables,
-                                     lens, pools, lora, grammar)
-            step_tok = nxt if nxt.ndim == 1 else jnp.argmax(
-                nxt, -1).astype(jnp.int32)
-            return (step_tok.astype(jnp.int32), lens + 1, pools), nxt
-        # int32 up front: with emit="logits" callers derive the seed
-        # token themselves (e.g. np.argmax -> int64) and a dtype drift
-        # would break the scan carry structure
-        (tok, _, pools), emits = jax.lax.scan(
-            body, (jnp.asarray(tok, jnp.int32), lengths, pools), None,
-            length=n)
+        (emits,), tok, pools = decode_scan(
+            lambda tok, lens, pools: decode_step(
+                outer, layers, tok, page_tables, lens, pools, lora,
+                grammar),
+            tok, lengths, pools, n)
         return emits, tok, pools
 
     pools = init_pools()

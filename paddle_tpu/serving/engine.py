@@ -66,7 +66,6 @@ from ..obs import trace as obs_trace
 from ..models.nlp.llama_decode import (as_grammar_config,
                                        as_lora_config,
                                        as_spec_config, as_tp_config,
-                                       llama_serving_decode_factory,
                                        repage_kv_data, route_decode,
                                        transcode_kv_data,
                                        tree_device_bytes)
@@ -311,6 +310,30 @@ def make_policy(spec) -> Policy:
     if spec == "routed":
         return RoutedPolicy()
     return FixedPolicy(spec)
+
+
+_LATENT_REFUSES = (
+    "a latent (compressed-KV) cache holds one page operand shared by "
+    "all heads, with no K and V pages and no head axis: it does not "
+    "compose yet with tp (tp_pool_spec splits kv heads), kv_quant / "
+    "kv_cache_dtype (the int8 codec scales per head slot), hostmem and "
+    "KV handoff (export / reshard / repage / transcode assume "
+    "(L, Hkv, P, ...) leaves), lora / adapters (q/v-projection deltas), "
+    "spec (the draft pool rides K/V pages), grammar or dispatch_ahead "
+    "(its programs take no such argument and count their calls) — got ")
+
+
+def _kv_layout(obj) -> str:
+    """The cache layout a model or a prebuilt factory states."""
+    return getattr(obj, "kv_layout_", "head_major")
+
+
+def _refuse_latent(**used):
+    """The one refusal of everything a latent cache does not compose
+    with: raises naming the options in use, returns when none is."""
+    named = sorted(k for k, v in used.items() if v is not None)
+    if named:
+        raise ValueError(_LATENT_REFUSES + ", ".join(named))
 
 
 def _coerce_paged_only(policy, what: str, why: str):
@@ -872,6 +895,22 @@ class ServingEngine:
         # (with Request.schema unset) decodes constrained under it.
         grammar_config = as_grammar_config(grammar_config)
         spec = as_spec_config(spec)
+        # A LATENT cache (one page operand of compressed K/V shared by
+        # every head, ``kv_layout_ = "latent"`` on the model or the
+        # prebuilt factory): whatever assumes K and V pages with a
+        # head axis is refused HERE, once, with one message
+        latent = _kv_layout(serving if serving is not None
+                            else model) == "latent"
+        if latent:
+            _refuse_latent(
+                tp=tp, lora=lora, adapters=adapters, spec=spec,
+                spec_draft=spec_draft, kv_quant=kv_quant,
+                kv_cache_dtype=kv_cache_dtype, hostmem=hostmem,
+                grammar=grammar, grammar_config=grammar_config,
+                dispatch_ahead=dispatch_ahead or None)
+            policy = _coerce_paged_only(
+                policy, "with a latent cache",
+                "the dense wave cache stores per-head K and V")
         if serving is None:
             if model is None:
                 raise ValueError("pass a model or a prebuilt serving "
@@ -896,9 +935,21 @@ class ServingEngine:
                 # page 0 is the reserved padding page; each slot may
                 # need max_len/page_size pages
                 n_pool_pages = slots * (max_len // page_size) + 1
-            serving = llama_serving_decode_factory(
-                model, max_len=max_len, page_size=page_size,
-                n_pool_pages=n_pool_pages, kv_cache_dtype=kv_cache_dtype,
+            # THE ONE SEAM where a factory is chosen: the model brings
+            # its serving factory, built from the geometry and the
+            # options (a Llama model answers with
+            # ``llama_serving_decode_factory``; a latent-cache model's
+            # cache composes with none of the options, which were
+            # refused above)
+            if not hasattr(model, "serving_decode_factory"):
+                raise TypeError(
+                    f"{type(model).__name__} brings no serving factory "
+                    "(serving_decode_factory(**build)): pass a model "
+                    "that does, or a prebuilt factory (serving=)")
+            serving = model.serving_decode_factory(
+                max_len=max_len, page_size=page_size,
+                n_pool_pages=n_pool_pages,
+                kv_cache_dtype=kv_cache_dtype,
                 batch_capacity=slots, scan_layers=scan_layers,
                 chunked_prefill=page_size, tp=tp, lora=lora,
                 draft=spec_draft, kv_quant=kv_quant,
@@ -1470,7 +1521,22 @@ class ServingEngine:
         # byte-identical).
         self._pool_bytes: Optional[Tuple[int, int]] = None
         self._g_pool_bytes = None
-        if tp is not None or kv_quant is not None:
+        # a factory may count its device calls (``call_counts``: a
+        # latent-cache expert model counts expert routing and latent
+        # positions read) and names the registry counters their sums
+        # go to: created ONLY for such a factory, so every other
+        # run's registry is unchanged
+        self._call_counts = getattr(serving, "call_counts", None)
+        self._ctr_model = None
+        if self._call_counts is not None:
+            self._ctr_model = {
+                key: obs_metrics.REGISTRY.counter(name, help_)
+                for key, (name, help_)
+                in self._call_counts.counters.items()}
+        # a latent pool is noted too: its page is not K and V of the
+        # head width, so the book is told its bytes (tp / kv_quant are
+        # None there: the refusals above)
+        if tp is not None or kv_quant is not None or latent:
             # a quantizing factory prices its own pool (the sim's
             # token pools model the int8 layout arithmetically; the
             # real factory's leaves ARE the small arrays)
@@ -2497,6 +2563,8 @@ class ServingEngine:
         may be of any length); returns its start on
         ``time.perf_counter``."""
         self._phases = obs_trace.HostPhases(keep=clock.mode != "fixed")
+        if self._call_counts is not None:
+            self._call_counts.reset()    # the run's calls alone
         return time.perf_counter()
 
     def _overhead_row(self, clock, run_w0,
@@ -2515,6 +2583,13 @@ class ServingEngine:
         less what ran outside every turn. ``whole=False`` (a session,
         which is driven from outside): ``run_wall_s`` is the time
         under its own turns and waits."""
+        counts = None
+        if self._call_counts is not None:
+            # one entry a program call of the run, in call order;
+            # their sums go onto the registry
+            counts = self._call_counts.take()
+            for name, ctr in self._ctr_model.items():
+                ctr.inc(sum(counts[name]))
         if clock.mode == "fixed":
             return None
         run_wall = time.perf_counter() - run_w0    # before the summing
@@ -2524,10 +2599,13 @@ class ServingEngine:
             run_wall = root_s
         dev = min(clock.dev_wall, run_wall)
         frac = 1.0 - dev / run_wall if run_wall > 0 else 0.0
-        return dict(acct, run_wall_s=round(run_wall, 6),
-                    device_wall_s=round(dev, 6),
-                    engine_host_frac=round(max(0.0, frac), 6),
-                    slots=self.slots)
+        row = dict(acct, run_wall_s=round(run_wall, 6),
+                   device_wall_s=round(dev, 6),
+                   engine_host_frac=round(max(0.0, frac), 6),
+                   slots=self.slots)
+        if counts is not None:
+            row["model_counts"] = counts
+        return row
 
     def _cost_result(self, clock, tr=None, m=None) -> Optional[Dict]:
         """Bank the cost ledger's run-end evidence for this engine's
@@ -3582,6 +3660,8 @@ class ServingEngine:
         real llama factory's pools, whose every leaf is page-indexed
         on axis 2 ((L, Hkv, P, page_size, ...) arrays — int8
         data+scale tuples included)."""
+        if _kv_layout(self.serving) == "latent":
+            _refuse_latent(kv_handoff_export=True)
         fn = getattr(self.serving, "export_kv_pages", None)
         ids = list(page_ids)
         if fn is not None:
@@ -3594,6 +3674,8 @@ class ServingEngine:
         """Scatter a handoff's exported page content into THIS
         engine's pool at ``page_ids`` (the importer's freshly
         allocated chain). Counterpart of ``export_kv_pages``."""
+        if _kv_layout(self.serving) == "latent":
+            _refuse_latent(kv_handoff_import=True)
         fn = getattr(self.serving, "import_kv_pages", None)
         ids = list(page_ids)
         if fn is not None:

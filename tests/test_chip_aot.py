@@ -143,6 +143,59 @@ def test_paged_decode_step_compiles_full_width(v5e):
         assert _mosaic_calls(decode_n.lower(*args, 8).compile()) >= 1
 
 
+# serve_latent_moe_docqa's shapes: 32 slots x 97 pages of 64 positions,
+# 16 heads against a latent page of 576 values padded to 640 columns,
+# of which the first 512 are the values; a pool of 3617 pages x 7 layers
+LATENT = dict(slots=32, table=97, page=64, heads=16, width=640, rank=512,
+              pool=3617, layers=7)
+
+
+@pytest.mark.parametrize("chunk,rows", [(1, LATENT["slots"]), (64, 1)])
+def test_latent_paged_kernel_compiles_at_the_cells_shapes(v5e, chunk, rows):
+    """Decode (chunk 1, every slot a row) and a 64-token prefill chunk of
+    one request, each against the whole pool addressed by (layer, page)."""
+    from paddle_tpu.ops.pallas.latent_paged_attention import (
+        latent_paged_attention, page_width)
+    one = SingleDeviceSharding(v5e[0])
+    g = LATENT
+    assert page_width(576) == g["width"]
+    args = (_sds((rows, g["heads"] * chunk, g["width"]), BF16, one),
+            _sds((g["layers"], g["pool"], g["page"], g["width"]), BF16, one),
+            _sds((rows, g["table"]), jnp.int32, one),
+            _sds((rows,), jnp.int32, one), _sds((rows,), jnp.int32, one))
+    c = _compile(lambda q, pool, pt, sl, st: latent_paged_attention(
+        q, pool, 3, pt, sl, st, chunk, g["rank"], 192 ** -0.5), *args)
+    assert _mosaic_calls(c) == 1
+
+
+def test_latent_decode_program_keeps_the_pool_in_place(v5e):
+    """The whole decode program of a 3-layer cut at the published widths:
+    the pool is donated and aliased, and nothing of its size is made
+    beside it (an unpadded 576-column pool was converted to another layout
+    and back, two pool-sized temporaries a call)."""
+    from paddle_tpu.models.nlp import deepseek_v3 as M
+    one = SingleDeviceSharding(v5e[0])
+    g = LATENT
+    net = M.DeepseekV3ForCausalLM(M.DeepseekV3Config(num_hidden_layers=3))
+    net.decode_params = lambda: (dict(net.outer),          # shapes in place of arrays
+                                 [dict(lp) for lp in net.layers])
+    outer, layers, _, _, _, decode_n = M.latent_paged_decode_factory(
+        net, page_size=g["page"], n_pool_pages=3, chunked_prefill=g["page"])
+    pool = _sds((3, g["pool"], g["page"], g["width"]), BF16, one)
+    i32 = lambda *s: _sds(s, jnp.int32, one)  # noqa: E731
+    with lower_for_chip():
+        c = decode_n._jit_inner[0].lower(
+            _on(outer, one), _on(layers, one), i32(g["slots"]),
+            i32(g["slots"], g["table"]), i32(g["slots"]), pool, 1).compile()
+    kernels = [ln for ln in c.as_text().splitlines()
+               if "custom-call(" in ln and "%latent_paged_attention" in ln.split("=")[0]]
+    assert len(kernels) == 3                                # one a layer
+    stats = c.memory_analysis()
+    pool_bytes = 3 * g["pool"] * g["page"] * g["width"] * 2
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert stats.temp_size_in_bytes < pool_bytes // 8
+
+
 def test_tp_sharded_paged_call_on_four_chips(v5e):
     """The paged kernel under tp=4: kv heads manual over the tp axis
     (``paged_kernel_call``); the bare sharded call is what JAX refuses."""
