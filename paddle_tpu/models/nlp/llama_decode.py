@@ -50,6 +50,24 @@ def _stack_apply(body, x, stacked, scan_layers: bool = True):
     return x, jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ys)
 
 
+def _stack_carry(body, carry, stacked, scan_layers: bool = True):
+    """``_stack_apply`` for a body that keeps STATE and knows its layer:
+    ``body(i, carry, per_layer) -> carry`` with ``i`` the layer index (a
+    traced int32 under the scan, a Python int unrolled). What rides the
+    carry is updated in place by the loop — the paged K/V pools do; a
+    scan's ``xs`` are sliced a layer at a time and its ``ys`` stacked
+    into a fresh buffer, which for a pool is a copy of the pool."""
+    L = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+    if scan_layers:
+        return jax.lax.scan(
+            lambda c, xs: (body(xs[0], c, xs[1]), None), carry,
+            (jnp.arange(L, dtype=jnp.int32), stacked))[0]
+    for i in range(L):
+        carry = body(i, carry,
+                     jax.tree_util.tree_map(lambda a: a[i], stacked))
+    return carry
+
+
 def _mm(x, w):
     """Matmul against a weight that may be int8-quantized.
 
@@ -385,7 +403,7 @@ def as_spec_config(spec) -> "SpecConfig | None":
 def _write_positions(pool_l, kv, page_tables, positions, page_size):
     """kv (B, nkv, T, hd) written at PER-ROW absolute ``positions``
     (B, T) through the page tables — the speculative draft/verify
-    write. Unlike ``_write_chunk`` (page-aligned) or ``_write_token``
+    write. Unlike ``_write_pages`` (page-aligned) or ``_write_token``
     (one slot), spec blocks start at each row's current length, so
     every (row, t) scatters to its own (page, offset). Positions of
     inactive rows resolve through page-table row 0 into the reserved
@@ -622,27 +640,35 @@ def tp_pool_spec(axis: str = "tp") -> tuple:
     return (None, axis)
 
 
-def paged_kernel_call(kernel, q, kp, vp, *scalars, mesh=None, axis=None):
-    """``kernel(q, k_pages, v_pages, *scalars)`` for one layer's pools,
-    with an int8 pool's (data, scales) pair unpacked. A Mosaic call
-    does not lower under GSPMD, so with a ``mesh`` it runs inside a
-    shard_map: query heads and the pools' kv-head axis manual over
-    ``axis`` (``tp_pool_spec``'s layout — each device walks its own kv
-    heads' pages), tables / lengths / positions replicated."""
-    if isinstance(kp, tuple):
-        pools = (kp[0], vp[0], kp[1], vp[1])
+def paged_kernel_call(kernel, q, kp, vp, *scalars, layer=None, mesh=None,
+                      axis=None):
+    """``kernel(q, k_pages, v_pages, *scalars, layer=layer)`` with an
+    int8 pool's (data, scales) pair unpacked. The pools are the whole
+    (L, Hkv, P, page, hd) ones read at ``layer`` (a Python int or the
+    layer loop's traced counter), or one layer's pages with ``layer``
+    None. A Mosaic call does not lower under GSPMD, so with a ``mesh``
+    it runs inside a shard_map: query heads and the pools' kv-head axis
+    manual over ``axis`` (``tp_pool_spec``'s layout — each device walks
+    its own kv heads' pages), tables / lengths / positions and the
+    layer index replicated."""
+    quant = isinstance(kp, tuple)
+    pools = (kp[0], vp[0], kp[1], vp[1]) if quant else (kp, vp)
+    if layer is not None:
+        scalars += (jnp.asarray(layer, jnp.int32),)      # rides last
 
-        def call(q, k, v, ks, vs, *sc):
-            return kernel(q, k, v, *sc, k_scales=ks, v_scales=vs)
-    else:
-        pools = (kp, vp)
+    def call(q, k, v, *rest):
+        kw = {}
+        if quant:
+            kw["k_scales"], kw["v_scales"], *rest = rest
+        if layer is not None:
+            *rest, kw["layer"] = rest
+        return kernel(q, k, v, *rest, **kw)
 
-        def call(q, k, v, *sc):
-            return kernel(q, k, v, *sc)
     if mesh is not None:
+        pool_spec = P(axis) if layer is None else P(*tp_pool_spec(axis))
         call = jax.shard_map(
             call, mesh=mesh,
-            in_specs=(P(None, axis),) + (P(axis),) * len(pools)
+            in_specs=(P(None, axis),) + (pool_spec,) * len(pools)
             + (P(),) * len(scalars),
             out_specs=P(None, axis), check_vma=False)
     return call(q, *pools, *scalars).astype(q.dtype)
@@ -1683,9 +1709,17 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
     serving path (ops/pallas/paged_attention.py; the reference's dense
     fused_multi_transformer cache cannot share memory across requests).
 
-    Per layer the pool is (Hkv, P, page_size, hd); sequences hold page
-    tables (B, pages_per_seq — the caller's table width) and real
-    lengths (B,). Ragged batches are
+    The pools are (L, Hkv, P, page_size, hd), one each for K and V;
+    sequences hold page tables (B, pages_per_seq — the caller's table
+    width) and real lengths (B,). Every program takes the pools DONATED
+    and addresses them in place by (layer, page): they ride the layer
+    loop's carry whole (``_stack_carry``), a write is one scatter of the
+    new rows at (layer, head, page, offset), the kernels read (layer,
+    head, page) blocks out of the whole pool and the dense chunk
+    attention gathers the batch's pages of a layer in one gather — no
+    program slices a layer out of a pool, stacks one back or copies
+    one (tests/test_chip_aot.py holds the chip compiler to that).
+    Ragged batches are
     first-class: rotary positions, cache writes and attention masks are
     all per-sequence, so requests at different depths decode together in
     ONE jitted step — admit/evict between steps by editing the tables
@@ -1732,8 +1766,8 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
     default until the kernel carries a chip measurement.
 
     ``scan_layers`` (default True): one scanned layer body over the
-    stacked (L, ...) weights and (L, ...) pools; False unrolls the
-    layers into the program (parity fallback).
+    stacked (L, ...) weights, the pools in its carry; False unrolls the
+    layers into the program (parity fallback, the same addressing).
 
     ``tp`` (``TPConfig`` / int degree): shard the decode path over a
     1-D named mesh — attention heads and MLP hidden dims partitioned
@@ -1835,23 +1869,55 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         return jnp.where(allow.astype(bool), logits,
                          jnp.asarray(-jnp.inf, logits.dtype))
 
-    # ONE definition of how the optional adapter bank rides the layer
-    # scan, shared by prefill / decode_step / _prefill_chunk (three
-    # private copies could silently diverge the chunked-prefill path
-    # from decode if the lora payload ever grows, e.g. k-proj deltas)
-    def _scan_operand(layers, k_pools, v_pools, lora):
-        return (layers, k_pools, v_pools) if lora is None \
-            else (layers, lora[0], k_pools, v_pools)
+    # ONE definition of how the layer loop runs, shared by prefill /
+    # decode_step / _prefill_chunk / the ragged chunk (private copies
+    # could silently diverge the chunked-prefill path from decode if the
+    # lora payload ever grows, e.g. k-proj deltas). What is read a layer
+    # at a time (the weights, the optional adapter bank) rides the loop's
+    # ``xs``; the pools ride its CARRY whole and are addressed in place
+    # by (layer, page) — as ``xs``/``ys`` each call sliced every layer
+    # out of them and stacked a fresh pool.
+    def _layers_over_pools(x, k_pools, v_pools, layers, lora, pos,
+                           attend_at):
+        """Every layer's ``_layer_math`` over the carried pools:
+        ``attend_at(i, k_pools, v_pools) -> attend`` is the program's
+        cache strategy at layer ``i``, its ``attend(q, k, v)`` returning
+        ``(ctx, (k_pools', v_pools'))``. -> (x, k_pools, v_pools)."""
+        def body(i, carry, per_layer):
+            x, k_pools, v_pools = carry
+            if lora is None:
+                lp, lo = per_layer, None
+            else:
+                lp, bl = per_layer
+                lo = (bl, lora[1], lora_scale)
+            x, (k_pools, v_pools) = _layer_math(
+                cfg, lp, x, pos, attend_at(i, k_pools, v_pools), lora=lo)
+            return x, k_pools, v_pools
 
-    def _split_per_layer(per_layer, lora):
-        """One scan step's operand -> (lp, kp_l, vp_l, lo) where
-        ``lo`` is the per-layer lora triple for ``_layer_math`` (None
-        without adapters)."""
-        if lora is None:
-            lp, kp_l, vp_l = per_layer
-            return lp, kp_l, vp_l, None
-        lp, bl, kp_l, vp_l = per_layer
-        return lp, kp_l, vp_l, (bl, lora[1], lora_scale)
+        return _stack_carry(
+            body, (x, k_pools, v_pools),
+            layers if lora is None else (layers, lora[0]), scan_layers)
+
+    # The kv head is an INDEX of every scatter and gather below, like
+    # the layer and the page, never a slice: what one index moves is then
+    # the pool's minor dims alone, contiguous as the pool lies. (With the
+    # heads as a slice XLA:TPU stores the pool heads-minor for the
+    # scatter and converts all of it there and back, every layer: the
+    # chip compiler's output, tests/test_chip_aot.py.)
+    _heads = np.arange(nkv)
+
+    def _gather_pages(pool, i, page_tables):
+        """(B, nkv, S, hd): layer ``i``'s pages of the batch in ONE
+        gather from the whole pool, dequantizing only that slice —
+        never a layer of the pool, never the pool."""
+        B, W = page_tables.shape
+        at = (i, _heads[None, :, None], page_tables[:, None, :])
+        if isinstance(pool, tuple):
+            data, sc = pool
+            g = data[at].astype(jnp.float32) * sc[at][..., None]
+        else:
+            g = pool[at]                         # (B, nkv, W, page, hd)
+        return g.reshape(B, nkv, W * page_size, hd)
 
     def init_pools():
         shape = (L, nkv, n_pool_pages, page_size, hd)
@@ -1920,26 +1986,37 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         return ((jnp.where(t, kf, k_eff), kq, ks),
                 (jnp.where(t, vf, v_eff), vq, vs), tier)
 
-    def _write_prompt(pool_l, kv, page_tables, T_pad):
-        """kv (B, nkv, T_pad, hd) -> pages at the tables' first
-        T_pad/page_size entries: the start=0 case of _write_chunk."""
-        return _write_chunk(pool_l, kv, page_tables, 0, T_pad)
-
-    def _write_token(pool_l, kv, page_tables, lengths):
-        """kv (B, nkv, 1, hd) written at each sequence's current end."""
-        pages = jnp.take_along_axis(
-            page_tables, (lengths // page_size)[:, None], 1)[:, 0]
-        offs = lengths % page_size
-        if isinstance(pool_l, tuple):
-            data, sc = pool_l
+    # the writes: ONE scatter of the new rows into the carried pool at
+    # (layer, head, page, offset) / (layer, head, page id)
+    def _write_token(pool, i, kv, pages, offs):
+        """kv (B, nkv, 1, hd) written at each row's (page, offset)."""
+        at = (i, _heads[None, :], pages[:, None], offs[:, None])
+        if isinstance(pool, tuple):
+            data, sc = pool
             qd, s = _q8(kv)                              # (B,nkv,1,hd)
-            return (data.at[:, pages, offs].set(
-                        jnp.transpose(qd[:, :, 0], (1, 0, 2))),
-                    sc.at[:, pages, offs].set(s[:, :, 0].T))
-        upd = jnp.transpose(kv[:, :, 0], (1, 0, 2))     # (nkv, B, hd)
-        return pool_l.at[:, pages, offs].set(upd.astype(pool_l.dtype))
+            return (data.at[at].set(qd[:, :, 0]),
+                    sc.at[at].set(s[:, :, 0]))
+        return pool.at[at].set(kv[:, :, 0].astype(pool.dtype))
 
-    @partial(jax.jit, donate_argnums=(5,))  # pools alias in place
+    def _write_pages(pool, i, kv, ids):
+        """kv (B, nkv, C, hd) scattered as whole pages to layer ``i``'s
+        page ``ids`` (B, C/page_size): the one page write under the
+        chunk, the ragged chunk and the one-shot prompt (their C and
+        first position are page multiples)."""
+        B, npg = ids.shape
+        at = (i, _heads[None, :, None], ids[:, None, :])
+
+        def pageify(a):                  # -> (B, nkv, npg, page, ...)
+            return a.reshape((B, nkv, npg, page_size) + a.shape[3:])
+
+        if isinstance(pool, tuple):
+            data, sc = pool
+            qd, s = _q8(kv)
+            return (data.at[at].set(pageify(qd)),
+                    sc.at[at].set(pageify(s)))
+        return pool.at[at].set(pageify(kv).astype(pool.dtype))
+
+    @partial(jax.jit, donate_argnums=(5,))  # updated where they lie
     def prefill(outer, layers, tokens, page_tables, lengths, pools,
                 lora=None, grammar=None):
         """Prompts padded to a page multiple; ``lengths`` are the REAL
@@ -1950,13 +2027,13 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         decoding masks over the FIRST emitted token (each row's id is
         its automaton's start state; free rows pass 0)."""
         B, T = tokens.shape
-        if pressure:
-            pools = _tier_clear(pools,
-                                page_tables[:, :T // page_size])
-        k_pools, v_pools, _tm = _tier_enter(pools)
         if T % page_size:
             raise ValueError(f"prefill length {T} must be a multiple of "
                              f"page_size {page_size} (pad the prompt)")
+        ids = page_tables[:, :T // page_size]     # the prompt's pages
+        if pressure:
+            pools = _tier_clear(pools, ids)
+        k_pools, v_pools, _tm = _tier_enter(pools)
         x = jnp.take(outer["model.embed_tokens.weight"], tokens, axis=0)
         pos_vec = jnp.arange(T)
         causal = jnp.tril(jnp.ones((T, T), bool))
@@ -1964,22 +2041,15 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         key_ok = jnp.arange(T)[None, :] < lengths[:, None]
         mask = causal[None, None] & key_ok[:, None, None, :]
 
-        def body(x, per_layer):
-            lp, kp_l, vp_l, lo = _split_per_layer(per_layer, lora)
-
+        def attend_at(i, k_pools, v_pools):
             def attend(q, k, v):
-                kp = _write_prompt(kp_l, k, page_tables, T)
-                vp = _write_prompt(vp_l, v, page_tables, T)
+                kp = _write_pages(k_pools, i, k, ids)
+                vp = _write_pages(v_pools, i, v, ids)
                 return _attend(cfg, q, k, v, mask), (kp, vp)
+            return attend
 
-            x, (kp, vp) = _layer_math(cfg, lp, x, pos_vec, attend,
-                                      lora=lo)
-            return x, (kp, vp)
-
-        x, ys = _stack_apply(
-            body, x, _scan_operand(layers, k_pools, v_pools, lora),
-            scan_layers)
-        k_pools, v_pools = ys
+        x, k_pools, v_pools = _layers_over_pools(
+            x, k_pools, v_pools, layers, lora, pos_vec, attend_at)
         x = _rms(x, outer["model.norm.weight"], cfg.rms_norm_eps)
         # each sequence's last REAL position owns the next token
         x_last = jnp.take_along_axis(
@@ -1990,32 +2060,28 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
     @partial(jax.jit, donate_argnums=(5,))  # no per-token pool copy
     def decode_step(outer, layers, tok, page_tables, lengths, pools,
                     lora=None, grammar=None):
+        # each sequence's current end: where this token's K/V land
+        pages = jnp.take_along_axis(
+            page_tables, (lengths // page_size)[:, None], 1)[:, 0]
+        offs = lengths % page_size
         if pressure:
-            pools = _tier_clear(pools, jnp.take_along_axis(
-                page_tables, (lengths // page_size)[:, None], 1))
+            pools = _tier_clear(pools, pages)
         k_pools, v_pools, _tm = _tier_enter(pools)
         x = jnp.take(outer["model.embed_tokens.weight"], tok,
                      axis=0)[:, None]                    # (B, 1, H)
         pos = lengths[:, None]                           # per-sequence
 
-        def body(x, per_layer):
-            lp, kp_l, vp_l, lo = _split_per_layer(per_layer, lora)
-
+        def attend_at(i, k_pools, v_pools):
             def attend(q, k, v):
-                kp = _write_token(kp_l, k, page_tables, lengths)
-                vp = _write_token(vp_l, v, page_tables, lengths)
+                kp = _write_token(k_pools, i, k, pages, offs)
+                vp = _write_token(v_pools, i, v, pages, offs)
                 ctx = _paged_kernel(paged_attention, q[:, :, 0], kp, vp,
-                                    page_tables, lengths + 1)
+                                    page_tables, lengths + 1, layer=i)
                 return ctx[:, :, None], (kp, vp)
+            return attend
 
-            x, (kp, vp) = _layer_math(cfg, lp, x, pos, attend,
-                                      lora=lo)
-            return x, (kp, vp)
-
-        x, ys = _stack_apply(
-            body, x, _scan_operand(layers, k_pools, v_pools, lora),
-            scan_layers)
-        k_pools, v_pools = ys
+        x, k_pools, v_pools = _layers_over_pools(
+            x, k_pools, v_pools, layers, lora, pos, attend_at)
         x = _rms(x, outer["model.norm.weight"], cfg.rms_norm_eps)
         out = _emit(_gmask(_logits(cfg, outer, x[:, 0]), grammar))
         return out, _tier_exit(k_pools, v_pools, _tm)
@@ -2027,10 +2093,12 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         writes its pages, attends to every pool position < start+C, and
         harvests the hidden state of each sequence's (length-1) row when
         it falls inside this chunk."""
-        B, C = chunk.shape
+        C = chunk.shape[1]
+        # start and C are page multiples, so whole pages scatter
+        ids = jax.lax.dynamic_slice_in_dim(
+            page_tables, start // page_size, C // page_size, 1)
         if pressure:
-            pools = _tier_clear(pools, jax.lax.dynamic_slice_in_dim(
-                page_tables, start // page_size, C // page_size, 1))
+            pools = _tier_clear(pools, ids)
         k_pools, v_pools, _tm = _tier_enter(pools)
         W = page_tables.shape[1]
         S = W * page_size
@@ -2043,40 +2111,23 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
                < lengths[:, None, None])
         mask = key_ok[:, None]                       # (B, 1, C, S)
 
-        def body(x, per_layer):
-            lp, kp_l, vp_l, lo = _split_per_layer(per_layer, lora)
-
+        def attend_at(i, k_pools, v_pools):
             def attend(q, k, v):
-                kp = _write_chunk(kp_l, k, page_tables, start, C)
-                vp = _write_chunk(vp_l, v, page_tables, start, C)
+                kp = _write_pages(k_pools, i, k, ids)
+                vp = _write_pages(v_pools, i, v, ids)
                 if prefill_attention == "kernel":
                     ctx = _paged_kernel(paged_prefill_attention, q, kp,
-                                        vp, page_tables, lengths, start)
+                                        vp, page_tables, lengths, start,
+                                        layer=i)
                     return ctx, (kp, vp)
-
-                def gather(pool):
-                    """(B, nkv, S, hd): gather the batch's pages FIRST,
-                    dequantize only that slice — never the whole pool."""
-                    if isinstance(pool, tuple):
-                        data, sc = pool
-                        g = (data[:, page_tables].astype(jnp.float32)
-                             * sc[:, page_tables][..., None])
-                    else:
-                        g = pool[:, page_tables]
-                    return jnp.swapaxes(g, 0, 1).reshape(B, nkv, S, hd)
-
-                k_all, v_all = gather(kp), gather(vp)
+                k_all = _gather_pages(kp, i, page_tables)
+                v_all = _gather_pages(vp, i, page_tables)
                 return _attend(cfg, q, k_all.astype(q.dtype),
                                v_all.astype(q.dtype), mask), (kp, vp)
+            return attend
 
-            x, (kp, vp) = _layer_math(cfg, lp, x, pos_vec, attend,
-                                      lora=lo)
-            return x, (kp, vp)
-
-        x, ys = _stack_apply(
-            body, x, _scan_operand(layers, k_pools, v_pools, lora),
-            scan_layers)
-        k_pools, v_pools = ys
+        x, k_pools, v_pools = _layers_over_pools(
+            x, k_pools, v_pools, layers, lora, pos_vec, attend_at)
         # harvest rows whose (length-1) position lives in this chunk
         idx = jnp.clip(lengths - 1 - start, 0, C - 1)
         row = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32),
@@ -2086,29 +2137,6 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         x_last = jnp.where(hit, row, x_last)
         return x_last, _tier_exit(k_pools, v_pools, _tm)
 
-    def _write_chunk(pool_l, kv, page_tables, start, C):
-        """kv (B, nkv, C, hd) written at absolute positions start.. —
-        start and C are page multiples, so whole pages scatter."""
-        B = kv.shape[0]
-        npg = C // page_size
-        first = start // page_size
-        ids = jax.lax.dynamic_slice_in_dim(page_tables, first, npg,
-                                           1).reshape(-1)
-
-        def pageify(a, *trail):
-            a = a.reshape((B, nkv, npg, page_size) + tuple(trail))
-            order = (1, 0, 2, 3) + tuple(range(4, a.ndim))
-            return jnp.transpose(a, order).reshape(
-                (nkv, B * npg, page_size) + tuple(trail))
-
-        if isinstance(pool_l, tuple):
-            data, sc = pool_l
-            qd, s = _q8(kv)
-            return (data.at[:, ids].set(pageify(qd, hd)),
-                    sc.at[:, ids].set(pageify(s)))
-        return pool_l.at[:, ids].set(
-            pageify(kv, hd).astype(pool_l.dtype))
-
     @jax.jit
     def _finish_prefill(outer, x_last, grammar=None):
         x = _rms(x_last, outer["model.norm.weight"], cfg.rms_norm_eps)
@@ -2117,32 +2145,6 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
     prefill_chunked = chunked_prefill_shim(
         _prefill_chunk, _finish_prefill, chunked_prefill,
         cfg.hidden_size, dtype)
-
-    def _write_chunk_ragged(pool_l, kv, page_tables, starts, C):
-        """kv (R, nkv, C, hd) written at PER-ROW absolute positions
-        starts[r].. — per-row page ids gathered with take_along_axis
-        instead of one shared dynamic slice. Duplicate ids across rows
-        (idle rows all point at the reserved page 0; cohort rows
-        rewriting a shared cached page carry identical content) make
-        the scatter order unspecified but the result deterministic."""
-        R = kv.shape[0]
-        npg = C // page_size
-        col = (starts // page_size)[:, None] + jnp.arange(npg)[None, :]
-        ids = jnp.take_along_axis(page_tables, col, 1).reshape(-1)
-
-        def pageify(a, *trail):
-            a = a.reshape((R, nkv, npg, page_size) + tuple(trail))
-            order = (1, 0, 2, 3) + tuple(range(4, a.ndim))
-            return jnp.transpose(a, order).reshape(
-                (nkv, R * npg, page_size) + tuple(trail))
-
-        if isinstance(pool_l, tuple):
-            data, sc = pool_l
-            qd, s = _q8(kv)
-            return (data.at[:, ids].set(pageify(qd, hd)),
-                    sc.at[:, ids].set(pageify(s)))
-        return pool_l.at[:, ids].set(
-            pageify(kv, hd).astype(pool_l.dtype))
 
     @partial(jax.jit, donate_argnums=(6,))
     def _prefill_chunk_ragged(outer, layers, chunk, starts, page_tables,
@@ -2155,12 +2157,17 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
         point their pages at the reserved padding page 0 and write
         garbage there (the pool convention); their x_last never
         updates because length-1 falls outside the chunk window."""
-        R, C = chunk.shape
+        C = chunk.shape[1]
+        # per-row page ids, gathered where _prefill_chunk takes one
+        # shared slice. Duplicate ids across rows (idle rows all point at
+        # the reserved page 0; cohort rows rewriting a shared cached page
+        # carry identical content) make the scatter order unspecified
+        # but the result deterministic.
+        col = (starts // page_size)[:, None] \
+            + jnp.arange(C // page_size)[None, :]
+        ids = jnp.take_along_axis(page_tables, col, 1)
         if pressure:
-            col = (starts // page_size)[:, None] + jnp.arange(
-                C // page_size)[None, :]
-            pools = _tier_clear(
-                pools, jnp.take_along_axis(page_tables, col, 1))
+            pools = _tier_clear(pools, ids)
         k_pools, v_pools, _tm = _tier_enter(pools)
         W = page_tables.shape[1]
         S = W * page_size
@@ -2173,38 +2180,18 @@ def llama_paged_decode_factory(model: LlamaForCausalLM,
                < lengths[:, None, None])
         mask = key_ok[:, None]                       # (R, 1, C, S)
 
-        def body(x, per_layer):
-            lp, kp_l, vp_l, lo = _split_per_layer(per_layer, lora)
-
+        def attend_at(i, k_pools, v_pools):
             def attend(q, k, v):
-                kp = _write_chunk_ragged(kp_l, k, page_tables, starts,
-                                         C)
-                vp = _write_chunk_ragged(vp_l, v, page_tables, starts,
-                                         C)
-
-                def gather(pool):
-                    """(R, nkv, S, hd): gather the batch's pages FIRST,
-                    dequantize only that slice — never the whole
-                    pool."""
-                    if isinstance(pool, tuple):
-                        data, sc = pool
-                        g = (data[:, page_tables].astype(jnp.float32)
-                             * sc[:, page_tables][..., None])
-                    else:
-                        g = pool[:, page_tables]
-                    return jnp.swapaxes(g, 0, 1).reshape(R, nkv, S, hd)
-
-                k_all, v_all = gather(kp), gather(vp)
+                kp = _write_pages(k_pools, i, k, ids)
+                vp = _write_pages(v_pools, i, v, ids)
+                k_all = _gather_pages(kp, i, page_tables)
+                v_all = _gather_pages(vp, i, page_tables)
                 return _attend(cfg, q, k_all.astype(q.dtype),
                                v_all.astype(q.dtype), mask), (kp, vp)
+            return attend
 
-            x, (kp, vp) = _layer_math(cfg, lp, x, pos, attend, lora=lo)
-            return x, (kp, vp)
-
-        x, ys = _stack_apply(
-            body, x, _scan_operand(layers, k_pools, v_pools, lora),
-            scan_layers)
-        k_pools, v_pools = ys
+        x, k_pools, v_pools = _layers_over_pools(
+            x, k_pools, v_pools, layers, lora, pos, attend_at)
         idx = jnp.clip(lengths - 1 - starts, 0, C - 1)
         row = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32),
                                   1)[:, 0]

@@ -16,12 +16,25 @@ online-softmax accumulation over the grid's page axis with VMEM scratch
 carrying (m, l, acc) between pages. GQA: all G query heads sharing a kv
 head run in one program, so each page is fetched ONCE per kv head.
 
+The pools of a whole model are ONE operand each, (L, Hkv, P, page_size,
+D), addressed in place by (layer, page): the layer index is part of the
+page operand's block index (layer, h, page_tables[b, j], 0, 0) and rides
+the scalar-prefetch channel beside the page table, so it may be a Python
+int or a traced scalar (a scanned layer loop's counter) and no layer
+slice of a pool is ever made. A (Hkv, P, page_size, D) pool is the
+one-layer case of the same call.
+
 API:
-  paged_attention(q, k_pages, v_pages, page_tables, seq_lens)
+  paged_attention(q, k_pages, v_pages, page_tables, seq_lens, layer=None)
     q           (B, Hq, D)            one decode position per sequence
-    k/v_pages   (Hkv, P, page_size, D) global page pools
+    k/v_pages   (L, Hkv, P, page_size, D) whole pools + ``layer``, or
+                (Hkv, P, page_size, D) one layer's pages (``layer`` None)
     page_tables (B, pages_per_seq)    page ids (padding ids are masked)
     seq_lens    (B,)                  real lengths -> (B, Hq, D)
+  paged_prefill_attention(q (B, Hq, C, D), ..., q_start, layer=None)
+    the same pools, a C-token query chunk -> (B, Hq, C, D)
+  k_scales / v_scales (int8 pools): the pools' shape less D; the kernel
+    reads the one layer's, sliced by the launcher (``_paged_call``).
 """
 from __future__ import annotations
 
@@ -38,8 +51,8 @@ from .flash_attention import NEG_INF
 from .lowering import interpret as _interpret
 
 
-def _paged_kernel(st_ref, pt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
-                  sm_scale, page_size, chunk, quantized=False):
+def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_ref, v_ref,
+                  *rest, sm_scale, page_size, chunk, quantized=False):
     """ONE program per (sequence, kv head, page), shared by decode and
     chunked prefill: (G*chunk) query rows accumulate online softmax over
     the page axis with VMEM scratch. Row r sits at absolute position
@@ -48,7 +61,8 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
     st = seq_len - 1. ``quantized``: int8 K/V refs with two per-slot f32
     scale refs preceding the output; dequant happens here in VMEM.
     Pages entirely beyond the causal horizon or the sequence length are
-    skipped (no dot/exp), though their DMA is already pipelined."""
+    skipped (no dot/exp), though their DMA is already pipelined.
+    ``ly_ref`` (the layer) is the block index's business alone."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -105,24 +119,43 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
-                chunk, sm_scale, k_scales, v_scales):
-    """Shared launcher: q4 (B, Hkv, G*chunk, D) -> same shape out."""
-    B, Hkv, rows, D = q4.shape
-    _, P, page_size, Dk = k_pages.shape
-    if D != Dk:
-        raise ValueError(f"head_dim mismatch: q {D} vs pages {Dk}")
-    n_pages = page_tables.shape[1]
+                chunk, sm_scale, k_scales, v_scales, layer):
+    """Shared launcher: q4 (B, Hkv, G*chunk, D) -> same shape out. The
+    pools are (L, Hkv, P, page_size, D) read at ``layer`` (int or traced
+    scalar); 4-D pools are lifted to the L = 1 pool they are."""
     quantized = k_scales is not None or v_scales is not None
     if quantized and (k_scales is None or v_scales is None):
         raise ValueError("int8 pools need BOTH k_scales and v_scales")
+    if k_pages.ndim == 4:
+        if layer is not None:
+            raise ValueError("a layer index needs the whole "
+                             "(L, Hkv, P, page_size, D) pools")
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        layer = 0
+    elif layer is None:
+        raise ValueError("(L, Hkv, P, page_size, D) pools need the layer "
+                         "to read")
+    elif quantized:
+        # the kernel wants its scales (page_size, 1), and that trailing
+        # axis is a lane-padded relayout of 128 x the bytes: made of ONE
+        # layer's scales (1 MB -> 134 MB at 8 x 513 x 64), where the
+        # pool's would be pool-sized (2.15 GB temporaries, AOT for v5e)
+        k_scales, v_scales = k_scales[layer], v_scales[layer]
+    B, Hkv, rows, D = q4.shape
+    _, _, P, page_size, Dk = k_pages.shape
+    if D != Dk:
+        raise ValueError(f"head_dim mismatch: q {D} vs pages {Dk}")
+    n_pages = page_tables.shape[1]
 
-    q_spec = pl.BlockSpec((1, 1, rows, D), lambda b, h, j, st, pt, sl:
+    q_spec = pl.BlockSpec((1, 1, rows, D), lambda b, h, j, st, pt, sl, ly:
                           (b, h, 0, 0))
-    page_spec = pl.BlockSpec((1, 1, page_size, D),
-                             lambda b, h, j, st, pt, sl:
-                             (h, pt[b, j], 0, 0))
+    # the layer axis is squeezed out of the block: the kernel sees the
+    # (1, 1, page_size, D) page it always saw
+    page_spec = pl.BlockSpec((None, 1, 1, page_size, D),
+                             lambda b, h, j, st, pt, sl, ly:
+                             (ly[0], h, pt[b, j], 0, 0))
     scale_spec = pl.BlockSpec((1, 1, page_size, 1),
-                              lambda b, h, j, st, pt, sl:
+                              lambda b, h, j, st, pt, sl, ly:
                               (h, pt[b, j], 0, 0))
     in_specs = [q_spec, page_spec, page_spec]
     args = [q4, k_pages, v_pages]
@@ -131,11 +164,12 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
         args += [k_scales[..., None].astype(jnp.float32),
                  v_scales[..., None].astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Hkv, n_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, rows, D),
-                               lambda b, h, j, st, pt, sl: (b, h, 0, 0)),
+                               lambda b, h, j, st, pt, sl, ly:
+                               (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -153,18 +187,21 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(jnp.asarray(starts, jnp.int32).reshape(B),
       jnp.asarray(page_tables, jnp.int32),
-      jnp.asarray(seq_lens, jnp.int32), *args)
+      jnp.asarray(seq_lens, jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *args)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
-                    sm_scale=None, k_scales=None, v_scales=None):
+                    sm_scale=None, k_scales=None, v_scales=None,
+                    layer=None):
     """Decode-step attention over a paged KV pool (shapes in the module
-    docstring). ``k_scales``/``v_scales`` (Hkv, P, page_size) switch the
-    int8-pool path: pages are int8 and dequantized in VMEM per block.
+    docstring). ``k_scales``/``v_scales`` (the pools' shape less D)
+    switch the int8-pool path: pages are int8 and dequantized in VMEM
+    per block. ``layer`` names the layer of a 5-D pool to read.
     Non-differentiable by design — a serving kernel. Internally the
     chunk=1 case of the shared paged kernel with start = seq_len - 1."""
     B, Hq, D = q.shape
-    Hkv = k_pages.shape[0]
+    Hkv = k_pages.shape[-4]
     if Hq % Hkv:
         raise ValueError(f"query heads {Hq} not a multiple of kv heads "
                          f"{Hkv}")
@@ -174,7 +211,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
     sl = jnp.asarray(seq_lens, jnp.int32)
     out = _paged_call(q.reshape(B, Hkv, G, D), k_pages, v_pages,
                       page_tables, sl, jnp.maximum(sl - 1, 0), 1,
-                      sm_scale, k_scales, v_scales)
+                      sm_scale, k_scales, v_scales, layer)
     return out.reshape(B, Hq, D)
 
 
@@ -957,7 +994,7 @@ class PagedKVCache:
 
 def paged_prefill_attention(q, k_pages, v_pages, page_tables, seq_lens,
                             q_start, sm_scale=None, k_scales=None,
-                            v_scales=None):
+                            v_scales=None, layer=None):
     """Causal attention of a C-token query chunk against the paged pool
     (the chunk's own K/V must already be written to its pages).
 
@@ -968,7 +1005,7 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, seq_lens,
     length are skipped.
     """
     B, Hq, C, D = q.shape
-    Hkv = k_pages.shape[0]
+    Hkv = k_pages.shape[-4]
     if Hq % Hkv:
         raise ValueError(f"query heads {Hq} not a multiple of kv heads "
                          f"{Hkv}")
@@ -978,5 +1015,5 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, seq_lens,
     starts = jnp.full((B,), q_start, jnp.int32)
     out = _paged_call(q.reshape(B, Hkv, G * C, D), k_pages, v_pages,
                       page_tables, jnp.asarray(seq_lens, jnp.int32),
-                      starts, C, sm_scale, k_scales, v_scales)
+                      starts, C, sm_scale, k_scales, v_scales, layer)
     return out.reshape(B, Hq, C, D)

@@ -9,6 +9,8 @@ chip branches and runs the real Mosaic + XLA:TPU compilers. Nothing
 executes: this proves "it compiles", not "it is right" — that is
 chip_smoke.py's job on the chip.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -196,22 +198,103 @@ def test_latent_decode_program_keeps_the_pool_in_place(v5e):
     assert stats.temp_size_in_bytes < pool_bytes // 8
 
 
-def test_tp_sharded_paged_call_on_four_chips(v5e):
+# the Mistral serving cells' geometry (serve_chat_steady, serve_prefill_burst):
+# 16 slots x 66 pages of 64 positions, 8 KV heads x 128 under 32 query
+# heads, a pool of 513 pages x 8 layers = 0.54 GB each for K and V
+SERVE = dict(slots=16, table=66, page=64, heads=32, kv_heads=8, pool=513,
+             layers=8, hidden=4096, inter=14336, vocab=32768)
+
+
+def test_llama_paged_programs_keep_the_pools_in_place(v5e):
+    """``decode_n`` (n = 1) and ``_prefill_chunk`` at the serving cells'
+    geometry: both pools are donated and aliased, nothing of a pool's or a
+    layer's pages' size is made beside them, and the kernel is ONE call in
+    the scanned layer body, fed the whole pools. (As a scan's xs / ys the
+    pools were sliced a layer at a time and stacked into fresh ones: 1.48
+    / 1.18 GB of temporaries and 21 / 19 pool-shaped results; with the kv
+    heads a SLICE of the scatter XLA:TPU stored the pools heads-minor and
+    converted all of them there and back, every layer.)"""
+    from paddle_tpu.models.nlp.llama_decode import llama_paged_decode_factory
+    one = SingleDeviceSharding(v5e[0])
+    g = SERVE
+    H, KV = g["hidden"], g["kv_heads"] * HD
+    # the programs take their widths from their arguments: a one-layer
+    # model of the cell's head geometry builds them, the cell's shapes
+    # (8 layers, the real MLP and vocabulary) are what is compiled
+    cfg = LlamaConfig(vocab_size=64, hidden_size=H, intermediate_size=64,
+                      num_hidden_layers=1, num_attention_heads=g["heads"],
+                      num_key_value_heads=g["kv_heads"], rope_theta=1e6,
+                      max_position_embeddings=g["table"] * g["page"],
+                      dtype=BF16)
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    outer, layers, _, prefill, _, decode_n = llama_paged_decode_factory(
+        model, page_size=g["page"], n_pool_pages=3,
+        chunked_prefill=g["page"])
+    bf = lambda *s: _sds(s, BF16, one)  # noqa: E731
+    i32 = lambda *s: _sds(s, jnp.int32, one)  # noqa: E731
+    L, I, V = g["layers"], g["inter"], g["vocab"]
+    wide = {"q_proj": (H, H), "k_proj": (H, KV), "v_proj": (H, KV),
+            "o_proj": (H, H), "gate_proj": (H, I), "up_proj": (H, I),
+            "down_proj": (I, H)}                  # the norms are (H,)
+    layers_s = {k: bf(L, *wide.get(k.split(".")[-2], (H,)))
+                for k in layers}
+    outer_s = {k: bf(*{"model.embed_tokens.weight": (V, H),
+                       "lm_head.weight": (H, V)}.get(k, (H,)))
+               for k in outer}
+    pool = bf(L, g["kv_heads"], g["pool"], g["page"], HD)
+    pool_bytes = 2 * L * g["kv_heads"] * g["pool"] * g["page"] * HD * 2
+    B, W = g["slots"], g["table"]
+    with lower_for_chip():
+        programs = {
+            "decode_n": decode_n.lower(
+                outer_s, layers_s, i32(B), i32(B, W), i32(B), (pool, pool),
+                1).compile(),
+            "_prefill_chunk": prefill._jit_inner[0].lower(
+                outer_s, layers_s, i32(1, g["page"]), i32(), i32(1, W),
+                i32(1), (pool, pool), bf(1, H)).compile()}
+    assert _mosaic_calls(programs["decode_n"]) == 1     # the scanned body's
+    moved = re.compile(
+        r"= \w+\[[\d,]*%d,%d,%d\]\S* (copy|copy-start|copy-done|"
+        r"dynamic-slice|dynamic-update-slice)\(" % (g["pool"], g["page"], HD))
+    for name, c in programs.items():
+        stats = c.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes, name
+        assert stats.temp_size_in_bytes < pool_bytes // 8, (
+            name, stats.temp_size_in_bytes)
+        assert not [ln for ln in c.as_text().splitlines()
+                    if moved.search(ln)], name
+
+
+@pytest.mark.parametrize("whole", [False, True],
+                         ids=["one_layer", "pool_and_layer"])
+def test_tp_sharded_paged_call_on_four_chips(v5e, whole):
     """The paged kernel under tp=4: kv heads manual over the tp axis
-    (``paged_kernel_call``); the bare sharded call is what JAX refuses."""
+    (``paged_kernel_call``), for one layer's pages and for the whole pools
+    read at a traced layer (``tp_pool_spec``'s layout, the index
+    replicated); the bare sharded call is what JAX refuses."""
     from paddle_tpu.models.nlp.llama_decode import paged_kernel_call
     from paddle_tpu.ops.pallas.paged_attention import paged_attention
     mesh = Mesh(np.asarray(v5e), ("tp",))
     ns = lambda *names: NamedSharding(mesh, P(*names))  # noqa: E731
-    pool = _sds((NH, POOL, PAGE, HD), BF16, ns("tp"))
+    pool = _sds((2, NH, POOL, PAGE, HD), BF16, ns(None, "tp")) if whole \
+        else _sds((NH, POOL, PAGE, HD), BF16, ns("tp"))
     args = (_sds((SLOTS, NH, HD), BF16, ns(None, "tp")), pool, pool,
             _sds((SLOTS, WIDTH), jnp.int32, ns()),
             _sds((SLOTS,), jnp.int32, ns()))
-    c = _compile(lambda *a: paged_kernel_call(paged_attention, *a,
-                                              mesh=mesh, axis="tp"), *args)
+    if whole:
+        c = _compile(lambda *a: paged_kernel_call(
+            paged_attention, *a[:-1], layer=a[-1], mesh=mesh, axis="tp"),
+            *args, _sds((), jnp.int32, ns()))
+    else:
+        c = _compile(lambda *a: paged_kernel_call(paged_attention, *a,
+                                                  mesh=mesh, axis="tp"),
+                     *args)
     assert _mosaic_calls(c) == 1
     with pytest.raises(NotImplementedError, match="automatically partition"):
-        _compile(paged_attention, *args)
+        _compile(lambda *a: paged_attention(*a, layer=1 if whole else None),
+                 *args)
 
 
 @pytest.mark.parametrize("shape,names", [((2, 2), ("data", "model")),

@@ -257,3 +257,85 @@ def test_prefix_cache_child_keys_die_with_parent():
     s = cache.cache_stats()
     assert s["resident_pages"] + s["evictable_pages"] \
         + s["free_pages"] == s["n_pages"]
+
+
+# --- the whole pools addressed by (layer, page) ----------------------------
+
+def _q8_np(x):
+    x = np.asarray(x, np.float32)
+    sc = np.maximum(np.abs(x).max(-1), 1e-8) / 127.0
+    qd = np.clip(np.round(x / sc[..., None]), -127, 127)
+    return jnp.asarray(qd.astype(np.int8)), jnp.asarray(
+        sc.astype(np.float32))
+
+
+@pytest.mark.parametrize("traced", [False, True],
+                         ids=["int_layer", "traced_layer"])
+@pytest.mark.parametrize("codec", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_whole_pool_read_at_a_layer(kind, codec, traced):
+    """The kernels given the (L, Hkv, P, page, D) pools and a layer index
+    — a Python int or a traced scalar — read that layer's pages: equal to
+    the oracle there, and bit-identical to the 4-D call on ``pool[layer]``
+    (the one-layer case of the same kernel)."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_prefill_attention)
+    rng = np.random.default_rng(23)
+    L, B, Hq, Hkv, D, P, ps, W, C = 3, 2, 4, 2, 16, 9, 8, 3, 8
+    layer, start = 2, 8
+    dt = jnp.bfloat16 if codec == "bf16" else jnp.float32
+    kp = jnp.asarray(rng.normal(0, 1, (L, Hkv, P, ps, D)), dt)
+    vp = jnp.asarray(rng.normal(0, 1, (L, Hkv, P, ps, D)), dt)
+    pt = jnp.asarray(rng.choice(np.arange(1, P), (B, W), replace=False),
+                     jnp.int32)
+    if kind == "decode":
+        q = jnp.asarray(rng.normal(0, 1, (B, Hq, D)), dt)
+        sl = jnp.asarray([13, 16], jnp.int32)
+
+        def kernel(k, v, **kw):
+            return paged_attention(q, k, v, pt, sl, **kw)
+    else:
+        q = jnp.asarray(rng.normal(0, 1, (B, Hq, C, D)), dt)
+        sl = jnp.asarray([start + C, start + 5], jnp.int32)
+
+        def kernel(k, v, **kw):
+            return paged_prefill_attention(q, k, v, pt, sl, start, **kw)
+
+    scales = {}
+    kf, vf = kp, vp                      # what the oracle reads
+    if codec == "int8":
+        (kp, ks), (vp, vs) = _q8_np(kp), _q8_np(vp)
+        scales = dict(k_scales=ks, v_scales=vs)
+        kf = kp.astype(jnp.float32) * ks[..., None]
+        vf = vp.astype(jnp.float32) * vs[..., None]
+
+    if traced:
+        got = jax.jit(lambda i: kernel(kp, vp, layer=i, **scales))(
+            jnp.int32(layer))
+    else:
+        got = kernel(kp, vp, layer=layer, **scales)
+    one_layer = kernel(kp[layer], vp[layer],
+                       **{n: s[layer] for n, s in scales.items()})
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(one_layer, np.float32))
+
+    tol = 2e-2 if codec == "bf16" else 2e-5
+    rows = [None] if kind == "decode" else range(C)
+    for c in rows:                       # a chunk row = a decode position
+        qc = q if c is None else q[:, :, c]
+        lens = sl if c is None else jnp.minimum(sl, start + c + 1)
+        want = paged_attention_reference(qc, kf[layer], vf[layer], pt,
+                                         lens)
+        np.testing.assert_allclose(
+            np.asarray(got if c is None else got[:, :, c], np.float32),
+            np.asarray(want, np.float32), rtol=tol, atol=tol)
+
+
+def test_layer_index_and_pool_rank_must_agree():
+    rng = np.random.default_rng(29)
+    q, kp, vp, pt = _setup(rng)
+    sl = jnp.asarray([5, 9], jnp.int32)
+    with pytest.raises(ValueError, match="whole"):
+        paged_attention(q, kp, vp, pt, sl, layer=0)
+    with pytest.raises(ValueError, match="need the layer"):
+        paged_attention(q, kp[None], vp[None], pt, sl)

@@ -3,6 +3,7 @@ the paged KV pool must reproduce the eager model's greedy tokens — per
 sequence, at RAGGED lengths in one batch."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.nlp import LlamaConfig, LlamaForCausalLM
@@ -471,3 +472,77 @@ def test_decode_n_logits_mode_greedy_feedback():
                                   np.asarray(emits_t))
     np.testing.assert_array_equal(np.asarray(last_l),
                                   np.asarray(last_t))
+
+
+# --- the pools ride the layer loop's carry, never its xs / ys ---------------
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    import jax
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("codec", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("program", ["decode_n", "decode_step",
+                                     "prefill_chunk", "ragged_chunk",
+                                     "oneshot_prefill"])
+def test_no_scan_slices_or_stacks_the_pools(program, codec):
+    """A scan slices every layer out of its ``xs`` and stacks its ``ys``
+    into a fresh buffer: a pool there is a copy of the pool a call,
+    whatever is donated (the 1.08 GB the serving cells copied twice a
+    call). So in every paged program's jaxpr the pools — and a layer's
+    pages of them — appear among no scan's scanned inputs or stacked
+    outputs; they ride a carry, which the loop updates in place."""
+    import jax
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(vocab=64, hidden=32, layers=3, heads=4,
+                           kv_heads=2)
+    n_pages, B, W = 23, 2, 4
+    outer, layers, pools, prefill, decode_step, decode_n = \
+        llama_paged_decode_factory(
+            LlamaForCausalLM(cfg), page_size=PS, n_pool_pages=n_pages,
+            kv_cache_dtype=codec,
+            chunked_prefill=None if program == "oneshot_prefill" else PS)
+    pt = jnp.zeros((B, W), jnp.int32)
+    lens = jnp.ones((B,), jnp.int32)
+    tok = jnp.zeros((B,), jnp.int32)
+    chunk = jnp.zeros((B, PS), jnp.int32)
+    x_last = jnp.zeros((B, cfg.hidden_size), jnp.float32)
+    if program == "decode_n":
+        jaxpr = jax.make_jaxpr(decode_n, static_argnums=(6,))(
+            outer, layers, tok, pt, lens, pools, 3)
+    elif program == "decode_step":
+        jaxpr = jax.make_jaxpr(decode_step)(outer, layers, tok, pt, lens,
+                                            pools)
+    elif program == "prefill_chunk":
+        jaxpr = jax.make_jaxpr(prefill._jit_inner[0])(
+            outer, layers, chunk, 0, pt, lens, pools, x_last)
+    elif program == "ragged_chunk":
+        jaxpr = jax.make_jaxpr(prefill._ragged._jit_inner[0])(
+            outer, layers, chunk, jnp.zeros((B,), jnp.int32), pt, lens,
+            pools, x_last)
+    else:
+        jaxpr = jax.make_jaxpr(prefill)(outer, layers, chunk, pt, lens,
+                                        pools)
+
+    page_dims = {(2, n_pages, PS, 8), (2, n_pages, PS)}   # data, scales
+
+    def of_the_pools(v):
+        shape = tuple(v.aval.shape)
+        return shape[-4:] in page_dims or shape[-3:] in page_dims
+
+    scans = list(_scans(jaxpr.jaxpr))
+    assert scans                                   # the layer loop is one
+    carried = 0
+    for eqn in scans:
+        fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+        xs, ys = eqn.invars[fixed:], eqn.outvars[eqn.params["num_carry"]:]
+        assert not [v.aval for v in xs if of_the_pools(v)], "pools in xs"
+        assert not [v.aval for v in ys if of_the_pools(v)], "pools in ys"
+        carried += sum(map(of_the_pools,
+                           eqn.invars[eqn.params["num_consts"]:fixed]))
+    assert carried >= len(jax.tree_util.tree_leaves(pools))

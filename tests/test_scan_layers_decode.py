@@ -143,7 +143,62 @@ class TestSpeculativeParity:
                                                  weight_bytes)
 
 
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["scanned", "unrolled"])
+def test_stack_carry_counts_layers_and_keeps_state(scan_layers):
+    """The carrying form of the layer loop: the body is told its layer
+    (a traced counter under the scan, a Python int unrolled), reads its
+    slice of the stacked operands, and what it returns is the next
+    layer's state — nothing is stacked."""
+    from paddle_tpu.models.nlp.llama_decode import _stack_carry
+    w = jnp.arange(12.0).reshape(4, 3)
+    seen = []
+
+    def body(i, carry, per_layer):
+        seen.append(i)
+        x, book = carry
+        return x + per_layer["w"].sum(), book.at[i].set(x)
+
+    x, book = jax.jit(lambda w: _stack_carry(
+        body, (jnp.float32(1.0), jnp.zeros((4,))), {"w": w},
+        scan_layers))(w)
+    assert float(x) == 1.0 + float(w.sum())
+    np.testing.assert_array_equal(np.asarray(book), [1., 4., 16., 37.])
+    if scan_layers:
+        assert len(seen) == 1 and isinstance(seen[0], jax.core.Tracer)
+    else:
+        assert seen == [0, 1, 2, 3]
+
+
 class TestPagedParity:
+    @pytest.mark.parametrize("build", [
+        dict(chunked_prefill=8),
+        dict(chunked_prefill=8, prefill_attention="kernel"),
+        dict(chunked_prefill=8, kv_cache_dtype="int8"),
+        dict(kv_quant="pressure"),
+    ], ids=["chunked", "chunked_kernel", "chunked_int8", "pressure"])
+    def test_pool_contents_scan_vs_unrolled(self, model, prompt, build):
+        """Both forms of the layer loop address the carried pools by
+        (layer, page): after a prefill and a ``decode_n`` the tokens AND
+        every pool leaf are equal, bit for bit."""
+        outs = {}
+        for flag in (True, False):
+            outer, layers, pools, prefill, _, decode_n = \
+                llama_paged_decode_factory(model, page_size=8,
+                                           n_pool_pages=32,
+                                           scan_layers=flag, **build)
+            pt = jnp.asarray(
+                np.arange(1, 9, dtype=np.int32).reshape(2, 4))
+            lens = jnp.asarray([6, 5], jnp.int32)
+            tok = jnp.asarray(np.pad(prompt, ((0, 0), (0, 2))))
+            nxt, pools = prefill(outer, layers, tok, pt, lens, pools)
+            emits, _, pools = decode_n(outer, layers, nxt, pt, lens,
+                                       pools, 4)
+            outs[flag] = [np.asarray(nxt), np.asarray(emits)] + [
+                np.asarray(a) for a in jax.tree_util.tree_leaves(pools)]
+        for a, b in zip(outs[True], outs[False]):
+            np.testing.assert_array_equal(a, b)
+
     def test_prefill_decode_token_exact(self, model, prompt):
         outs = {}
         for flag in (True, False):
