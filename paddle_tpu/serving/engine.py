@@ -765,6 +765,11 @@ class KVHandoff:
 class ServingEngine:
     """Replay a trace (workload.Request list) through the serving stack.
 
+    The engine holds the configuration, the compiled programs and the
+    turn's helpers; what one turn is, and the state of one run, live
+    in ``EngineSession``. ``run(trace)`` feeds a session a whole trace
+    in one call, ``session()`` hands one to a router that feeds it.
+
     ``slots``: concurrent paged decode rows (the fixed compiled batch
     shape; empty slots ride along as length-0 page-0 rows) and the dense
     routing capacity. ``decode_chunk``: decode steps fused per scheduler
@@ -1475,8 +1480,8 @@ class ServingEngine:
             raise ValueError("ledger= needs clock='measured' or "
                              "'fixed'")
         self._ledger = ledger
-        # the host spans of the run (or session turn) in progress;
-        # run() and EngineSession put their own here
+        # the host spans of the turn in progress; each EngineSession
+        # puts its own here
         self._phases = obs_trace.HostPhases(keep=False)
         self.eos_token_id = eos_token_id
         self._expect_churn = expect_churn
@@ -1566,8 +1571,8 @@ class ServingEngine:
         (``pool_bytes_per_device`` — a ThresholdRule can watch it).
         No-op unsharded and unquantized: cache_stats/metrics stay
         byte-identical. With kv_quant= the bookkeeper is also armed
-        with the tier pricing/compaction hooks here (one seam for
-        run(), _run_scheduled() and sessions), and under 'pressure'
+        with the tier pricing/compaction hooks here (every run is a
+        session, so one seam), and under 'pressure'
         the streamed signal is the LOGICAL stored-byte census —
         occupied pages priced by tier — not the static arena size:
         it moves as rows land and parked pages compact, which is
@@ -1841,27 +1846,14 @@ class ServingEngine:
         return f"tenant/{r.tenant}" if r.tenant is not None \
             else "requests"
 
-    def _make_tracer(self, clock) -> Optional[obs_trace.Tracer]:
+    def _make_tracer(self) -> Optional[obs_trace.Tracer]:
         spec = self._trace_spec
         if spec is None or spec is False:
             return None
         if isinstance(spec, obs_trace.Tracer):
-            t = spec
-            t.clear()   # each run() is one trace
-        else:
-            t = obs_trace.Tracer()
-        t.set_clock(clock.now)  # spans live in the CLOCK's time
-        return t
-
-    def _close_trace(self, tr: Optional[obs_trace.Tracer], clock):
-        """Run end: the host spans join the trace where they share its
-        time base (a wall clock alone), then a path spec exports."""
-        if tr is None:
-            return
-        if clock.mode == "wall":
-            self._phases.to_tracer(tr, clock.t_zero)
-        if isinstance(self._trace_spec, str):
-            tr.export(self._trace_spec)
+            spec.clear()   # each run() is one trace
+            return spec
+        return obs_trace.Tracer()
 
     def _make_monitor(self, fresh: bool = True) \
             -> Optional[obs_slo.SLOMonitor]:
@@ -2293,7 +2285,7 @@ class ServingEngine:
         return self._footprint_len(len(r.prompt), r.max_new_tokens)
 
     def _order_wave(self, wave) -> List[Request]:
-        """Cache-aware co-scheduling for the FIFO loop's PAGED branch:
+        """Cache-aware co-scheduling for the FIFO wave's PAGED branch:
         requests whose prompts open with the same first page become
         ADJACENT (groups in first-arrival order, members in their
         incoming order), so when slots run out mid-wave a cohort is
@@ -2302,7 +2294,7 @@ class ServingEngine:
         stay resident while every sharer needs them. Prompts that
         share no page keep their order exactly (every group is a
         singleton), so plain traces replay bit-identically. Routing,
-        dense waves and the QoS loop never see this reordering: dense
+        dense waves and the QoS wave never see this reordering: dense
         has no page cache to win, and the QoS scheduler's
         priority/WFQ order is authoritative (cache awareness enters
         its admission through ``ServiceEstimator.prefill_cost``
@@ -2358,204 +2350,28 @@ class ServingEngine:
                         f"{r.rid}: unknown schema {r.schema!r} "
                         f"(registered: {self._grammar_store.names()})")
 
-    # --- the replay loop --------------------------------------------------
+    # --- the replay -------------------------------------------------------
     def run(self, trace: List[Request]) -> ServeResult:
-        if self.scheduler is not None:
-            return self._run_scheduled(trace, self.scheduler)
+        """Replay a whole trace on the engine's clock, under the
+        engine's own ``trace=`` / ``slo=`` / ``scheduler=``
+        configuration: one ``EngineSession`` that is handed every
+        arrival up front (``EngineSession.replay``) where the cluster
+        router hands its sessions one at a time. The trace is
+        validated as a whole before any work."""
         self._validate(trace)
-        clock = self._make_clock()
-        tr = self._make_tracer(clock)
-        mon = self._make_monitor()
-        m = MetricsCollector(monitor=mon)
-        book = PagedKVCache(self.n_pool_pages, self.page_size,
-                            kv_heads=1, head_dim=1)  # bookkeeping only:
-        # tables/lengths/free-list/prefix refcounts — device pages live
-        # in the factory pools, written by prefill/decode_n
-        self._note_pool(book, m)
-        hst = self._arm_hostmem(book, clock, m, tr)
-        acache = self._make_adapter_cache()
-        gcache = self._make_grammar_cache()
-        spst = self._make_spec_state()
-        qst = self._make_quant_state()
-        ahst = self._make_ahead_state()
-        run_w0 = self._open_phases(clock)
-        pages_total = len(book._free)
-        pending = deque(sorted(trace, key=lambda r: (r.arrival, r.rid)))
-        waiting: List[Request] = []
-        active: Dict[str, _PagedRow] = {}
-        lane = deque() if self.prefill_chunk_budget is not None \
-            else None
-        free_slots = list(range(self.slots))
-        outputs: Dict[str, List[int]] = {}
-        decisions: List[dict] = []
-        slot_log: List[tuple] = []
-        prefix_cached: Dict[str, int] = {}
-        seen_groups: set = set()
-        prefill_tokens = 0
-        inv_ok = True
-        a_inv = True
-        g_inv = True
+        tr = self._make_tracer()
         expect_churn = self._expect_churn if self._expect_churn \
             is not None else any(r.cancel_after is not None
                                  for r in trace)
-        ctx_base = {"capacity": self.slots, "expect_churn": expect_churn}
-
-        prev_tr = obs_trace.active()
+        sess = EngineSession(self, tracer=tr, expect_churn=expect_churn,
+                             slo=self._make_monitor())
         if tr is not None:
-            obs_trace.activate(tr)
-        try:
-            while pending or waiting or active or lane:
-                with self._phase("turn"):
-                    with self._phase("intake"):
-                        now = clock.now()
-                        while pending and pending[0].arrival <= now + 1e-12:
-                            r = pending.popleft()
-                            waiting.append(r)
-                            # QoS fields ride along so a FIFO baseline
-                            # run on a QoS trace still reports deadline
-                            # attainment/goodput; on a plain trace they
-                            # are all None and the metrics record stays
-                            # byte-identical to PR 2
-                            m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
-                                         priority=r.priority,
-                                         deadline_ms=r.deadline_ms)
-                            self._ctr_arrived.inc()
-                            self._req_open(tr, r)
-                        m.on_queue_depth(now, len(waiting))
-                        if tr is not None:
-                            tr.counter("queue_depth", len(waiting), t=now)
-
-                    progressed = False
-                    with self._phase("admit"):
-                        if waiting and self._admission_ready(waiting, pending,
-                                                             active, clock):
-                            wave = waiting[:self.admission.max_batch]
-                            groups = [r.prefix_group for r in wave
-                                      if r.prefix_group is not None]
-                            shared = (len(groups) != len(set(groups))
-                                      or any(g in seen_groups for g in groups))
-                            ctx = dict(ctx_base, shared_prefix=shared,
-                                       active_paged=len(active)
-                                       + (len(lane) if lane else 0))
-                            backend, reason = self.policy.route(wave, ctx)
-                            decision = {
-                                "t": round(clock.now(), 6), "wave": len(wave),
-                                "prompt_lens": [len(r.prompt) for r in wave],
-                                "backend": backend, "rule": reason}
-                            if backend == "dense":
-                                decisions.append(decision)
-                                self._wave_instant(tr, decision)
-                                del waiting[:len(wave)]
-                                seen_groups.update(g for g in groups)
-                                self._run_dense_wave(wave, clock, m, outputs,
-                                                     tr=tr)
-                                progressed = True
-                            else:
-                                # only the paged ADMISSION order is cache-
-                                # reordered (routing and the decision log keep
-                                # arrival order)
-                                wave = self._order_wave(wave)
-                                n_adm, _, ptoks = self._admit_paged(
-                                    wave, book, clock, m, active, free_slots,
-                                    slot_log, prefix_cached, seen_groups,
-                                    outputs, tr=tr, lane=lane, acache=acache,
-                                    spst=spst, hst=hst, gcache=gcache)
-                                prefill_tokens += ptoks
-                                for r in wave[:n_adm]:  # maybe reordered:
-                                    waiting.remove(r)   # remove by identity
-                                progressed = n_adm > 0
-                                if n_adm:
-                                    # a BLOCKED wave (no slots/pages yet)
-                                    # is not a decision — it will re-route
-                                    # once something frees; logging every
-                                    # retry turn would inflate the per-wave
-                                    # statistics the bench reports
-                                    decision["admitted"] = n_adm
-                                    # prompt_lens above is ARRIVAL order;
-                                    # the cache reorder means the first-n
-                                    # slice no longer names the admitted
-                                    # set — the rids do
-                                    decision["admit_rids"] = \
-                                        [r.rid for r in wave[:n_adm]]
-                                    decisions.append(decision)
-                                    self._wave_instant(tr, decision)
-                                elif not active and not lane:
-                                    raise RuntimeError(
-                                        f"pool/slot config too small for "
-                                        f"{wave[0].rid} (free pages "
-                                        f"{len(book._free)}, free slots "
-                                        f"{len(free_slots)})")
-
-                    if active:
-                        self._paged_chunk(book, clock, m, active, free_slots,
-                                          slot_log, outputs, tr=tr,
-                                          acache=acache, spst=spst,
-                                          ahst=ahst, gcache=gcache)
-                        progressed = True
-
-                    if lane:
-                        # the async lane: decode ran FIRST — pending
-                        # prefill gets at most prefill_chunk_budget chunks
-                        # of this turn, so TPOT is independent of how much
-                        # prefill is queued
-                        _, ptoks = self._lane_step(
-                            lane, book, clock, m, active, free_slots,
-                            slot_log, outputs, prefix_cached, seen_groups,
-                            tr=tr, acache=acache, spst=spst,
-                            gcache=gcache)
-                        prefill_tokens += ptoks
-                        progressed = True
-
-                    if not progressed and not active:
-                        targets = []
-                        if pending:
-                            targets.append(pending[0].arrival)
-                        if waiting:
-                            targets.append(waiting[0].arrival
-                                           + self.admission.max_delay)
-                        self._idle_wait(clock, min(targets))
-                    inv_ok, a_inv, g_inv = self._turn_tail(
-                        book, m, clock, tr, qst, acache, gcache,
-                        (inv_ok, a_inv, g_inv))
-        finally:
-            if tr is not None:
-                if prev_tr is not None:
-                    obs_trace.activate(prev_tr)
-                else:
-                    obs_trace.deactivate()
-        cost_stats = self._cost_result(clock, tr, m)
-        self._close_trace(tr, clock)
-        self._stitch_resumes(outputs, hst)
-        return ServeResult(policy=self.policy.name, outputs=outputs,
-                           metrics=m, decisions=decisions,
-                           slot_log=slot_log, prefix_cached=prefix_cached,
-                           pages_total=pages_total,
-                           pages_free_end=(len(book._free)
-                                           + len(book._evictable)),
-                           trace=tr, prefill_tokens=prefill_tokens,
-                           cache_stats=dict(book.cache_stats(),
-                                            invariant_ok=inv_ok),
-                           incidents=self._bank_incidents(mon),
-                           adapter_stats=(
-                               None if acache is None else
-                               dict(acache.cache_stats(),
-                                    invariant_ok=a_inv)),
-                           spec_stats=(None if spst is None
-                                       else spst.stats()),
-                           kv_quant_stats=self._quant_result(book,
-                                                             qst),
-                           overhead=self._overhead_row(clock, run_w0),
-                           hostmem_stats=self._hostmem_result(book,
-                                                              hst),
-                           pages_spilled=(
-                               None if hst is None else
-                               book.cache_stats().get(
-                                   "spilled_pages", 0)),
-                           grammar_stats=(
-                               None if gcache is None else
-                               dict(gcache.cache_stats(),
-                                    invariant_ok=g_inv)),
-                           cost_stats=cost_stats)
+            tr.set_clock(sess.clock.now)  # spans live in the CLOCK's time
+        with obs_trace.use(tr):
+            res = sess.replay(trace)
+        if isinstance(self._trace_spec, str):
+            tr.export(self._trace_spec)
+        return res
 
     def _open_phases(self, clock) -> float:
         """A new host-span store for the run that starts now (kept
@@ -2580,9 +2396,11 @@ class ServingEngine:
         rows, and per call start_s, seam_s, dispatch_s, wait_s},
         ``idle_wait_s`` and ``unaccounted_s`` (the turns' self time),
         which conserve: phases + calls + unaccounted = ``run_wall_s``
-        less what ran outside every turn. ``whole=False`` (a session,
-        which is driven from outside): ``run_wall_s`` is the time
-        under its own turns and waits."""
+        less what ran outside every turn. ``whole``: a replay's
+        ``run_wall_s`` is the wall time since the run opened; a
+        session fed from outside shares that wall with its router and
+        the other replicas, so its ``run_wall_s`` is the time under
+        its own turns and waits (``whole=False``)."""
         counts = None
         if self._call_counts is not None:
             # one entry a program call of the run, in call order;
@@ -2631,297 +2449,6 @@ class ServingEngine:
         self._ledger.publish(obs_metrics.REGISTRY)
         return stats
 
-    def _admission_ready(self, waiting, pending, active, clock) -> bool:
-        if len(waiting) >= self.admission.max_batch:
-            return True
-        # the window-close test MUST round identically to the idle
-        # target `arrival + max_delay` the loop advances to: comparing
-        # `now - arrival >= max_delay` instead livelocks once the
-        # clock is large enough that one ulp exceeds the epsilon
-        # (advance_to(target) lands ON target yet reads as not-ready
-        # — first seen at t ~ 6e4 on the 10^5-request cluster trace)
-        if clock.now() >= waiting[0].arrival \
-                + self.admission.max_delay - 1e-12:
-            return True
-        return not pending and not active  # nothing else will ever come
-
-    # --- the QoS-scheduled replay loop ------------------------------------
-    def _run_scheduled(self, trace: List[Request],
-                       sched) -> ServeResult:
-        """The same arrive->admit->route->prefill->decode lifecycle,
-        with the scheduler owning the waiting set: it orders admission
-        (priority above weighted fair queueing), sheds what cannot meet
-        its deadline (at enqueue under a queue bound, at selection once
-        infeasible), clamps budgets through degradation tiers, and the
-        engine times out RUNNING rows past their deadline through the
-        same eviction path ``cancel_after`` uses."""
-        self._validate(trace)
-        sched.reset()
-        clock = self._make_clock()
-        tr = self._make_tracer(clock)
-        costs = self.fixed_costs or {}
-        est_kw = {}
-        if "prefill_unit" in costs:
-            # per-chunk clock pricing -> per-chunk admission pricing
-            # (the feasibility check then sees exactly what the clock
-            # will charge, cached chunks excluded)
-            est_kw = {"prefill_unit": costs["prefill_unit"],
-                      "chunk_tokens": self.chunk_C}
-        est = ServiceEstimator(prefill=costs.get("prefill", 1.0),
-                               decode=costs.get("decode", 1.0),
-                               **est_kw)
-        mon = self._make_monitor()
-        self._wire_spec_overload(mon, sched)
-        self._wire_pressure(mon, sched)
-        m = MetricsCollector(monitor=mon)
-        book = PagedKVCache(self.n_pool_pages, self.page_size,
-                            kv_heads=1, head_dim=1)
-        self._note_pool(book, m)
-        hst = self._arm_hostmem(book, clock, m, tr)
-        acache = self._make_adapter_cache()
-        gcache = self._make_grammar_cache()
-        spst = self._make_spec_state()
-        qst = self._make_quant_state()
-        ahst = self._make_ahead_state()
-        run_w0 = self._open_phases(clock)
-        pages_total = len(book._free)
-        pending = deque(sorted(trace, key=lambda r: (r.arrival, r.rid)))
-        active: Dict[str, _PagedRow] = {}
-        lane = deque() if self.prefill_chunk_budget is not None \
-            else None
-        free_slots = list(range(self.slots))
-        outputs: Dict[str, List[int]] = {}
-        decisions: List[dict] = []
-        slot_log: List[tuple] = []
-        prefix_cached: Dict[str, int] = {}
-        shed_log: Dict[str, str] = {}
-        seen_groups: set = set()
-        prefill_tokens = 0
-        inv_ok = True
-        a_inv = True
-        g_inv = True
-        expect_churn = self._expect_churn if self._expect_churn \
-            is not None else any(r.cancel_after is not None
-                                 for r in trace)
-        ctx_base = {"capacity": self.slots, "expect_churn": expect_churn}
-
-        def _shed(pairs):
-            for r, reason in pairs:
-                t = clock.now()
-                m.on_shed(r.rid, t, reason)
-                shed_log[r.rid] = reason
-                self._ctr_shed.inc()
-                if acache is not None:
-                    acache.forget_pending(r.rid)
-                if gcache is not None:
-                    gcache.forget_pending(r.rid)
-                if hst is not None and r.rid in hst["preempted"]:
-                    # a preempted request shed while requeued: its
-                    # pinned chain will never page back in — release
-                    # the arena bytes (the partial stream it was
-                    # served survives via _stitch_resumes)
-                    hst["preempted"].discard(r.rid)
-                    book.drop_spilled_owner(r.rid)
-                if tr is not None:
-                    tr.instant("shed", t=t, track="scheduler",
-                               rid=r.rid, reason=reason,
-                               tenant=r.tenant)
-                self._req_close(tr, r, t, "shed", 0, reason=reason)
-            return bool(pairs)
-
-        prev_tr = obs_trace.active()
-        if tr is not None:
-            obs_trace.activate(tr)
-        try:
-            while pending or sched.waiting() or active or lane:
-                with self._phase("turn"):
-                    with self._phase("intake"):
-                        now = clock.now()
-                        while pending and pending[0].arrival <= now + 1e-12:
-                            r = pending.popleft()
-                            m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
-                                         priority=r.priority,
-                                         deadline_ms=r.deadline_ms)
-                            self._ctr_arrived.inc()
-                            self._req_open(tr, r)
-                            _shed(sched.enqueue(r, now))
-                        m.on_queue_depth(now, sched.waiting())
-                        if tr is not None:
-                            tr.counter("queue_depth", sched.waiting(), t=now)
-                    with self._phase("admit"):
-                        progressed = _shed(sched.shed_expired(now))
-
-                        if sched.waiting() and self._sched_ready(
-                                sched, pending, active, clock):
-                            dec = sched.select(
-                                now, max_batch=self.admission.max_batch,
-                                est=est, decode_chunk=self.decode_chunk,
-                                match_prefix=(book.match_prefix
-                                              if self.prefix_cache
-                                              else None),
-                                backlog_cost=(
-                                    self._lane_backlog_cost(lane, est)
-                                    if lane else 0.0))
-                            progressed |= _shed(dec.shed)
-                            # the scheduler's priority/WFQ order is kept
-                            # as-is: its feasibility estimates assumed
-                            # it, and a cache reorder could hand a scarce
-                            # slot to a lower class (cache awareness is
-                            # in the select() pricing)
-                            wave = dec.wave
-                            if wave:
-                                groups = [r.prefix_group for r in wave
-                                          if r.prefix_group is not None]
-                                shared = (len(groups) != len(set(groups))
-                                          or any(g in seen_groups
-                                                 for g in groups))
-                                ctx = dict(ctx_base, shared_prefix=shared,
-                                           active_paged=len(active)
-                                           + (len(lane) if lane else 0))
-                                backend, reason = self.policy.route(wave, ctx)
-                                decision = {
-                                    "t": round(clock.now(), 6),
-                                    "wave": len(wave),
-                                    "prompt_lens": [len(r.prompt)
-                                                    for r in wave],
-                                    "backend": backend, "rule": reason,
-                                    "rids": [r.rid for r in wave]}
-                                if backend == "dense":
-                                    decisions.append(decision)
-                                    self._wave_instant(tr, decision)
-                                    seen_groups.update(g for g in groups)
-                                    self._commit_wave(wave, dec, sched, m,
-                                                      tr=tr, t=clock.now())
-                                    self._run_dense_wave(
-                                        wave, clock, m, outputs,
-                                        timeouts=True, tr=tr)
-                                    progressed = True
-                                else:
-                                    t0 = clock.now()
-                                    n_adm, n_chunks, ptoks = \
-                                        self._admit_paged(
-                                            wave, book, clock, m, active,
-                                            free_slots, slot_log,
-                                            prefix_cached, seen_groups,
-                                            outputs, tr=tr, lane=lane,
-                                            acache=acache, spst=spst,
-                                            hst=hst, gcache=gcache)
-                                    prefill_tokens += ptoks
-                                    if n_adm:
-                                        dt = clock.now() - t0
-                                        est.observe("prefill", dt / n_adm)
-                                        if n_chunks and "prefill_unit" \
-                                                in est.costs:
-                                            est.observe("prefill_unit",
-                                                        dt / n_chunks)
-                                        self._commit_wave(wave[:n_adm], dec,
-                                                          sched, m, tr=tr,
-                                                          t=clock.now())
-                                        decision["admitted"] = n_adm
-                                        decisions.append(decision)
-                                        self._wave_instant(tr, decision)
-                                        progressed = True
-                                    elif hst is not None and active \
-                                            and self._preempt_turn(
-                                                wave[0], book, clock, m,
-                                                active, free_slots, slot_log,
-                                                sched, hst, _shed, tr=tr,
-                                                acache=acache, gcache=gcache):
-                                        # the rung between degrade and shed:
-                                        # a fully blocked wave swaps ONE
-                                        # lower-priority running row out to
-                                        # the arena; the blocked request
-                                        # stays queued and admits next turn
-                                        # into the freed slot/pages
-                                        progressed = True
-                                    elif not active and not lane:
-                                        raise RuntimeError(
-                                            f"pool/slot config too small "
-                                            f"for {wave[0].rid} (free pages "
-                                            f"{len(book._free)}, free slots "
-                                            f"{len(free_slots)})")
-
-                    if active:
-                        t0 = clock.now()
-                        self._paged_chunk(book, clock, m, active, free_slots,
-                                          slot_log, outputs, tr=tr,
-                                          acache=acache, spst=spst,
-                                          ahst=ahst, gcache=gcache)
-                        est.observe("decode", clock.now() - t0)
-                        self._row_timeouts(book, clock, m, active,
-                                           free_slots, slot_log, outputs,
-                                           tr=tr, acache=acache,
-                                           gcache=gcache)
-                        progressed = True
-
-                    if lane:
-                        _, ptoks = self._lane_step(
-                            lane, book, clock, m, active, free_slots,
-                            slot_log, outputs, prefix_cached, seen_groups,
-                            tr=tr, acache=acache, spst=spst,
-                            gcache=gcache)
-                        prefill_tokens += ptoks
-                        self._lane_timeouts(lane, book, clock, m,
-                                            free_slots, slot_log, outputs,
-                                            tr=tr, acache=acache,
-                                            gcache=gcache)
-                        progressed = True
-
-                    if not progressed and not active:
-                        targets = []
-                        if pending:
-                            targets.append(pending[0].arrival)
-                        if sched.waiting():
-                            targets.append(sched.oldest_arrival()
-                                           + self.admission.max_delay)
-                        if not targets:
-                            break  # everything left this turn was shed
-                        self._idle_wait(clock, min(targets))
-                    inv_ok, a_inv, g_inv = self._turn_tail(
-                        book, m, clock, tr, qst, acache, gcache,
-                        (inv_ok, a_inv, g_inv))
-        finally:
-            if tr is not None:
-                if prev_tr is not None:
-                    obs_trace.activate(prev_tr)
-                else:
-                    obs_trace.deactivate()
-        cost_stats = self._cost_result(clock, tr, m)
-        self._close_trace(tr, clock)
-        self._stitch_resumes(outputs, hst)
-        return ServeResult(policy=self.policy.name, outputs=outputs,
-                           metrics=m, decisions=decisions,
-                           slot_log=slot_log,
-                           prefix_cached=prefix_cached,
-                           pages_total=pages_total,
-                           pages_free_end=(len(book._free)
-                                           + len(book._evictable)),
-                           scheduler=sched.name, shed=shed_log,
-                           trace=tr, prefill_tokens=prefill_tokens,
-                           cache_stats=dict(book.cache_stats(),
-                                            invariant_ok=inv_ok),
-                           incidents=self._bank_incidents(mon),
-                           adapter_stats=(
-                               None if acache is None else
-                               dict(acache.cache_stats(),
-                                    invariant_ok=a_inv)),
-                           spec_stats=(None if spst is None
-                                       else spst.stats()),
-                           kv_quant_stats=self._quant_result(book,
-                                                             qst),
-                           overhead=self._overhead_row(clock, run_w0),
-                           hostmem_stats=self._hostmem_result(book,
-                                                              hst),
-                           pages_spilled=(
-                               None if hst is None else
-                               book.cache_stats().get(
-                                   "spilled_pages", 0)),
-                           grammar_stats=(
-                               None if gcache is None else
-                               dict(gcache.cache_stats(),
-                                    invariant_ok=g_inv)),
-                           cost_stats=cost_stats)
-
     def _commit_wave(self, admitted, dec, sched, m, tr=None, t=0.0):
         """Charge the fair-queue tags for what actually ran (the
         degraded budget when a tier fired) and record degradations
@@ -2945,15 +2472,6 @@ class ServingEngine:
                     tr.instant("degrade", t=t, track="scheduler",
                                rid=r.rid, budget=b, orig_budget=b0,
                                tenant=r.tenant)
-
-    def _sched_ready(self, sched, pending, active, clock) -> bool:
-        if sched.waiting() >= self.admission.max_batch:
-            return True
-        # same-rounding rule as _admission_ready (see comment there)
-        if clock.now() >= sched.oldest_arrival() \
-                + self.admission.max_delay - 1e-12:
-            return True
-        return not pending and not active
 
     def _preempt_turn(self, blocked, book, clock, m, active,
                       free_slots, slot_log, sched, hst, shed_fn,
@@ -3588,7 +3106,7 @@ class ServingEngine:
                       slot_log, outputs, tr=None, acache=None,
                       gcache=None):
         """A RUNNING row past its deadline is evicted through the
-        path ``cancel_after`` uses (QoS-scheduled loops only)."""
+        path ``cancel_after`` uses (under a scheduler only)."""
         with self._phase("timeouts"):
             t = clock.now()
             for sid in list(active):
@@ -4124,8 +3642,9 @@ class ServingEngine:
     def session(self, *, tracer=None, replica: Optional[str] = None,
                 expect_churn: bool = False, role: str = "both",
                 slo=None) -> "EngineSession":
-        """An incremental session over this engine's configuration —
-        the cluster router's entry point (see ``EngineSession``).
+        """A session over this engine's configuration that is fed
+        from outside — the cluster router's entry point (see
+        ``EngineSession``; ``run()`` feeds one of its own).
         ``role`` is the disaggregation stage this session serves
         ("prefill" exports finished prefills as KV handoffs, "decode"
         adopts them, "both" is the classic replica). ``slo`` is this
@@ -4133,9 +3652,8 @@ class ServingEngine:
         one per replica over a shared IncidentLog); it observes the
         session's metrics stream and never mutates it. With ``slo``
         unset, an engine constructed with ``ServingEngine(slo=...)``
-        monitors its sessions too — both run paths see the same
-        watchdog config. The engine object itself is untouched;
-        ``run()`` keeps replaying traces byte-identically."""
+        monitors its sessions too — however a session is fed, it
+        sees the same watchdog config."""
         if slo is None:
             slo = self._make_monitor(fresh=False)
         return EngineSession(self, tracer=tracer, replica=replica,
@@ -4153,7 +3671,7 @@ class ServingEngine:
         The wave runs start-to-finish (dense slots cannot admit or
         evict mid-stream); arrivals meanwhile queue.
 
-        ``timeouts`` (the QoS-scheduled loop only): a row whose
+        ``timeouts`` (under a scheduler only): a row whose
         deadline passes mid-wave stops STREAMING at that point — like
         ``cancel_after``, the batch keeps computing but the row takes
         no more tokens and is marked evicted with reason "timeout", so
@@ -4255,12 +3773,16 @@ class ServingEngine:
 
 
 class EngineSession:
-    """One INCREMENTAL engine replay — the seam the cluster layer
-    composes N replicas through.
+    """One engine run: the arrive→admit→route→prefill→decode→finish
+    turn (``_turn``) and the state it works on. There is no other
+    turn; there are two ways to feed it arrivals.
 
-    ``ServingEngine.run()`` replays a whole trace start-to-finish on a
-    private clock; a session is the same arrive→admit→route→prefill→
-    decode→finish lifecycle driven from outside, one event at a time:
+    Fed in one call — ``ServingEngine.run(trace)`` → ``replay(trace)``:
+    the session owns the arrivals to come, takes each in at the turn
+    whose clock has reached it and waits for the next inside the turn.
+
+    Fed from outside, one event at a time — the cluster router, which
+    composes N replicas through sessions:
 
     - ``submit(r)`` feeds one arrival (the router has already advanced
       this replica's clock to the arrival time);
@@ -4273,11 +3795,10 @@ class EngineSession:
       streaming);
     - ``finish()`` runs the backlog dry and builds the ``ServeResult``.
 
-    Both admission disciplines drive through here — FIFO
-    (``scheduler=None``) mirrors ``run()``'s loop body, a
-    ``QoSScheduler`` mirrors ``_run_scheduled``'s (shedding, degrade
-    tiers, cache-aware feasibility pricing, running-row timeouts). The
-    single-engine loops are untouched and replay byte-identically.
+    Both admission disciplines are waves of that turn: FIFO
+    (``scheduler=None``, ``_fifo_wave``) and a ``QoSScheduler``
+    (``_qos_wave``: shedding, degrade tiers, cache-aware feasibility
+    pricing, running-row timeouts).
 
     Each replica needs its OWN engine (and its own serving factory:
     factories share live pool buffers, and two sessions allocating page
@@ -4285,11 +3806,12 @@ class EngineSession:
     other's K/V). Timestamps are always explicit, so one shared cluster
     ``Tracer`` serves N per-replica clocks.
 
-    Per-request metrics, outputs, decisions and slot logs match
-    ``run()`` exactly on the same stream; the one sampled diagnostic
-    that differs is queue-depth cadence (``run()`` also samples on
-    pure arrival-ingestion iterations; a session samples once per
-    turn), so ``queue_depth_mean`` is comparable but not bit-equal.
+    Per-request metrics, outputs, decisions and slot logs are the
+    same on the same stream whichever way it is fed; the one sampled
+    diagnostic that differs is queue-depth cadence (a replay takes a
+    turn to wait for an arrival with nothing queued, and samples in
+    it; a lane fed from outside jumps there without one), so
+    ``queue_depth_mean`` is comparable but not bit-equal.
     """
 
     def __init__(self, engine: ServingEngine, *, tracer=None,
@@ -4319,10 +3841,6 @@ class EngineSession:
         # into the router's census like handoff_stats
         self.handoff_resharded: Dict[str, int] = {}
         self.clock = eng._make_clock(replica or "engine")
-        # this session's host spans: every turn points the engine's
-        # ``_phase`` at them (one session drives an engine at a time)
-        self._w0 = eng._open_phases(self.clock)
-        self.phases = eng._phases
         self.tr = tracer
         self.slo = slo
         self.m = MetricsCollector(monitor=slo)
@@ -4376,6 +3894,11 @@ class EngineSession:
             self.est = ServiceEstimator(
                 prefill=costs.get("prefill", 1.0),
                 decode=costs.get("decode", 1.0), **est_kw)
+        # the arrivals still to come, in (arrival, rid) order, when
+        # the session was handed them up front (``replay``); empty on
+        # a session fed from outside
+        self.pending: deque = deque()
+        self._replayed = False
         self.waiting: List[Request] = []   # FIFO discipline only
         self.active: Dict[str, _PagedRow] = {}
         self.free_slots = list(range(eng.slots))
@@ -4394,7 +3917,7 @@ class EngineSession:
         # grammar-slot census flag, separate for the same reason
         self.g_inv_ok = True
         # True while the router may still submit here; finish() (and a
-        # drain) clears it, enabling run()'s "nothing else will ever
+        # drain) clears it, enabling the "nothing else will ever
         # come" admission clause
         self.more_expected = True
         self._ctx_base = {"capacity": eng.slots,
@@ -4423,6 +3946,12 @@ class EngineSession:
         # re-place.
         self.decode_fault_hook = None
         self.aborted: List[Tuple[Request, List[int]]] = []
+        # this session's host spans, opened last so that the run's
+        # wall time starts where its first turn can: every turn points
+        # the engine's ``_phase`` at them (one session drives an
+        # engine at a time)
+        self._w0 = eng._open_phases(self.clock)
+        self.phases = eng._phases
 
     # --- placement probes --------------------------------------------------
     def queued(self) -> int:
@@ -4482,15 +4011,30 @@ class EngineSession:
 
     # --- arrivals ----------------------------------------------------------
     def submit(self, r: Request):
-        """One arrival (advance this lane to ``r.arrival`` first). On
-        a CRASHED session the request dead-letters instead of entering
-        the scheduler: a dead process cannot run admission policy, so
-        it must never shed (a terminal rejection issued by a corpse
-        would permanently drop a request the failover contract
-        promises to rescue) — the dead letters leave with the queue
-        at ``pull_unadmitted``."""
+        """One arrival from outside (advance this lane to
+        ``r.arrival`` first), validated here: the router's streams
+        have no whole trace to validate up front."""
+        self.eng._validate([r])
+        self._arrive(r)
+
+    def replay(self, trace: List[Request]) -> ServeResult:
+        """Every arrival in one call (``ServingEngine.run``, which has
+        validated the trace): the turns take each in when the clock
+        has reached it, and ``finish`` runs until none is left."""
+        self.pending.extend(sorted(trace,
+                                   key=lambda r: (r.arrival, r.rid)))
+        self._replayed = True
+        return self.finish()
+
+    def _arrive(self, r: Request):
+        """One arrival joins the queue. On a CRASHED session the
+        request dead-letters instead of entering the scheduler: a
+        dead process cannot run admission policy, so it must never
+        shed (a terminal rejection issued by a corpse would
+        permanently drop a request the failover contract promises to
+        rescue) — the dead letters leave with the queue at
+        ``pull_unadmitted``."""
         eng = self.eng
-        eng._validate([r])
         self.m.on_arrival(r.rid, r.arrival, tenant=r.tenant,
                           priority=r.priority,
                           deadline_ms=r.deadline_ms)
@@ -4996,12 +4540,15 @@ class EngineSession:
         return bool(pairs)
 
     def _ready(self) -> bool:
-        """run()'s admission-window test with ``more_expected``
-        standing in for the trace's pending deque. The comparison uses
-        the IDENTICAL float expression ``oldest + max_delay`` as
-        ``_idle_target`` — advance_to(target) must always read as
-        ready on arrival, or one ulp of a large clock livelocks the
-        advance loop (see ``_admission_ready``)."""
+        """The admission window's test: a full batch waits, the oldest
+        waiter's window has closed, or nothing else will ever come
+        (no arrival owned or expected, nothing running). The window
+        test MUST round identically to the idle target ``oldest +
+        max_delay`` that ``_idle_target`` gives the wait: comparing
+        ``now - oldest >= max_delay`` instead livelocks once the clock
+        is large enough that one ulp exceeds the epsilon
+        (advance_to(target) lands ON target yet reads as not-ready —
+        first seen at t ~ 6e4 on the 10^5-request cluster trace)."""
         if self.queued() >= self.eng.admission.max_batch:
             return True
         oldest = self.sched.oldest_arrival() if self.sched is not None \
@@ -5009,14 +4556,18 @@ class EngineSession:
         if self.clock.now() >= oldest \
                 + self.eng.admission.max_delay - 1e-12:
             return True
-        return not self.more_expected and not self.active
+        return not self.more_expected and not self.pending \
+            and not self.active
 
     def _idle_target(self) -> Optional[float]:
-        """When nothing progressed and nothing runs: the time the
-        oldest waiting request's admission window closes, or the next
-        queued handoff's delivery time — whichever is sooner (None
-        with neither: only a new arrival can wake this lane)."""
+        """When nothing progressed and nothing runs: the next owned
+        arrival, the time the oldest waiting request's admission
+        window closes, or the next queued handoff's delivery time —
+        whichever is soonest (None with none of them: only an arrival
+        from outside can wake this lane)."""
         targets = []
+        if self.pending:
+            targets.append(self.pending[0].arrival)
         if self.queued():
             oldest = self.sched.oldest_arrival() \
                 if self.sched is not None else self.waiting[0].arrival
@@ -5031,26 +4582,32 @@ class EngineSession:
             targets.append(min(future))
         return min(targets) if targets else None
 
-    def _wait(self, t: float):
-        """An ``idle_wait`` of this session's own, between its turns."""
-        self.eng._phases = self.phases
-        self.eng._idle_wait(self.clock, t)
+    def _busy(self) -> bool:
+        """Anything left that a turn could work on or wait for."""
+        return bool(self.pending or self.queued() or self.active
+                    or self.lane or self.import_queue)
 
-    def _turn(self) -> bool:
-        """One scheduler turn: admission attempt + decode chunk —
-        run()'s / _run_scheduled's loop body minus arrival ingestion
-        (the router owns arrivals)."""
+    def _turn(self, until: Optional[float] = None) -> bool:
+        """One engine turn, the only one there is: intake, admission
+        attempt, decode chunk, the lane's step, the wait when none of
+        them progressed and nothing runs, the tail. ``until`` is the
+        horizon of whoever drives from outside: the wait ends there
+        at the latest. False when nothing progressed and there is
+        nothing to wait for either."""
         eng = self.eng
         eng._phases = self.phases
         with eng._phase("turn"):
-            return self._turn_body()
+            return self._turn_body(until)
 
-    def _turn_body(self) -> bool:
+    def _turn_body(self, until: Optional[float]) -> bool:
         """``_turn`` under its ``turn`` span."""
         eng = self.eng
         clock, tr, m = self.clock, self.tr, self.m
         with eng._phase("intake"):
             now = clock.now()
+            while self.pending \
+                    and self.pending[0].arrival <= now + 1e-12:
+                self._arrive(self.pending.popleft())
             m.on_queue_depth(now, self.queued())
             # decode-slot utilization (busy slots / capacity), sampled
             # once per turn like queue depth: the live gauge any scrape
@@ -5126,10 +4683,17 @@ class EngineSession:
                                    acache=self.acache,
                                    gcache=self.gcache)
             progressed = True
+        if not progressed and not self.active:
+            target = self._idle_target()
+            if until is not None:
+                target = until if target is None else min(target, until)
+            if target is None:
+                return False
+            eng._idle_wait(clock, target)
         self.inv_ok, self.a_inv_ok, self.g_inv_ok = eng._turn_tail(
             self.book, m, clock, tr, self.qst, self.acache,
             self.gcache, (self.inv_ok, self.a_inv_ok, self.g_inv_ok))
-        return progressed
+        return True
 
     def _route_ctx(self, wave):
         groups = [r.prefix_group for r in wave
@@ -5246,9 +4810,9 @@ class EngineSession:
     def advance_until(self, t: float):
         """Process this lane up to virtual time ``t``. Compute may
         overshoot ``t`` (a decode chunk crossing the horizon models a
-        busy replica — same as the single-engine loop); an idle lane's
-        clock jumps straight to ``t`` so later submissions see honest
-        queueing delays.
+        busy replica — a replay's turns overshoot an arrival the same
+        way); an idle lane's clock jumps straight to ``t`` so later
+        submissions see honest queueing delays.
 
         A CRASHED session advances its clock but processes nothing (a
         dead process has no turns). A STALLED session does the same
@@ -5264,21 +4828,13 @@ class EngineSession:
                 return
             self.clock.advance_to(self.stall_until)
             self.stall_until = None
-        while True:
-            if self.queued() == 0 and not self.active \
-                    and not self.lane and not self.import_queue:
-                self._wait(t)
-                return
+        while self._busy():
             if self.clock.now() >= t - 1e-12:
                 return
-            progressed = self._turn()
-            if not progressed and not self.active and not self.lane:
-                target = self._idle_target()
-                if target is not None and target <= t:
-                    self._wait(target)
-                else:
-                    self._wait(t)
-                    return
+            self._turn(until=t)
+        # an idle lane takes no turn: it jumps (no queue-depth sample)
+        self.eng._phases = self.phases
+        self.eng._idle_wait(self.clock, t)
 
     def finish(self) -> ServeResult:
         """No more arrivals will ever reach this session: run the
@@ -5296,15 +4852,9 @@ class EngineSession:
         # a crashed session has nothing left to run (its rows were
         # torn down at crash; its queue is rescued by the router) —
         # its result banks only the work that finished before death
-        while not self.crashed and (self.queued() or self.active
-                                    or self.lane
-                                    or self.import_queue):
-            progressed = self._turn()
-            if not progressed and not self.active and not self.lane:
-                target = self._idle_target()
-                if target is None:
-                    break  # everything left this turn was shed
-                self._wait(target)
+        while not self.crashed and self._busy():
+            if not self._turn():
+                break  # everything left this turn was shed
         ServingEngine._stitch_resumes(self.outputs, self.hst)
         self.eng._phases = self.phases
         if self.tr is not None and self.clock.mode == "wall":
@@ -5333,7 +4883,7 @@ class EngineSession:
             kv_quant_stats=self.eng._quant_result(self.book,
                                                   self.qst),
             overhead=self.eng._overhead_row(self.clock, self._w0,
-                                            whole=False),
+                                            whole=self._replayed),
             hostmem_stats=self.eng._hostmem_result(self.book,
                                                    self.hst),
             pages_spilled=(

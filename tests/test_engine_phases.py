@@ -1,6 +1,6 @@
 """The engine's host phases and each device call's seam/dispatch/wait
 split (``obs.trace.HostPhases``, ``ServingEngine._phase`` / ``_timed``,
-``ServeResult.overhead``): conservation on all three loops, span
+``ServeResult.overhead``): conservation however the turn is fed, span
 parentage, where planted delays land, fixed-clock identity, the spans in
 a ``jax.profiler`` trace, the ``clock=`` seam and the public per-token
 reads.
@@ -73,7 +73,8 @@ def _accounted(ov):
     ids=["run", "run-wall", "scheduled", "session", "session-qos"])
 def test_overhead_conserves_on_every_loop(srv_model, how, kw):
     """Phases + calls + the turns' own time make up the run's wall
-    time to within 1 % on run(), _run_scheduled() and a session."""
+    time to within 1 %, under either discipline, on a session fed in
+    one call (``run()``) and on one fed from outside."""
     eng = _engine(srv_model, **kw)
     _run(eng, how, _trace())            # compiles every shape
     res = _run(eng, how, _trace())
