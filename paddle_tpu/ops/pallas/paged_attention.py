@@ -8,18 +8,25 @@ pool; each sequence holds a page table of indices, so cache memory
 tracks the sum of real lengths and slots are reused across requests —
 the design that makes continuous batching work.
 
-TPU mapping: the page table rides the scalar-prefetch channel
-(pltpu.PrefetchScalarGridSpec) so the BlockSpec index_map can address
-the NEXT page's (page_size, D) K/V block in HBM while the current one
-computes — Pallas double-buffers the gather; the kernel itself is an
-online-softmax accumulation over the grid's page axis with VMEM scratch
-carrying (m, l, acc) between pages. GQA: all G query heads sharing a kv
-head run in one program, so each page is fetched ONCE per kv head.
+TPU mapping: one program a sequence, and its work is the pages the
+sequence HAS. The page table, the lengths and the layer ride the
+scalar-prefetch channel (pltpu.PrefetchScalarGridSpec); the pools stay
+in HBM as whole operands, and the kernel walks a row's live pages in
+blocks of ``BLOCK_PAGES``: it copies a block's pages itself
+(pltpu.make_async_copy, one strided copy a page carrying every kv head)
+into one of two VMEM buffers, the next block in flight while this one is
+multiplied, and the next sequence's first block started before this
+program ends — an idle slot (length 1 on the reserved page 0) costs one
+page, a row of 5 pages one block, a row that fills its table
+ceil(W / BLOCK_PAGES). (The grid used to carry the table's width as an
+axis: 16 x 8 x 66 steps a layer whatever the rows held.) The arithmetic
+is an online softmax over the blocks with VMEM scratch carrying
+(m, l, acc) per kv head. GQA: all G query heads sharing a kv head run in
+one product, so each page is fetched ONCE.
 
 The pools of a whole model are ONE operand each, (L, Hkv, P, page_size,
-D), addressed in place by (layer, page): the layer index is part of the
-page operand's block index (layer, h, page_tables[b, j], 0, 0) and rides
-the scalar-prefetch channel beside the page table, so it may be a Python
+D), addressed in place by (layer, page): a copy's source is
+``pool.at[layer, :, page_tables[b, j]]``, so the layer may be a Python
 int or a traced scalar (a scanned layer loop's counter) and no layer
 slice of a pool is ever made. A (Hkv, P, page_size, D) pool is the
 one-layer case of the same call.
@@ -33,8 +40,9 @@ API:
     seq_lens    (B,)                  real lengths -> (B, Hq, D)
   paged_prefill_attention(q (B, Hq, C, D), ..., q_start, layer=None)
     the same pools, a C-token query chunk -> (B, Hq, C, D)
-  k_scales / v_scales (int8 pools): the pools' shape less D; the kernel
-    reads the one layer's, sliced by the launcher (``_paged_call``).
+  k_scales / v_scales (int8 pools): the pools' shape less D; the launcher
+    (``_paged_call``) slices the one layer's and pads them to rows of 128
+    lanes, and the kernel copies the rows of the pages it walks.
 """
 from __future__ import annotations
 
@@ -51,78 +59,154 @@ from .flash_attention import NEG_INF
 from .lowering import interpret as _interpret
 
 
-def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_ref, v_ref,
-                  *rest, sm_scale, page_size, chunk, quantized=False):
-    """ONE program per (sequence, kv head, page), shared by decode and
-    chunked prefill: (G*chunk) query rows accumulate online softmax over
-    the page axis with VMEM scratch. Row r sits at absolute position
-    st_ref[b] + (r % chunk); masking is causal over absolute positions
-    AND bounded by seq_len — decode is simply the chunk=1 case with
-    st = seq_len - 1. ``quantized``: int8 K/V refs with two per-slot f32
-    scale refs preceding the output; dequant happens here in VMEM.
-    Pages entirely beyond the causal horizon or the sequence length are
-    skipped (no dot/exp), though their DMA is already pipelined.
-    ``ly_ref`` (the layer) is the block index's business alone."""
+# Pages a walk copies and multiplies as one block. The launcher's own
+# constant, not an option: 8 pages of 64 tokens x 8 kv heads is 1 MB a
+# buffer in bf16 (K and V double-buffered: 4 MB of the 16 MB of VMEM).
+BLOCK_PAGES = 8
+
+
+def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
+                  *rest, sm_scale, page_size, chunk, block_pages,
+                  quantized=False):
+    """ONE program per sequence, shared by decode and chunked prefill:
+    all the device's kv heads, and for each its (G*chunk) query rows,
+    walk the pages the sequence HAS in blocks of ``block_pages``. The
+    pools stay in HBM; a block's pages are copied into one of two VMEM
+    buffers (one strided copy a page carries every head), the next block
+    in flight while this one is multiplied, and the next sequence's
+    first block started before this program ends. Online softmax over
+    the blocks with (m, l, acc) in VMEM scratch. Row r sits at absolute
+    position st_ref[b] + (r % chunk); masking is causal over absolute
+    positions AND bounded by seq_len — decode is simply the chunk=1 case
+    with st = seq_len - 1. ``quantized``: int8 pools whose per-slot f32
+    scales ride the same copies as (page_size,) rows and scale the
+    scores and the probabilities, which is where a row of them fits.
+    The grid is sequential: buffers, semaphores and the buffer's parity
+    (``slot_ref``) pass from one program to the next."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+         slot_ref, m_scr, l_scr, acc_scr) = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, k_buf, v_buf, sems, slot_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    n_seqs = pl.num_programs(0)
+    _, heads, rows, head_dim = q_ref.shape
+    width = pt_ref.shape[1]
+    layer = ly_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def pages_of(b):
+        """Pages sequence ``b``'s rows attend to: at least one, so that
+        every program waits for exactly the block its predecessor
+        started; an empty row reads its table's first entry, masked."""
+        live = jnp.minimum(sl_ref[b], st_ref[b] + chunk)
+        return jnp.clip(pl.cdiv(live, page_size), 1, width)
 
-    seq_len = sl_ref[b]
-    start = st_ref[b]
-    base = j * page_size
-    live = (base <= start + chunk - 1) & (base < seq_len)
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    scales = ((ks_hbm, ks_buf), (vs_hbm, vs_buf)) if quantized else ()
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G*chunk, D)
-        k = k_ref[0, 0].astype(jnp.float32)        # (page_size, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def block_copies(b, j, slot, act):
+        """``act`` (start or wait) on every copy of block ``j`` of
+        sequence ``b`` into buffer ``slot``: a page's K and V (and their
+        scales), every kv head in one strided copy."""
+        left = pages_of(b) - j * block_pages
+
+        def page_copies(i):
+            page = pt_ref[b, j * block_pages + i]
+            for which, (hbm, buf) in enumerate(pools):
+                act(pltpu.make_async_copy(
+                    hbm.at[layer, :, page], buf.at[slot, :, i],
+                    sems.at[which, slot]))
+            for which, (hbm, buf) in enumerate(scales):
+                act(pltpu.make_async_copy(
+                    hbm.at[:, page], buf.at[slot, :, i],
+                    sems.at[which, slot]))
+
+        page_copies(0)                     # a block has at least a page
+        for i in range(1, block_pages):
+            pl.when(i < left)(functools.partial(page_copies, i))
+
+    def start(b, j, slot):
+        block_copies(b, j, slot, lambda c: c.start())
+
+    @pl.when(b == 0)
+    def _first():
+        # pages a block does not copy keep what the buffer held: V (and
+        # the scales) must be finite there, since 0 x NaN is NaN
+        slot_ref[0] = 0
+        v_buf[...] = jnp.zeros_like(v_buf)
         if quantized:
-            kk = k * ks_ref[0, 0]
-            vv = v * vs_ref[0, 0]
-        else:
-            kk, vv = k, v
-        s = jax.lax.dot_general(q, kk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * sm_scale                           # (G*chunk, page_size)
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        row_pos = start + jax.lax.rem(rows, chunk)
-        col_pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            ks_buf[...] = jnp.zeros_like(ks_buf)
+            vs_buf[...] = jnp.zeros_like(vs_buf)
+        start(0, 0, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    seq_len = sl_ref[b]
+    q_start = st_ref[b]
+    n_blocks = pl.cdiv(pages_of(b), block_pages)
+    slot0 = slot_ref[0]
+    shape = (block_pages, rows, page_size)
+    # key position inside a block, and each row's absolute position
+    col_in = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * page_size
+              + jax.lax.broadcasted_iota(jnp.int32, shape, 2))
+    row_pos = q_start + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, shape, 1), chunk)
+
+    def walk(j, _):
+        slot = jax.lax.rem(slot0 + j, 2)
+
+        @pl.when(j + 1 < n_blocks)
+        def _next_block():
+            start(b, j + 1, 1 - slot)
+
+        @pl.when((j + 1 == n_blocks) & (b + 1 < n_seqs))
+        def _next_sequence():
+            start(b + 1, 0, 1 - slot)
+
+        block_copies(b, j, slot, lambda c: c.wait())
+        col_pos = j * (block_pages * page_size) + col_in
         mask = (col_pos <= row_pos) & (col_pos < seq_len)
-        s = jnp.where(mask, s, NEG_INF)
+        for h in range(heads):
+            q = jnp.broadcast_to(q_ref[0, h].astype(jnp.float32)[None],
+                                 (block_pages, rows, head_dim))
+            s = jax.lax.dot_general(
+                q, k_buf[slot, h].astype(jnp.float32),
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            if quantized:
+                s = s * ks_buf[slot, h, :, :page_size][:, None]
+            s = jnp.where(mask, s * sm_scale, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, vv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(jnp.max(s, 2, keepdims=True), 0))
+            p = jnp.exp(s - m_new[None])
+            p = jnp.where(mask, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(
+                jnp.sum(p, 2, keepdims=True), 0)
+            if quantized:
+                p = p * vs_buf[slot, h, :, :page_size][:, None]
+            acc_scr[h] = acc_scr[h] * alpha + jnp.sum(jax.lax.dot_general(
+                p, v_buf[slot, h].astype(jnp.float32),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32), 0)
+            m_scr[h] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-20)).astype(
-            o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, walk, None)
+    slot_ref[0] = jax.lax.rem(slot0 + n_blocks, 2)
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)).astype(
+        o_ref.dtype)
 
 
 def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
                 chunk, sm_scale, k_scales, v_scales, layer):
     """Shared launcher: q4 (B, Hkv, G*chunk, D) -> same shape out. The
     pools are (L, Hkv, P, page_size, D) read at ``layer`` (int or traced
-    scalar); 4-D pools are lifted to the L = 1 pool they are."""
+    scalar); 4-D pools are lifted to the L = 1 pool they are. The pools
+    go in whole and stay in HBM, an int8 pool's scales as the one layer's
+    rows: the kernel copies the pages it walks."""
     quantized = k_scales is not None or v_scales is not None
     if quantized and (k_scales is None or v_scales is None):
         raise ValueError("int8 pools need BOTH k_scales and v_scales")
@@ -135,56 +219,54 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
     elif layer is None:
         raise ValueError("(L, Hkv, P, page_size, D) pools need the layer "
                          "to read")
-    elif quantized:
-        # the kernel wants its scales (page_size, 1), and that trailing
-        # axis is a lane-padded relayout of 128 x the bytes: made of ONE
-        # layer's scales (1 MB -> 134 MB at 8 x 513 x 64), where the
-        # pool's would be pool-sized (2.15 GB temporaries, AOT for v5e)
-        k_scales, v_scales = k_scales[layer], v_scales[layer]
     B, Hkv, rows, D = q4.shape
     _, _, P, page_size, Dk = k_pages.shape
     if D != Dk:
         raise ValueError(f"head_dim mismatch: q {D} vs pages {Dk}")
-    n_pages = page_tables.shape[1]
 
-    q_spec = pl.BlockSpec((1, 1, rows, D), lambda b, h, j, st, pt, sl, ly:
-                          (b, h, 0, 0))
-    # the layer axis is squeezed out of the block: the kernel sees the
-    # (1, 1, page_size, D) page it always saw
-    page_spec = pl.BlockSpec((None, 1, 1, page_size, D),
-                             lambda b, h, j, st, pt, sl, ly:
-                             (ly[0], h, pt[b, j], 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, page_size, 1),
-                              lambda b, h, j, st, pt, sl, ly:
-                              (h, pt[b, j], 0, 0))
-    in_specs = [q_spec, page_spec, page_spec]
+    row_spec = pl.BlockSpec((1, Hkv, rows, D),
+                            lambda b, st, pt, sl, ly: (b, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     args = [q4, k_pages, v_pages]
+    buffers = [pltpu.VMEM((2, Hkv, BLOCK_PAGES, page_size, D), p.dtype)
+               for p in (k_pages, v_pages)]
     if quantized:
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scales[..., None].astype(jnp.float32),
-                 v_scales[..., None].astype(jnp.float32)]
+        # a copy out of HBM takes whole rows of 128 lanes, and a page's
+        # scales are page_size wide: ONE layer's scales are sliced out and
+        # padded to such rows (2 MB at 8 x 513 x 64, where the pool's would
+        # be pool-sized), and the kernel copies the rows of the pages it
+        # walks
+        lanes = -(-page_size // 128) * 128
+        if k_scales.ndim == 4:
+            k_scales, v_scales = k_scales[layer], v_scales[layer]
+        args += [jnp.pad(s.astype(jnp.float32),
+                         ((0, 0), (0, 0), (0, lanes - page_size)))
+                 for s in (k_scales, v_scales)]
+        buffers += [pltpu.VMEM((2, Hkv, BLOCK_PAGES, lanes),
+                               jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, Hkv, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, D),
-                               lambda b, h, j, st, pt, sl, ly:
-                               (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
+        grid=(B,),
+        in_specs=[row_spec] + [in_hbm] * (len(args) - 1),
+        out_specs=row_spec,
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((2, 2)),       # (K | V, buffer)
+            pltpu.SMEM((1,), jnp.int32),           # the buffer's parity
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rows, D), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale,
                           page_size=page_size, chunk=chunk,
-                          quantized=quantized),
+                          block_pages=BLOCK_PAGES, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q4.dtype),
         interpret=_interpret(),
+        name="paged_attention",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
     )(jnp.asarray(starts, jnp.int32).reshape(B),
       jnp.asarray(page_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32),

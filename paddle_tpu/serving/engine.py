@@ -1483,6 +1483,10 @@ class ServingEngine:
         # the host spans of the turn in progress; each EngineSession
         # puts its own here
         self._phases = obs_trace.HostPhases(keep=False)
+        # the run's decode calls: pages their rows hold against the
+        # tables' slots (what a paged kernel walks, and what a grid as
+        # wide as the table would visit)
+        self._paged_walk = [0, 0]
         self.eos_token_id = eos_token_id
         self._expect_churn = expect_churn
         self._dense = dense_parts
@@ -2381,6 +2385,7 @@ class ServingEngine:
         self._phases = obs_trace.HostPhases(keep=clock.mode != "fixed")
         if self._call_counts is not None:
             self._call_counts.reset()    # the run's calls alone
+        self._paged_walk = [0, 0]
         return time.perf_counter()
 
     def _overhead_row(self, clock, run_w0,
@@ -2420,7 +2425,9 @@ class ServingEngine:
         row = dict(acct, run_wall_s=round(run_wall, 6),
                    device_wall_s=round(dev, 6),
                    engine_host_frac=round(max(0.0, frac), 6),
-                   slots=self.slots)
+                   slots=self.slots,
+                   paged_pages_walked=self._paged_walk[0],
+                   paged_table_slots=self._paged_walk[1])
         if counts is not None:
             row["model_counts"] = counts
         return row
@@ -3403,6 +3410,9 @@ class ServingEngine:
             n = 1
         toks, pt, lens, aids, gids = self._decode_batch(
             rows, book, acache, gcache)
+        # every slot rides: an idle one at length 0 on the reserved page
+        self._paged_walk[0] += int((-(-(lens + n) // self.page_size)).sum())
+        self._paged_walk[1] += self.slots * self.W
         served_ahead = (ahst is not None and ahst.emits is not None
                         and ahst.fp == self._roster_fp(rows, book))
         if served_ahead:

@@ -205,6 +205,38 @@ SERVE = dict(slots=16, table=66, page=64, heads=32, kv_heads=8, pool=513,
              layers=8, hidden=4096, inter=14336, vocab=32768)
 
 
+@pytest.mark.parametrize("tp", [1, 4], ids=["one_chip", "tp4"])
+@pytest.mark.parametrize("codec", ["bf16", "int8"])
+def test_paged_walk_compiles_at_the_serve_cells_geometry(v5e, codec, tp):
+    """The decode kernel's walk over the whole pools at a traced layer, at
+    the cells' geometry: on one chip (all 8 kv heads a program) and under
+    the four-chip ``shard_map`` (2 a device). The pools go in whole and
+    stay in HBM (no operand of a pool's size is made beside them); an int8
+    pool's scales are one layer's, sliced and padded to rows of 128 lanes
+    outside the kernel."""
+    from paddle_tpu.models.nlp.llama_decode import paged_kernel_call
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    g = SERVE
+    mesh = Mesh(np.asarray(v5e[:tp]), ("tp",))
+    ns = lambda *names: NamedSharding(mesh, P(*names))  # noqa: E731
+    shape = (g["layers"], g["kv_heads"], g["pool"], g["page"])
+    data = _sds(shape + (HD,), jnp.int8 if codec == "int8" else BF16,
+                ns(None, "tp"))
+    pool = (data, _sds(shape, jnp.float32, ns(None, "tp"))) \
+        if codec == "int8" else data
+    c = _compile(
+        lambda q, kp, vp, pt, sl, i: paged_kernel_call(
+            paged_attention, q, kp, vp, pt, sl, layer=i, mesh=mesh,
+            axis="tp"),
+        _sds((g["slots"], g["heads"], HD), BF16, ns(None, "tp")), pool, pool,
+        _sds((g["slots"], g["table"]), jnp.int32, ns()),
+        _sds((g["slots"],), jnp.int32, ns()), _sds((), jnp.int32, ns()))
+    assert _mosaic_calls(c) == 1
+    assert "%paged_attention" in c.as_text()        # the kernel's own name
+    layer_bytes = int(np.prod(shape[1:])) * HD * data.dtype.itemsize // tp
+    assert c.memory_analysis().temp_size_in_bytes < layer_bytes // 2
+
+
 def test_llama_paged_programs_keep_the_pools_in_place(v5e):
     """``decode_n`` (n = 1) and ``_prefill_chunk`` at the serving cells'
     geometry: both pools are donated and aliased, nothing of a pool's or a

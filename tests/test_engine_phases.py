@@ -93,6 +93,10 @@ def test_overhead_conserves_on_every_loop(srv_model, how, kw):
     assert dec["n"] == len(dec["start_s"]) == len(dec["wait_s"])
     assert dec["n"] <= dec["rows"] <= 4 * dec["n"]
     assert ov["calls"]["prefill"]["rows"] == ov["calls"]["prefill"]["n"]
+    # what the decode kernel walks: every slot at least its one page a
+    # call, never more than its table holds
+    assert ov["paged_table_slots"] == dec["n"] * 4 * eng.W
+    assert 4 * dec["n"] <= ov["paged_pages_walked"] < ov["paged_table_slots"]
     assert all(len(out) == r.max_new_tokens
                for r in _trace() for out in [res.outputs[r.rid]])
     if kw.get("clock") == "wall":

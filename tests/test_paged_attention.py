@@ -339,3 +339,84 @@ def test_layer_index_and_pool_rank_must_agree():
         paged_attention(q, kp, vp, pt, sl, layer=0)
     with pytest.raises(ValueError, match="need the layer"):
         paged_attention(q, kp[None], vp[None], pt, sl)
+
+
+# --- the walk: the pages a row has, a block at a time ------------------------
+
+@pytest.mark.parametrize("hkv", [1, 2], ids=["one_kv_head", "two_kv_heads"])
+@pytest.mark.parametrize("codec", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_walk_visits_the_pages_a_row_has(kind, codec, hkv):
+    """One batch with every shape of row the walk must serve, against the
+    oracle, through the whole pools at a TRACED layer: idle slots (length
+    1 on the reserved page 0, as ``_decode_batch`` leaves them) beside
+    long rows; lengths on a page's edge, one past it, and inside the first
+    page; live pages that are no multiple of the block; a row that fills
+    its whole table (three blocks, the last one short); the kv heads a
+    ``tp`` shard sees. The prefill entry runs the same walk with a taller
+    query block, bounded by ``min(seq_len, start + chunk)``."""
+    import importlib
+    mod = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    rng = np.random.default_rng(31)
+    ps, W, L, D, G, layer = 8, 19, 2, 16, 2, 1
+    B = mod.BLOCK_PAGES
+    assert W % B and W > 2 * B
+    lens = [1, ps, ps + 1, 2 * ps, 1, 3, (B + 3) * ps - 3, B * ps,
+            B * ps + 1, W * ps, 1]
+    n = len(lens)
+    P = 1 + sum(-(-ln // ps) for ln in lens)
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+          "int8": jnp.float32}[codec]
+    kp = jnp.asarray(rng.normal(0, 1, (L, hkv, P, ps, D)), dt)
+    vp = jnp.asarray(rng.normal(0, 1, (L, hkv, P, ps, D)), dt)
+    pt = np.zeros((n, W), np.int32)       # idle rows: page 0 throughout
+    free = list(rng.permutation(np.arange(1, P)))
+    for i, ln in enumerate(lens):
+        if ln > 1:
+            pt[i, :-(-ln // ps)] = [free.pop() for _ in range(-(-ln // ps))]
+    pt, sl = jnp.asarray(pt), jnp.asarray(lens, jnp.int32)
+
+    scales, kf, vf = {}, kp, vp
+    if codec == "int8":
+        (kp, ks), (vp, vs) = _q8_np(kp), _q8_np(vp)
+        scales = dict(k_scales=ks, v_scales=vs)
+        kf = kp.astype(jnp.float32) * ks[..., None]
+        vf = vp.astype(jnp.float32) * vs[..., None]
+    tol = 2e-2 if codec == "bf16" else 2e-5
+
+    if kind == "decode":
+        q = jnp.asarray(rng.normal(0, 1, (n, hkv * G, D)), dt)
+        got = jax.jit(lambda i: mod.paged_attention(
+            q, kp, vp, pt, sl, layer=i, **scales))(jnp.int32(layer))
+        want = paged_attention_reference(q, kf[layer], vf[layer], pt, sl)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        return
+    C, start = ps, B * ps                 # the chunk opens the second block
+    q = jnp.asarray(rng.normal(0, 1, (n, hkv * G, C, D)), dt)
+    got = jax.jit(lambda i: mod.paged_prefill_attention(
+        q, kp, vp, pt, sl, start, layer=i, **scales))(jnp.int32(layer))
+    for c in range(C):
+        want = paged_attention_reference(
+            q[:, :, c], kf[layer], vf[layer], pt,
+            jnp.minimum(sl, start + c + 1))
+        np.testing.assert_allclose(np.asarray(got[:, :, c], np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+def test_an_empty_row_reads_nothing_and_returns_zeros():
+    """Length 0 (no position to attend to): the walk still takes its one
+    block, everything in it masked, and the row comes out 0, not NaN —
+    whatever the buffer held from the row before."""
+    rng = np.random.default_rng(37)
+    q, kp, vp, pt = _setup(rng, B=3, P=10)
+    sl = jnp.asarray([17, 0, 24], jnp.int32)
+    got = np.asarray(paged_attention(q, kp * jnp.inf, vp, pt, sl * 0))
+    assert not got.any()
+    got = np.asarray(paged_attention(q, kp, vp, pt, sl))
+    want = np.asarray(paged_attention_reference(q, kp, vp, pt, sl))
+    assert not got[1].any()
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], rtol=2e-5,
+                               atol=2e-5)
