@@ -308,18 +308,19 @@ def full_forward(cfg, outer, layers, ids):
 
 
 # -- the model object -----------------------------------------------------
-class DeepseekV3ForCausalLM(nn.Layer):
-    """The model object ``ServingEngine`` and the benchmark hold.
+class ShapesUntilLoaded(nn.Layer):
+    """A model object that holds shapes only until ``load_tree``, for
+    ``ServingEngine`` and the benchmark: ``outer`` holds the embedding,
+    the final norm and the head under their ``state_dict`` names;
+    ``layers[i]`` holds layer ``i``'s leaves under their short names.
+    Every leaf is a ``jax.ShapeDtypeStruct`` until ``load_tree`` brings
+    its value, and is then the loaded array itself: no stacking, no copy.
+    A subclass states its ``layer_leaf_shapes(config, i)`` and its
+    ``full_forward(config, outer, layers, ids)``."""
 
-    ``outer`` holds the embedding, the final norm and the head under their
-    ``state_dict`` names; ``layers[i]`` holds layer ``i``'s leaves under
-    their short names.  Every leaf is a ``jax.ShapeDtypeStruct`` until
-    ``load_tree`` brings its value, and is then the loaded array itself:
-    no stacking, no copy."""
+    layer_leaf_shapes = full_forward = None
 
-    kv_layout_ = "latent"        # what ServingEngine's refusals read
-
-    def __init__(self, config: DeepseekV3Config):
+    def __init__(self, config):
         super().__init__()
         self.config = config
         self._forward = None
@@ -330,7 +331,7 @@ class DeepseekV3ForCausalLM(nn.Layer):
                       "model.norm.weight": sds((H,)),
                       "lm_head.weight": sds((H, config.vocab_size))}
         self.layers = [{k: sds(s) for k, s in
-                        layer_leaf_shapes(config, i).items()}
+                        type(self).layer_leaf_shapes(config, i).items()}
                        for i in range(config.num_hidden_layers)]
 
     def _store(self, name: str):
@@ -345,7 +346,15 @@ class DeepseekV3ForCausalLM(nn.Layer):
         return self.layers[int(idx)], key
 
     def leaf_shapes(self) -> dict:
-        return leaf_shapes(self.config)
+        """Every leaf under its ``state_dict`` name -> shape."""
+        shapes = {"model.embed_tokens.weight":
+                  self.outer["model.embed_tokens.weight"].shape}
+        for i, lp in enumerate(self.layers):
+            shapes.update({f"model.layers.{i}.{k}": v.shape
+                           for k, v in lp.items()})
+        shapes.update({k: self.outer[k].shape
+                       for k in ("model.norm.weight", "lm_head.weight")})
+        return shapes
 
     def _leaves(self):
         return [v for store in (self.outer, *self.layers)
@@ -405,8 +414,18 @@ class DeepseekV3ForCausalLM(nn.Layer):
             input_ids = getattr(input_ids, "_value", input_ids)
         ids = jnp.asarray(input_ids, jnp.int32)
         if self._forward is None:
-            self._forward = jax.jit(partial(full_forward, self.config))
+            self._forward = jax.jit(partial(type(self).full_forward,
+                                            self.config))
         return self._forward(outer, layers, ids)
+
+
+class DeepseekV3ForCausalLM(ShapesUntilLoaded):
+    """The model object ``ServingEngine`` and the benchmark hold
+    (``ShapesUntilLoaded``)."""
+
+    kv_layout_ = "latent"        # what ServingEngine's refusals read
+    layer_leaf_shapes = staticmethod(layer_leaf_shapes)
+    full_forward = staticmethod(full_forward)
 
     def serving_decode_factory(self, *, scan_layers=True, **build):
         """What ``ServingEngine`` asks a model for: its paged serving
@@ -428,7 +447,7 @@ class DeepseekV3ForCausalLM(nn.Layer):
 # -- the paged serving factory --------------------------------------------
 class CallCounts:
     """The counts of every device call of a run, kept on the device until
-    asked for: one ``(len(CALL_COUNTS),)`` int32 row a program call, in
+    asked for: one ``(len(names),)`` int32 row a program call, in
     call order, each call's layers and steps summed.  ``counters`` names
     the registry counter each count's sum over a run goes to (the engine
     creates them for a factory that has a ``CallCounts``, and for no
@@ -450,7 +469,10 @@ class CallCounts:
                                "latent cache positions the decode rows "
                                "attended to, summed over steps")}
 
-    def __init__(self):
+    def __init__(self, names=CALL_COUNTS, counters=None):
+        self.names = tuple(names)
+        if counters is not None:    # another model's counts and counters
+            self.counters = counters
         self._rows: list = []       # (kind, device array)
 
     def add(self, kind: str, counts):
@@ -466,10 +488,10 @@ class CallCounts:
         # fetched as they are: stacking them on the device would compile
         # a program for every number of calls
         table = np.asarray(jax.device_get([c for _, c in rows]),
-                           np.int64).reshape(-1, len(CALL_COUNTS))
+                           np.int64).reshape(-1, len(self.names))
         out = {"kind": [k for k, _ in rows]}
         out.update({name: [int(v) for v in table[:, j]]
-                    for j, name in enumerate(CALL_COUNTS)})
+                    for j, name in enumerate(self.names)})
         return out
 
 

@@ -1,12 +1,16 @@
 """A drop-free sparse feed-forward with shared experts, told which
 experts it holds.
 
-Routing is over all ``E = n_routed_experts`` experts whatever is held:
-``s = sigmoid_f32(x . W_g)``, the ``k`` experts with the largest ``s + b``
-are chosen (``b`` the per-expert selection bias, ``topk_method:
-noaux_tc``; one group, so no group limit), and their weights are
-``s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling_factor`` — the bias
-moves the choice, never the weight.  There is no capacity factor and no
+Routing is over all ``E`` experts whatever is held: ``s = sigmoid_f32(x .
+W_g)``, the ``k`` experts with the largest ``s + b`` are chosen (``b`` the
+per-expert selection bias of ``topk_method: noaux_tc``, where the layer
+has that leaf; one group, so no group limit), and their weights are
+``s[chosen] / (sum s[chosen] + 1e-20) * scaling`` — the bias moves the
+choice, never the weight.  The router's settings (``Router``: ``k``,
+``E``, scaling, whether the chosen scores are normalised) come from
+whichever config hands them (``router_of``): a config that has a
+``router`` attribute gives its own, one with ``deepseek_v3``'s keys is
+read by their names.  There is no capacity factor and no
 token is dropped: the ``N x k`` (token, expert) pairs are sorted by
 expert and the three matrix products run as grouped products over the
 sorted rows (``jax.lax.ragged_dot``, which XLA:TPU lowers to a grouped
@@ -24,7 +28,8 @@ held experts add to each token; what every chip computes alike, the
 shared expert, is ``shared_part`` and is added once (``expert_layer``).
 
 Leaves of one layer (``state_dict`` names under ``model.layers.<i>.``):
-``mlp.gate.weight`` (H, E), ``mlp.gate.e_score_correction_bias`` (E,),
+``mlp.gate.weight`` (H, E), ``mlp.gate.e_score_correction_bias`` (E,;
+optional),
 ``mlp.experts.gate_proj`` / ``up_proj`` (held, H, I), ``down_proj``
 (held, I, H), ``mlp.shared_experts.{gate,up}_proj.weight`` (H, S * I),
 ``mlp.shared_experts.down_proj.weight`` (S * I, H).  The expert stacks are
@@ -32,6 +37,8 @@ leaves of their own per layer and are handed to the grouped product
 whole: a slice of an ``(L, E, H, I)`` stack would be copied first.
 """
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -47,25 +54,54 @@ SHARED_KEYS = ("mlp.shared_experts.gate_proj.weight",
 ROUTE_COUNTS = ("pairs", "experts_hit", "max_expert_pairs")
 
 
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """One router's settings, whatever the config calls them."""
+    k: int                      # experts a token
+    n_experts: int              # E: the router's width
+    scaling: float              # on the chosen, normalised scores
+    normalise: bool = True      # chosen scores divided by their sum
+    scoring: str = "sigmoid"
+    groups: tuple = (1, 1)      # (n_group, topk_group)
+
+
+def router_of(cfg) -> Router:
+    """The router's settings from whichever config hands them: its own
+    ``router`` (a ``Router``), else ``deepseek_v3``'s keys."""
+    own = getattr(cfg, "router", None)
+    if own is not None:
+        return own
+    if cfg.topk_method != "noaux_tc":
+        raise NotImplementedError(
+            "the router chooses the k largest sigmoid scores, a per-expert "
+            "selection bias added where the layer has one "
+            f"(topk_method='noaux_tc'); got {cfg.topk_method!r}")
+    return Router(k=cfg.num_experts_per_tok, n_experts=cfg.n_routed_experts,
+                  scaling=cfg.routed_scaling_factor,
+                  normalise=cfg.norm_topk_prob, scoring=cfg.scoring_func,
+                  groups=(cfg.n_group, cfg.topk_group))
+
+
 def route(cfg, router_w, bias, x):
     """x (N, H) -> (weights (N, k) float32, experts (N, k) int32): the
-    router in float32 whatever the activations' type."""
-    if cfg.scoring_func != "sigmoid" or cfg.topk_method != "noaux_tc" \
-            or cfg.n_group != 1 or cfg.topk_group != 1:
+    router in float32 whatever the activations' type.  ``bias`` (E,) moves
+    the choice alone; None where the layer has no such leaf."""
+    r = router_of(cfg)
+    if r.scoring != "sigmoid" or r.groups != (1, 1):
         raise NotImplementedError(
-            "the router computes scoring_func='sigmoid' with "
-            "topk_method='noaux_tc' over one group; got "
-            f"{cfg.scoring_func!r}, {cfg.topk_method!r}, n_group "
-            f"{cfg.n_group}, topk_group {cfg.topk_group}")
+            "the router computes sigmoid scores over one group of experts "
+            "(the k largest, optionally biased, optionally normalised, "
+            f"scaled); got scoring {r.scoring!r}, (n_group, topk_group) "
+            f"{r.groups}")
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                router_w.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :],
-                           cfg.num_experts_per_tok)
+    chosen_by = s if bias is None else s + bias.astype(jnp.float32)[None, :]
+    _, idx = jax.lax.top_k(chosen_by, r.k)
     w = jnp.take_along_axis(s, idx, axis=-1)
-    if cfg.norm_topk_prob:
+    if r.normalise:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return w * cfg.routed_scaling_factor, idx.astype(jnp.int32)
+    return w * r.scaling, idx.astype(jnp.int32)
 
 
 def routed_part(cfg, lp, x, held=None):
@@ -75,10 +111,11 @@ def routed_part(cfg, lp, x, held=None):
     (token, expert) pairs, held experts that received a token, and the
     largest held expert's pairs."""
     N, H = x.shape
-    k, E = cfg.num_experts_per_tok, cfg.n_routed_experts
+    r = router_of(cfg)
+    k, E = r.k, r.n_experts
     n_held = lp[EXPERT_KEYS[0]].shape[0]
     with jax.named_scope("moe.route"):
-        w, idx = route(cfg, lp[ROUTER], lp[ROUTER_BIAS], x)
+        w, idx = route(cfg, lp[ROUTER], lp.get(ROUTER_BIAS), x)
         if held is None:
             if n_held != E:
                 raise ValueError(f"the stacks hold {n_held} of {E} "

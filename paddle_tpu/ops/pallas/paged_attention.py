@@ -67,7 +67,7 @@ BLOCK_PAGES = 8
 
 def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
                   *rest, sm_scale, page_size, chunk, block_pages,
-                  quantized=False):
+                  quantized=False, window=None):
     """ONE program per sequence, shared by decode and chunked prefill:
     all the device's kv heads, and for each its (G*chunk) query rows,
     walk the pages the sequence HAS in blocks of ``block_pages``. The
@@ -82,7 +82,19 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
     scales ride the same copies as (page_size,) rows and scale the
     scores and the probabilities, which is where a row of them fits.
     The grid is sequential: buffers, semaphores and the buffer's parity
-    (``slot_ref``) pass from one program to the next."""
+    (``slot_ref``) pass from one program to the next.
+
+    ``window`` (static; None = causal over everything, and then the
+    traced program is the one it was before the argument existed): a row
+    at position t sees the keys j with t - window < j <= t. The walk then
+    has a LOWER bound too: it starts at the page that holds the first
+    position the program's first row may see, max(0, st - window + 1),
+    masks what lies before each row's own bound, and walks to the row's
+    last page - ``window / page_size + 1`` pages for a decode row
+    whatever its context. The table is indexed by position as without a
+    window (entry j holds positions j * page_size ...): entries behind
+    the window are never read, so the host may leave them dead (0) once
+    it has given those pages back."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
          slot_ref, m_scr, l_scr, acc_scr) = rest
@@ -101,6 +113,11 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
         live = jnp.minimum(sl_ref[b], st_ref[b] + chunk)
         return jnp.clip(pl.cdiv(live, page_size), 1, width)
 
+    def first_page(b):
+        """The table entry a windowed walk of sequence ``b`` starts at."""
+        first = jnp.maximum(st_ref[b] - (window - 1), 0)
+        return jnp.minimum(first // page_size, pages_of(b) - 1)
+
     pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     scales = ((ks_hbm, ks_buf), (vs_hbm, vs_buf)) if quantized else ()
 
@@ -109,9 +126,15 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
         sequence ``b`` into buffer ``slot``: a page's K and V (and their
         scales), every kv head in one strided copy."""
         left = pages_of(b) - j * block_pages
+        if window is not None:
+            first = first_page(b)
+            left = left - first
 
         def page_copies(i):
-            page = pt_ref[b, j * block_pages + i]
+            if window is None:
+                page = pt_ref[b, j * block_pages + i]
+            else:
+                page = pt_ref[b, first + j * block_pages + i]
             for which, (hbm, buf) in enumerate(pools):
                 act(pltpu.make_async_copy(
                     hbm.at[layer, :, page], buf.at[slot, :, i],
@@ -144,7 +167,10 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
     acc_scr[...] = jnp.zeros_like(acc_scr)
     seq_len = sl_ref[b]
     q_start = st_ref[b]
-    n_blocks = pl.cdiv(pages_of(b), block_pages)
+    walked = pages_of(b)
+    if window is not None:
+        walked = walked - first_page(b)
+    n_blocks = pl.cdiv(walked, block_pages)
     slot0 = slot_ref[0]
     shape = (block_pages, rows, page_size)
     # key position inside a block, and each row's absolute position
@@ -166,7 +192,11 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
 
         block_copies(b, j, slot, lambda c: c.wait())
         col_pos = j * (block_pages * page_size) + col_in
+        if window is not None:
+            col_pos = col_pos + first_page(b) * page_size
         mask = (col_pos <= row_pos) & (col_pos < seq_len)
+        if window is not None:
+            mask = mask & (col_pos > row_pos - window)
         for h in range(heads):
             q = jnp.broadcast_to(q_ref[0, h].astype(jnp.float32)[None],
                                  (block_pages, rows, head_dim))
@@ -201,7 +231,7 @@ def _paged_kernel(st_ref, pt_ref, sl_ref, ly_ref, q_ref, k_hbm, v_hbm,
 
 
 def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
-                chunk, sm_scale, k_scales, v_scales, layer):
+                chunk, sm_scale, k_scales, v_scales, layer, window=None):
     """Shared launcher: q4 (B, Hkv, G*chunk, D) -> same shape out. The
     pools are (L, Hkv, P, page_size, D) read at ``layer`` (int or traced
     scalar); 4-D pools are lifted to the L = 1 pool they are. The pools
@@ -260,13 +290,18 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
     return pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale,
                           page_size=page_size, chunk=chunk,
-                          block_pages=BLOCK_PAGES, quantized=quantized),
+                          block_pages=BLOCK_PAGES, quantized=quantized,
+                          **({} if window is None
+                             else {"window": int(window)})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q4.dtype),
         interpret=_interpret(),
         name="paged_attention",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            # a 64-token chunk under 8 query heads a kv head (512 rows)
+            # needs 18.7 MB of VMEM, over Mosaic's scoped default of 16
+            **({"vmem_limit_bytes": 32 << 20} if rows > 384 else {})),
     )(jnp.asarray(starts, jnp.int32).reshape(B),
       jnp.asarray(page_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32),
@@ -275,9 +310,11 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
 
 def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                     sm_scale=None, k_scales=None, v_scales=None,
-                    layer=None):
+                    layer=None, window=None):
     """Decode-step attention over a paged KV pool (shapes in the module
-    docstring). ``k_scales``/``v_scales`` (the pools' shape less D)
+    docstring). ``window`` (static): the row sees its last ``window``
+    positions alone, itself among them, and the walk starts at the page
+    that holds the first of them (``_paged_kernel``). ``k_scales``/``v_scales`` (the pools' shape less D)
     switch the int8-pool path: pages are int8 and dequantized in VMEM
     per block. ``layer`` names the layer of a 5-D pool to read.
     Non-differentiable by design — a serving kernel. Internally the
@@ -293,7 +330,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
     sl = jnp.asarray(seq_lens, jnp.int32)
     out = _paged_call(q.reshape(B, Hkv, G, D), k_pages, v_pages,
                       page_tables, sl, jnp.maximum(sl - 1, 0), 1,
-                      sm_scale, k_scales, v_scales, layer)
+                      sm_scale, k_scales, v_scales, layer, window)
     return out.reshape(B, Hq, D)
 
 
@@ -322,13 +359,167 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, seq_lens,
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 
+def window_ring(window: int, page_size: int, slack: int = 1) -> int:
+    """The most window-kind pages a row holds: the window, the page a
+    decode call of ``slack`` steps may cross into, the partial page at
+    the window's far end."""
+    return -(-(window + slack) // page_size) + 1
+
+
+class _WindowKind:
+    """The book's SECOND kind of page: what a model's sliding-window
+    layers hold of a sequence (``PagedKVCache(window_pages=, window=)``).
+
+    A window layer's row at position t reads the keys j with t - window
+    < j <= t, so the pages wholly behind that are given back WHILE the
+    request runs. A sequence's table is indexed by position like the
+    global kind's (entry j holds positions j * page_size ...; the kernel
+    starts its walk at the first live entry and never reads one behind
+    it), with 0 where a page was given back: a row holds at most ``ring``
+    pages of this kind however long it runs. The kind has its own free
+    list, refcounts and evictable LRU; a page published for prefix
+    sharing is keyed by the GLOBAL page that holds the same tokens (the
+    global chain stays the one chain over token pages) and parks with
+    its key when its last holder lets go, so a later hit finds the
+    window's pages beside the chain's. Page 0 is the reserved padding
+    page, as in the global kind."""
+
+    def __init__(self, n_pages: int, page_size: int, window: int,
+                 slack: int = 1):
+        if window % page_size:
+            raise ValueError(f"window {window} must be a multiple of "
+                             f"page_size {page_size}")
+        self.n_pages = n_pages
+        self.page_size = page_size
+        self.window = window
+        self.ring = window_ring(window, page_size, slack)
+        self.reset()
+        self.released = 0           # pages given back behind the window
+        self.cut = 0                # prefix hits shortened or lost here
+
+    def reset(self):
+        self._free = list(range(self.n_pages - 1, 0, -1))
+        self.tables: dict = {}      # seq -> [page | 0] by position
+        self._refs: dict = {}       # page -> holders
+        self._evictable: dict = {}  # parked page -> True; insertion = LRU
+        self._key: dict = {}        # page -> the global page it is keyed by
+        self._by_key: dict = {}     # global page -> page
+
+    def populations(self):
+        return len(self._refs), len(self._evictable), len(self._free)
+
+    def _take(self) -> int:
+        """One page off the free list, the least recently parked page
+        reclaimed (its key dropped) when the list is dry."""
+        if not self._free:
+            if not self._evictable:
+                raise MemoryError(
+                    "window pages exhausted: every page is held by a "
+                    "running row (the pool is smaller than slots x ring)")
+            p = next(iter(self._evictable))
+            del self._evictable[p]
+            self._by_key.pop(self._key.pop(p), None)
+            self._free.append(p)
+        p = self._free.pop()
+        self._refs[p] = 1
+        return p
+
+    def _let_go(self, p: int):
+        rc = self._refs.get(p, 1) - 1
+        if rc > 0:
+            self._refs[p] = rc
+            return
+        self._refs.pop(p, None)
+        if p in self._key:
+            self._evictable[p] = True       # parked, key live
+        else:
+            self._free.append(p)
+
+    def extend(self, seq_id, n_tokens: int):
+        """Pages for every position < n_tokens that has none yet."""
+        table = self.tables.setdefault(seq_id, [])
+        for _ in range(-(-n_tokens // self.page_size) - len(table)):
+            table.append(self._take())
+        return table
+
+    def release(self, seq_id, next_pos: int) -> int:
+        """Give back ``seq_id``'s pages no position >= ``next_pos`` can
+        see: page j is dead once its last position lies at or before
+        ``next_pos - window``."""
+        table = self.tables.get(seq_id, ())
+        dead = min(max(0, (next_pos - self.window + 1) // self.page_size),
+                   len(table))
+        n = 0
+        for j in range(dead - 1, -1, -1):     # behind them all is 0 already
+            if table[j] == 0:
+                break
+            self._let_go(table[j])
+            table[j] = 0
+            n += 1
+        self.released += n
+        return n
+
+    def free(self, seq_id):
+        for p in self.tables.pop(seq_id, ()):
+            if p:
+                self._let_go(p)
+
+    def publish(self, seq_id, index: int, global_page: int):
+        """Key ``seq_id``'s page at ``index`` by the global page of the
+        same tokens, unless that key or this page is published."""
+        table = self.tables.get(seq_id, ())
+        p = table[index] if index < len(table) else 0
+        if p and p not in self._key and global_page not in self._by_key:
+            self._key[p] = global_page
+            self._by_key[global_page] = p
+
+    def unkey(self, global_page: int):
+        """The global page lost its identity: the page keyed by it can
+        serve no prefix again (parked: freed; held: freed when let go)."""
+        p = self._by_key.pop(global_page, None)
+        if p is None:
+            return
+        self._key.pop(p, None)
+        if p in self._evictable:
+            del self._evictable[p]
+            self._free.append(p)
+
+    def covered(self, chain, n: int) -> bool:
+        """Are the pages that positions >= n * page_size may see of the
+        first n chain pages all held or parked?"""
+        back = self.window // self.page_size
+        return all(g in self._by_key for g in chain[max(0, n - back):n])
+
+    def share(self, seq_id, chain, n: int):
+        """Take the covering pages of an ``n``-page hit into ``seq_id``'s
+        table (parked ones revived), dead entries before them."""
+        back = self.window // self.page_size
+        table = self.tables.setdefault(seq_id, [])
+        for j in range(n):
+            p = self._by_key[chain[j]] if j >= n - back else 0
+            if p:
+                self._evictable.pop(p, None)
+                self._refs[p] = self._refs.get(p, 0) + 1
+            table.append(p)
+
+
 class PagedKVCache:
     """Host-side page-pool bookkeeping for serving loops: a free list of
     pages plus per-sequence tables (~ vLLM's BlockManager). Device data
     stays functional — ``write`` returns the updated pools."""
 
     def __init__(self, n_pages: int, page_size: int, kv_heads: int,
-                 head_dim: int, dtype=jnp.bfloat16):
+                 head_dim: int, dtype=jnp.bfloat16,
+                 window_pages: int | None = None,
+                 window: int | None = None, window_slack: int = 1):
+        # a second kind of page (``_WindowKind``) for a model whose
+        # sliding-window layers keep a pool of their own; None: every
+        # statement below runs as it did before the kind existed
+        self._win = None if window_pages is None else _WindowKind(
+            window_pages, page_size, window, window_slack)
+        self._pub: dict = {}        # seq -> (pages published, last page)
+        self._cut_of: dict = {}     # seq -> its hit was cut (for rollback)
+        self._kind_bytes: dict | None = None
         self.page_size = page_size
         self.k_pages = jnp.zeros((kv_heads, n_pages, page_size, head_dim),
                                  dtype)
@@ -772,6 +963,8 @@ class PagedKVCache:
         the wrong-context-KV hazard — every key chained THROUGH it
         (a future sequence must never match stale children under the
         recycled id and share unrelated K/V)."""
+        if self._win is not None:
+            self._win.unkey(p)
         key = self._page_key.pop(p, None)
         if key is not None:
             self._prefix.pop(key, None)
@@ -785,6 +978,8 @@ class PagedKVCache:
             if page_c is not None \
                     and self._page_key.get(page_c) == ck:
                 self._page_key.pop(page_c, None)
+                if self._win is not None:
+                    self._win.unkey(page_c)
 
     def acquire_prefix(self, seq_id, tokens) -> int:
         """Match ``tokens`` against cached FULL prompt pages; matched
@@ -801,7 +996,14 @@ class PagedKVCache:
                 "free() it first (e.g. after a failed allocate)")
         table = self.tables.setdefault(seq_id, [])
         n = 0
-        for page in self._chain(tokens):
+        pages = self._chain(tokens)
+        if self._win is not None:
+            chain, hit, cap = self._two_kind_hit(tokens)
+            pages = chain[:hit]
+            if hit < cap:
+                self._win.cut += 1
+                self._cut_of[seq_id] = True
+        for page in pages:
             if page in self._evictable:
                 del self._evictable[page]  # revival: LRU -> resident
             self._refs[page] = self._refs.get(page, 0) + 1
@@ -812,6 +1014,9 @@ class PagedKVCache:
             (len(tokens) // self.page_size) * self.page_size
         # write()/decode append after the cached prefix, never inside it
         self.lengths[seq_id] = n
+        if self._win is not None:
+            self._win.share(seq_id, chain, hit)
+            self._pub[seq_id] = (hit, pages[-1] if pages else 0)
         return n
 
     def rollback_acquire(self, seq_id, tokens):
@@ -824,6 +1029,8 @@ class PagedKVCache:
         while the table still holds ONLY acquired pages (allocate
         failed without mutating)."""
         n_cached = len(self.tables.get(seq_id, ())) * self.page_size
+        if self._win is not None and self._cut_of.pop(seq_id, False):
+            self._win.cut -= 1      # the retry will count it again
         self.free(seq_id)
         self._stats["hit_tokens"] -= n_cached
         self._stats["lookup_tokens"] -= \
@@ -851,11 +1058,99 @@ class PagedKVCache:
         the cache could serve right now (a page multiple). No refcount,
         LRU, or stats mutation — safe for a scheduler to call per
         admission turn to price prefill work before committing."""
+        if self._win is not None:
+            return self._two_kind_hit(tokens)[1] * self.page_size
         return sum(self.page_size for _ in self._chain(tokens))
+
+    def _two_kind_hit(self, tokens):
+        """-> (the matched global chain, pages a two-kind book can serve
+        of it, pages it could were no window page gone): the longest hit
+        whose window-kind pages — those covering the ``window`` positions
+        before its end — are all still held or parked. A hit never takes
+        the whole prompt (the final chunk always runs, and re-running it
+        inside the hit would need one page more behind the window), and
+        never resumes with window pages missing: a window layer's keys
+        cannot be rebuilt without re-running every layer under it."""
+        chain = list(self._chain(tokens))
+        cap = min(len(chain), (len(tokens) - 1) // self.page_size)
+        hit = cap
+        while hit > 0 and not self._win.covered(chain, hit):
+            hit -= 1
+        return chain, hit, cap
+
+    # --- the window kind (two-kind books only) --------------------------
+
+    def window_table(self, seq_id):
+        """``seq_id``'s window-kind pages by position, 0 where dead."""
+        return self._win.tables.get(seq_id, ())
+
+    def window_extend(self, seq_id, n_tokens: int):
+        """Window-kind pages for every position < ``n_tokens``: called
+        before the chunk or decode call that writes them. It cannot fail
+        on a pool of at least ``slots x ring`` pages (the engine's
+        floor): a row holds at most ``ring``, the rest is free or
+        parked."""
+        return self._win.extend(seq_id, n_tokens)
+
+    def window_release(self, seq_id, next_pos: int) -> int:
+        """Give back the window-kind pages wholly behind ``next_pos -
+        window`` (``next_pos``: the lowest position still to attend);
+        a published one parks with its key. Returns pages given back."""
+        return self._win.release(seq_id, next_pos)
+
+    def publish_upto(self, seq_id, tokens, n_tokens: int):
+        """Two kinds: publish ``seq_id``'s full prompt pages below
+        ``n_tokens`` (they hold real K/V), both kinds, continuing where
+        the last call stopped. A window page has to be published BEFORE
+        it is given back, so the engine calls this after every chunk
+        where a one-kind book registers once, at the prompt's end."""
+        table = self.tables.get(seq_id, [])
+        i, parent = self._pub.get(seq_id, (0, 0))
+        ps = self.page_size
+        while i is not None and (i + 1) * ps <= min(n_tokens, len(tokens)):
+            if parent and parent not in self._page_key:
+                # the chain this sequence followed was evicted under it
+                # (its own next page was not yet a live child): nothing
+                # further of it can be published
+                i = None
+                break
+            key = (parent, tuple(int(t) for t in tokens[i * ps:(i + 1)
+                                                        * ps]))
+            if self._prefix.get(key) is None:
+                page = table[i]
+                self._prefix[key] = page
+                self._page_key[page] = key
+                self._children.setdefault(parent, set()).add(key)
+            parent = self._prefix[key]
+            self._win.publish(seq_id, i, parent)
+            i += 1
+        self._pub[seq_id] = (i, parent)
+
+    def note_kind_bytes(self, by_kind: dict):
+        """A page's bytes by kind (``{"global": b, "window": b}``: each
+        over the layers of its kind), so that what a request will hold
+        can be priced: ``footprint_bytes``."""
+        self._kind_bytes = {k: int(v) for k, v in by_kind.items()}
+
+    def footprint_bytes(self, n_tokens: int) -> int | None:
+        """Bytes a request of ``n_tokens`` will hold at its fullest:
+        every page in the global kind, at most the ring in the window
+        kind."""
+        if self._kind_bytes is None:
+            return None
+        pages = -(-n_tokens // self.page_size)
+        return pages * self._kind_bytes["global"] \
+            + min(pages, self._win.ring) * self._kind_bytes["window"]
+
+    def populations_by_kind(self) -> dict:
+        return {"global": self.populations(),
+                "window": self._win.populations()}
 
     def register_prefix(self, seq_id, tokens):
         """Publish seq_id's FULL prompt pages (now holding real K/V) for
         sharing. Call after the prompt's prefill wrote its pages."""
+        if self._win is not None:
+            return self.publish_upto(seq_id, tokens, len(tokens))
         table = self.tables.get(seq_id, [])
         parent = 0
         ps = self.page_size
@@ -901,6 +1196,10 @@ class PagedKVCache:
         return self.k_pages, self.v_pages
 
     def free(self, seq_id):
+        if self._win is not None:
+            self._win.free(seq_id)
+            self._pub.pop(seq_id, None)
+            self._cut_of.pop(seq_id, None)
         for p in self.tables.pop(seq_id, []):
             rc = self._refs.get(p, 1) - 1
             if rc <= 0:
@@ -943,6 +1242,10 @@ class PagedKVCache:
         self._quant.clear()  # both tiers go: pre-purge int8 content is
         # as untrusted as the full-precision pages
         self._free = list(range(n_pages - 1, 0, -1))
+        if self._win is not None:
+            self._win.reset()
+            self._pub.clear()
+            self._cut_of.clear()
         if self._arena is not None:
             # the host tier dies with the pool: pre-purge spilled
             # content is exactly as untrusted as pre-purge device
@@ -1011,6 +1314,9 @@ class PagedKVCache:
                 return False
             if any(k not in self._arena for k in self._spilled):
                 return False
+        if self._win is not None:
+            balanced = balanced and obs_ledger.census_balanced(
+                self._win.n_pages - 1, *self._win.populations())
         return balanced and tier_ok
 
     def cache_stats(self) -> dict:
@@ -1031,6 +1337,19 @@ class PagedKVCache:
             "hit_rate": round(hit / lookup, 4) if lookup else 0.0,
             "evictions": self._stats["evictions"],
         }
+        if self._win is not None:
+            # populations by kind ONLY when there is more than one: every
+            # one-kind run's dict stays byte-identical
+            out["kinds"] = {
+                kind: dict(zip(("resident_pages", "evictable_pages",
+                                "free_pages"), pops))
+                for kind, pops in self.populations_by_kind().items()}
+            out["kinds"]["window"]["n_pages"] = self._win.n_pages - 1
+            out["kinds"]["global"]["n_pages"] = out["n_pages"]
+            out["window_pages_released"] = self._win.released
+            out["prefix_hits_cut_by_window"] = self._win.cut
+            if self._kind_bytes is not None:
+                out["page_bytes"] = dict(self._kind_bytes)
         if self._pool_bytes is not None:
             # only when noted (a sharded serving pool): unsharded runs
             # keep the pre-TP dict byte-for-byte
@@ -1076,7 +1395,7 @@ class PagedKVCache:
 
 def paged_prefill_attention(q, k_pages, v_pages, page_tables, seq_lens,
                             q_start, sm_scale=None, k_scales=None,
-                            v_scales=None, layer=None):
+                            v_scales=None, layer=None, window=None):
     """Causal attention of a C-token query chunk against the paged pool
     (the chunk's own K/V must already be written to its pages).
 
@@ -1084,7 +1403,9 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, seq_lens,
     absolute position of the chunk's first token (shared across the
     left-aligned batch). Returns (B, Hq, C, D). The chunk=C case of the
     shared paged kernel; pages entirely beyond start+C or the sequence
-    length are skipped.
+    length are skipped, and with a ``window`` (static) those wholly
+    before ``q_start - window + 1`` too: each query row is bounded by
+    its own ``t - window < j <= t``.
     """
     B, Hq, C, D = q.shape
     Hkv = k_pages.shape[-4]
@@ -1097,5 +1418,6 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, seq_lens,
     starts = jnp.full((B,), q_start, jnp.int32)
     out = _paged_call(q.reshape(B, Hkv, G * C, D), k_pages, v_pages,
                       page_tables, jnp.asarray(seq_lens, jnp.int32),
-                      starts, C, sm_scale, k_scales, v_scales, layer)
+                      starts, C, sm_scale, k_scales, v_scales, layer,
+                      window)
     return out.reshape(B, Hq, C, D)

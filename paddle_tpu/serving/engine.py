@@ -69,7 +69,7 @@ from ..models.nlp.llama_decode import (as_grammar_config,
                                        repage_kv_data, route_decode,
                                        transcode_kv_data,
                                        tree_device_bytes)
-from ..ops.pallas.paged_attention import PagedKVCache
+from ..ops.pallas.paged_attention import PagedKVCache, window_ring
 from .adapters import AdapterCache, AdapterStore
 from .grammar import GrammarCache, GrammarStore, TokenVocab
 from .hostmem import HostArena, as_hostmem_config
@@ -323,6 +323,19 @@ _LATENT_REFUSES = (
     "(its programs take no such argument and count their calls) — got ")
 
 
+_WINDOWED_REFUSES = (
+    "a two-kind (global + sliding-window) cache keeps two pools and two "
+    "page tables a sequence, and gives window pages back while a request "
+    "runs: it does not compose yet with tp (one pool spec, one table), "
+    "kv_quant / kv_cache_dtype (one codec over one pool), hostmem and KV "
+    "handoff (export / import / reshard / repage / transcode move one "
+    "chain of one kind), lora / adapters, spec (the draft pool rides the "
+    "global page ids), grammar or dispatch_ahead (its programs take no "
+    "such argument, and a stashed batch would outlive the give-back), "
+    "ragged_prefill, or prefill outside the lane (prefill_chunk_budget="
+    "None: a whole prompt's window pages at once) — got ")
+
+
 def _kv_layout(obj) -> str:
     """The cache layout a model or a prebuilt factory states."""
     return getattr(obj, "kv_layout_", "head_major")
@@ -331,9 +344,18 @@ def _kv_layout(obj) -> str:
 def _refuse_latent(**used):
     """The one refusal of everything a latent cache does not compose
     with: raises naming the options in use, returns when none is."""
+    _refuse(_LATENT_REFUSES, **used)
+
+
+def _refuse(message: str, **used):
     named = sorted(k for k, v in used.items() if v is not None)
     if named:
-        raise ValueError(_LATENT_REFUSES + ", ".join(named))
+        raise ValueError(message + ", ".join(named))
+
+
+def _refuse_windowed(**used):
+    """``_refuse_latent``'s twin for a two-kind cache."""
+    _refuse(_WINDOWED_REFUSES, **used)
 
 
 def _coerce_paged_only(policy, what: str, why: str):
@@ -828,7 +850,8 @@ class ServingEngine:
                  kv_quant_budget=None, ragged_prefill: bool = False,
                  dispatch_ahead: bool = False, hostmem=None,
                  grammar=None, grammar_config=None,
-                 adapter_schemas=None, ledger=None):
+                 adapter_schemas=None, ledger=None,
+                 n_window_pages: Optional[int] = None):
         # ``tp``: None (byte-identical to the single-device engine —
         # outputs, slot logs, metrics records, registry contents), a
         # TPConfig, or an int degree. With a MODEL it is threaded into
@@ -916,6 +939,27 @@ class ServingEngine:
             policy = _coerce_paged_only(
                 policy, "with a latent cache",
                 "the dense wave cache stores per-head K and V")
+        # A TWO-KIND cache (``kv_layout_ = "windowed"``: global layers'
+        # pages grow with the sequence, sliding-window layers' pages are
+        # given back behind the window): the same pattern, once
+        windowed = _kv_layout(serving if serving is not None
+                              else model) == "windowed"
+        if windowed:
+            _refuse_windowed(
+                tp=tp, lora=lora, adapters=adapters, spec=spec,
+                spec_draft=spec_draft, kv_quant=kv_quant,
+                kv_cache_dtype=kv_cache_dtype, hostmem=hostmem,
+                grammar=grammar, grammar_config=grammar_config,
+                dispatch_ahead=dispatch_ahead or None,
+                ragged_prefill=ragged_prefill or None,
+                prefill_outside_the_lane=True
+                if prefill_chunk_budget is None else None)
+            policy = _coerce_paged_only(
+                policy, "with a two-kind cache",
+                "the dense wave cache has no page kinds")
+        elif n_window_pages is not None:
+            raise ValueError("n_window_pages= sizes a two-kind cache's "
+                             "window pool; this model has one kind")
         if serving is None:
             if model is None:
                 raise ValueError("pass a model or a prebuilt serving "
@@ -958,7 +1002,10 @@ class ServingEngine:
                 batch_capacity=slots, scan_layers=scan_layers,
                 chunked_prefill=page_size, tp=tp, lora=lora,
                 draft=spec_draft, kv_quant=kv_quant,
-                grammar=grammar_config)
+                grammar=grammar_config,
+                # the window pool's size goes to a two-kind model alone
+                **({"n_window_pages": n_window_pages,
+                    "window_slack": decode_chunk} if windowed else {}))
         else:
             if spec_draft is not None:
                 raise ValueError(
@@ -1221,6 +1268,18 @@ class ServingEngine:
         self.page_size = page_size
         self.n_pool_pages = n_pool_pages
         self.W = max_len // page_size  # fixed page-table width
+        # a two-kind cache: the window pool's geometry, told by the
+        # factory; a row's tables ride one (., 2 W) operand, the global
+        # kind's entries then the window kind's. None: one kind, and
+        # every statement below runs as it did
+        self.window = getattr(serving, "window_", None) \
+            if windowed else None
+        self.n_window_pages = getattr(serving, "n_window_pages_", None) \
+            if windowed else None
+        self._table_cols = self.W * (2 if windowed else 1)
+        if windowed and serving.chunked_prefill_ != page_size:
+            raise ValueError("a two-kind cache prefills a page a chunk "
+                             "(a hit resumes on a page's edge)")
         self.chunk_C = serving.chunked_prefill_
         # ``clock``: "measured" | "fixed" (virtual time), "wall"
         # (real time: what a live server runs on), or an EngineClock
@@ -1457,6 +1516,43 @@ class ServingEngine:
         # spec=None keeps the legacy arithmetic bit-for-bit.
         self._slack = decode_chunk if spec is None \
             else max(decode_chunk, spec.n_draft + 1)
+        self._ctr_window = None
+        if self.window is not None:
+            # the window pool's floor: a row holds at most ``ring``
+            # pages of the kind, so slots x ring (+ the padding page)
+            # can never run dry mid-request, whatever is parked
+            ring = window_ring(self.window, page_size, self._slack)
+            if self.n_window_pages - 1 < slots * ring:
+                raise ValueError(
+                    f"n_window_pages {self.n_window_pages} is under "
+                    f"slots x ring + 1 = {slots * ring + 1}: a running "
+                    "row's window pages could not be had")
+            # created ONLY for a two-kind model (every other run's
+            # registry is unchanged)
+            _wc = obs_metrics.REGISTRY.counter
+            self._ctr_window = {
+                "window_pages_released": _wc(
+                    "serving_window_pages_released_total",
+                    "window-kind pages given back behind the window "
+                    "while their request ran"),
+                "prefix_hits_cut_by_window": _wc(
+                    "serving_prefix_hits_cut_by_window_total",
+                    "prefix hits shortened or lost because window-kind "
+                    "pages were gone"),
+                "kv_pages_held_global": _wc(
+                    "serving_kv_pages_held_global_total",
+                    "global-kind pages held by running rows, summed "
+                    "over turns"),
+                "kv_pages_held_window": _wc(
+                    "serving_kv_pages_held_window_total",
+                    "window-kind pages held by running rows, summed "
+                    "over turns"),
+                "kv_pages_if_all_global": _wc(
+                    "serving_kv_pages_if_all_global_total",
+                    "pages a layer the same rows would hold were "
+                    "every layer global, summed over turns")}
+        # the run's page census by kind, sampled a turn (two kinds only)
+        self._kv_held = [0, 0, 0]        # turns, global, window
         self.clock_mode = clock
         self.fixed_costs = fixed_costs
         # ``ledger``: None (byte-identical — the tr-is-None
@@ -1545,7 +1641,7 @@ class ServingEngine:
         # a latent pool is noted too: its page is not K and V of the
         # head width, so the book is told its bytes (tp / kv_quant are
         # None there: the refusals above)
-        if tp is not None or kv_quant is not None or latent:
+        if tp is not None or kv_quant is not None or latent or windowed:
             # a quantizing factory prices its own pool (the sim's
             # token pools model the int8 layout arithmetically; the
             # real factory's leaves ARE the small arrays)
@@ -1587,6 +1683,10 @@ class ServingEngine:
         if self._pool_bytes is None:
             return
         book.note_pool_bytes(*self._pool_bytes)
+        if self.window is not None:
+            # a page's bytes by kind: what a request will hold is priced
+            # in the window kind by its ring, not its length
+            book.note_kind_bytes(self.serving.page_bytes_)
         if self.kv_quant == "pressure":
             sb = book.stored_bytes()
             if sb is not None:
@@ -2258,6 +2358,11 @@ class ServingEngine:
             self._quant_turn(book, m, clock, tr, qst)
             inv_ok, a_inv, g_inv = census
             inv_ok &= book.census_ok()
+            if self.window is not None:
+                pops = book.populations_by_kind()
+                self._kv_held[0] += 1
+                self._kv_held[1] += pops["global"][0]
+                self._kv_held[2] += pops["window"][0]
             if acache is not None:
                 a_inv &= acache.census_ok()
             if gcache is not None:
@@ -2270,6 +2375,24 @@ class ServingEngine:
         return inv_ok, a_inv, g_inv
 
     # --- helpers ----------------------------------------------------------
+    def _fill_tables(self, pt_row, book, sid):
+        """One row of the page-table operand: the sequence's pages by
+        position; with two kinds the window kind's follow at column W
+        (0 where a page was given back: the kernel never reads there)."""
+        table = book.tables[sid]
+        pt_row[:len(table)] = table
+        if self.window is not None:
+            wt = book.window_table(sid)
+            pt_row[self.W:self.W + len(wt)] = wt
+
+    def _window_give_back(self, book, sid, next_pos: int):
+        """After a chunk or a decode call of a two-kind cache: the
+        row's window-kind pages wholly behind ``next_pos - window`` go
+        back (a published one parks with its key). A span of its own
+        under ``turn``."""
+        with self._phase("window.release", sid):
+            book.window_release(sid, next_pos)
+
     def _pad_len(self, n: int) -> int:
         # pad prompts to the CHUNK multiple (a page multiple by factory
         # contract): prefill_chunked rejects prompts that are not — a
@@ -2386,10 +2509,11 @@ class ServingEngine:
         if self._call_counts is not None:
             self._call_counts.reset()    # the run's calls alone
         self._paged_walk = [0, 0]
+        self._kv_held = [0, 0, 0]
         return time.perf_counter()
 
-    def _overhead_row(self, clock, run_w0,
-                      whole: bool = True) -> Optional[Dict]:
+    def _overhead_row(self, clock, run_w0, whole: bool = True,
+                      book=None) -> Optional[Dict]:
         """The run's wall-clock accounting (measured and wall clocks;
         None on fixed clocks — their results stay byte-identical).
         ``engine_host_frac`` is the fraction of the run's wall time
@@ -2413,6 +2537,28 @@ class ServingEngine:
             counts = self._call_counts.take()
             for name, ctr in self._ctr_model.items():
                 ctr.inc(sum(counts[name]))
+        kinds = None
+        if self.window is not None and book is not None:
+            # a two-kind cache's own accounting (absent for every other
+            # model): pages held by kind summed over the turns sampled,
+            # what the same rows would hold a layer were every layer
+            # global (the global kind's count: each such page would then
+            # stand in the window layers too), and the give-back's work
+            turns, held_g, held_w = self._kv_held
+            sums = {"kv_pages_held_global": held_g,
+                    "kv_pages_held_window": held_w,
+                    "kv_pages_if_all_global": held_g,
+                    "window_pages_released": book._win.released,
+                    "prefix_hits_cut_by_window": book._win.cut}
+            for key, n in sums.items():
+                self._ctr_window[key].inc(n)
+            kinds = {
+                "kv_pages_held": {"global": held_g, "window": held_w,
+                                  "turns": turns},
+                "kv_page_bytes": dict(self.serving.page_bytes_),
+                **{k: sums[k] for k in ("kv_pages_if_all_global",
+                                        "window_pages_released",
+                                        "prefix_hits_cut_by_window")}}
         if clock.mode == "fixed":
             return None
         run_wall = time.perf_counter() - run_w0    # before the summing
@@ -2430,6 +2576,8 @@ class ServingEngine:
                    paged_table_slots=self._paged_walk[1])
         if counts is not None:
             row["model_counts"] = counts
+        if kinds is not None:
+            row.update(kinds)
         return row
 
     def _cost_result(self, clock, tr=None, m=None) -> Optional[Dict]:
@@ -2689,9 +2837,8 @@ class ServingEngine:
                 T = self._pad_len(len(r.prompt))
                 toks = np.zeros((1, T), np.int32)
                 toks[0, :len(r.prompt)] = r.prompt
-                pt = np.zeros((1, self.W), np.int32)
-                table = book.tables[sid]
-                pt[0, :len(table)] = table
+                pt = np.zeros((1, self._table_cols), np.int32)
+                self._fill_tables(pt[0], book, sid)
                 lens = np.asarray([len(r.prompt)], np.int32)
                 resume = (n_cached // self.chunk_C) * self.chunk_C
                 # the factory clamps resume so the FINAL chunk always runs
@@ -2945,6 +3092,12 @@ class ServingEngine:
                 lens = np.asarray(
                     [len(e.req.prompt) if final else (k + 1) * C],
                     np.int32)
+                if self.window is not None:
+                    # the chunk's window-kind page, and the row's tables
+                    # as they stand now (pages behind the window are gone)
+                    book.window_extend(sid, (k + 1) * C)
+                    e.pt = np.zeros_like(e.pt)   # the last may be in flight
+                    self._fill_tables(e.pt[0], book, sid)
 
             def _call(toks=toks, pt=e.pt, lens=lens, resume=k * C,
                       aslot=e.aslot, gslot=e.gslot, gstate=e.gstate):
@@ -2972,6 +3125,12 @@ class ServingEngine:
             e.next_chunk += 1
             chunks_run += 1
             tokens_run += C
+            if self.window is not None and not final:
+                # publish the chunk's pages BEFORE any of them is given
+                # back: a parked window page has to carry its key
+                if self.prefix_cache:
+                    book.publish_upto(sid, e.req.prompt, (k + 1) * C)
+                self._window_give_back(book, sid, (k + 1) * C)
             if not final:
                 continue
             with self._phase("lane.complete", sid):
@@ -2988,6 +3147,10 @@ class ServingEngine:
                     sink=sink, acache=acache, aslot=e.aslot, spst=spst,
                     spec_row=e.spec, gcache=gcache, gslot=e.gslot,
                     gname=e.gname, gaut=e.gaut, gstate=e.gstate)
+                if self.window is not None and sid in book.tables:
+                    # published by _prefill_complete; the first decode
+                    # position is the prompt's length
+                    self._window_give_back(book, sid, len(e.req.prompt))
         if self._g_lane_depth is not None:
             self._g_lane_depth.set(float(len(lane)))
         m.on_lane_depth(clock.now(), len(lane))
@@ -3361,7 +3524,7 @@ class ServingEngine:
         of."""
         with self._phase("decode.build"):
             toks = np.zeros((self.slots,), np.int32)
-            pt = np.zeros((self.slots, self.W), np.int32)
+            pt = np.zeros((self.slots, self._table_cols), np.int32)
             lens = np.zeros((self.slots,), np.int32)
             # per-slot adapter ids (0 = identity slot): built only when
             # multi-model serving is on — this is the engine's hottest
@@ -3381,6 +3544,10 @@ class ServingEngine:
                     aids[st.slot] = st.aslot
                 if gids is not None and st.gaut is not None:
                     gids[st.slot] = gcache.flat_id(st.gslot, st.gstate)
+            if self.window is not None:     # the window kind's entries
+                for st in rows:
+                    wt = book.window_table(st.req.rid)
+                    pt[st.slot, self.W:self.W + len(wt)] = wt
         return toks, pt, lens, aids, gids
 
     @staticmethod
@@ -3408,6 +3575,10 @@ class ServingEngine:
             # greedy decode is chunking-invariant, so free rows in
             # the same wave still emit byte-identical streams.
             n = 1
+        if self.window is not None:
+            for st in rows:     # the window-kind pages this call writes
+                book.window_extend(st.req.rid,
+                                   book.lengths[st.req.rid] + n)
         toks, pt, lens, aids, gids = self._decode_batch(
             rows, book, acache, gcache)
         # every slot rides: an idle one at length 0 on the reserved page
@@ -3490,6 +3661,8 @@ class ServingEngine:
                                        free_slots, slot_log, outputs,
                                        tr=tr, acache=acache,
                                        gcache=gcache)
+                elif self.window is not None:
+                    self._window_give_back(book, sid, book.lengths[sid])
         if ahst is not None:
             self._dispatch_ahead_turn(ahst, book, active, acache, n)
 
@@ -3864,8 +4037,11 @@ class EngineSession:
                 "serving_replica_busy_frac",
                 "busy decode slots / slot capacity, sampled per turn",
                 replica=replica or "-")
-        self.book = PagedKVCache(eng.n_pool_pages, eng.page_size,
-                                 kv_heads=1, head_dim=1)
+        self.book = PagedKVCache(
+            eng.n_pool_pages, eng.page_size, kv_heads=1, head_dim=1,
+            **({} if eng.window is None else dict(
+                window_pages=eng.n_window_pages, window=eng.window,
+                window_slack=eng._slack)))
         eng._note_pool(self.book, self.m)
         # per-session host arena (hostmem= engines; None otherwise):
         # each replica owns its spill tier — eviction spill, priced
@@ -4893,7 +5069,8 @@ class EngineSession:
             kv_quant_stats=self.eng._quant_result(self.book,
                                                   self.qst),
             overhead=self.eng._overhead_row(self.clock, self._w0,
-                                            whole=self._replayed),
+                                            whole=self._replayed,
+                                            book=self.book),
             hostmem_stats=self.eng._hostmem_result(self.book,
                                                    self.hst),
             pages_spilled=(

@@ -374,3 +374,74 @@ def test_4d_train_step_without_pipe_axis_lowers_for_tpu():
         text = step.trace(params, opt_state, tok, tok).lower(
             lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") >= 3
+
+
+# Laguna-XS.2's serving cell (serve_window_moe_codemix): 32 slots x 137
+# pages of 64 positions in each of two tables, 8 KV heads x 128 under 48
+# (full) and 64 (sliding, window 512) query heads, a global pool of 5409
+# pages x 2 layers and a window pool of 513 pages x 3 layers
+WINDOWED = dict(slots=32, table=137, page=64, kv_heads=8, window=512,
+                pools={"global": (2, 5409), "window": (3, 513)})
+
+
+@pytest.mark.parametrize("heads,kind", [(48, "global"), (64, "window")])
+@pytest.mark.parametrize("chunk,rows", [(1, WINDOWED["slots"]), (64, 1)])
+def test_windowed_paged_kernel_compiles_at_the_cells_shapes(v5e, chunk, rows,
+                                                            heads, kind):
+    """Query groups of 6 and 8 a KV head, decode (every slot a row) and a
+    64-token chunk (384 and 512 rows a KV head: the second needs more than
+    Mosaic's scoped default of VMEM, which the launcher asks for), with and
+    without the walk's lower bound."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_attention, paged_prefill_attention)
+    one = SingleDeviceSharding(v5e[0])
+    g = WINDOWED
+    layers, pages = g["pools"][kind]
+    window = g["window"] if kind == "window" else None
+    pool = _sds((layers, g["kv_heads"], pages, g["page"], HD), BF16, one)
+    pt, sl = _sds((rows, g["table"]), jnp.int32, one), _sds((rows,), jnp.int32, one)
+    if chunk == 1:
+        c = _compile(lambda q, k, v, pt, sl: paged_attention(
+            q, k, v, pt, sl, layer=1, window=window),
+            _sds((rows, heads, HD), BF16, one), pool, pool, pt, sl)
+    else:
+        c = _compile(lambda q, k, v, pt, sl, st: paged_prefill_attention(
+            q, k, v, pt, sl, st, layer=1, window=window),
+            _sds((rows, heads, chunk, HD), BF16, one), pool, pool, pt, sl,
+            _sds((), jnp.int32, one))
+    assert _mosaic_calls(c) == 1 and "%paged_attention" in c.as_text()
+
+
+def test_windowed_programs_keep_both_pools_in_place(v5e):
+    """The decode and chunk programs of the five-layer cut at the published
+    widths: the four pools are donated and aliased, nothing of a pool's size
+    is made beside them, and each layer calls the kernel once."""
+    from paddle_tpu.models.nlp import laguna as M
+    one = SingleDeviceSharding(v5e[0])
+    g = WINDOWED
+    net = M.LagunaForCausalLM(M.LagunaConfig(num_hidden_layers=5))
+    net.decode_params = lambda: (dict(net.outer),          # shapes in place of arrays
+                                 [dict(lp) for lp in net.layers])
+    outer, layers, _, prefill, _, decode_n = M.windowed_paged_decode_factory(
+        net, page_size=g["page"], n_pool_pages=3, n_window_pages=3,
+        chunked_prefill=g["page"])
+    pools = tuple(_sds((n, g["kv_heads"], p, g["page"], HD), BF16, one)
+                  for n, p in (g["pools"]["global"], g["pools"]["window"])
+                  for _ in range(2))
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    i32 = lambda *s: _sds(s, jnp.int32, one)  # noqa: E731
+    with lower_for_chip():
+        dec = decode_n._jit_inner[0].lower(
+            _on(outer, one), _on(layers, one), i32(g["slots"]),
+            i32(g["slots"], 2 * g["table"]), i32(g["slots"]), pools, 1).compile()
+        chunk = prefill._jit_inner[0].program.lower(
+            _on(outer, one), _on(layers, one), i32(1, g["page"]), i32(),
+            i32(1, 2 * g["table"]), i32(1), pools,
+            _sds((1, 2048), BF16, one)).compile()
+    for c in (dec, chunk):
+        kernels = [ln for ln in c.as_text().splitlines()
+                   if "custom-call(" in ln and "%paged_attention" in ln.split("=")[0]]
+        assert len(kernels) == 5                            # one a layer
+        stats = c.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes
+        assert stats.temp_size_in_bytes < pool_bytes // 8
