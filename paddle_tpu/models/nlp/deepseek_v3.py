@@ -658,6 +658,10 @@ def latent_serving_decode_factory(model: DeepseekV3ForCausalLM,
         page_size_ = page_size
         n_pool_pages_ = n_pool_pages
         chunked_prefill_ = chunked_prefill
+        # chunks ONE lane call may span: the kernel holds heads x width
+        # query rows in VMEM (16 x 256 = 4096 rows: 21.2 MB, for which
+        # the launcher asks); wider than 4 was never compiled for the chip
+        chunked_prefill_widest_ = 4
         kv_layout_ = "latent"
         call_counts = paged[5].counts    # CallCounts: reset() / take()
 

@@ -643,6 +643,13 @@ def windowed_serving_decode_factory(model: LagunaForCausalLM,
         n_window_pages_ = n_window_pages
         window_ = cfg.sliding_window
         chunked_prefill_ = chunked_prefill
+        # chunks ONE lane call may span: the kernel holds (heads a KV
+        # head) x width query rows a KV head in VMEM and unrolls over
+        # them. 8 x 128 = 1024 rows need 33.3 MB (the launcher asks);
+        # 3 and 4 chunks compile too (48.4 / 37.0 MB) but Mosaic takes
+        # 78 / 128 s over such a program where 2 take 40 (compiled for
+        # the chip on a CPU host): a cold start's minutes, so 2
+        chunked_prefill_widest_ = 2
         kv_layout_ = "windowed"
         # a page's bytes, K and V over the layers of its kind
         page_bytes_ = {"global": kv_bytes * len(cfg.layers_of(FULL)),

@@ -1625,7 +1625,9 @@ def chunked_prefill_shim(prefill_chunk, finish_prefill, C: int,
     python over ONE compiled chunk program
     (``prefill_chunk(outer, layers, chunk, start, page_tables, lengths,
     pools, x_last, lora) -> (x_last, pools)``) and the finishing
-    program (``finish_prefill(outer, x_last, grammar)``)."""
+    program (``finish_prefill(outer, x_last, grammar)``). The walk is
+    the entry outside the serving lane; the lane's own is
+    ``prefill_chunked.lane_call``: a span of chunks in one program."""
 
     def prefill_chunked(outer, layers, tokens, page_tables, lengths,
                         pools, resume_from: int = 0, lora=None,
@@ -1664,10 +1666,37 @@ def chunked_prefill_shim(prefill_chunk, finish_prefill, C: int,
         with TraceAnnotation("factory:prefill.finish"):
             return finish_prefill(outer, x_last, grammar), pools
 
+    x0 = {}     # the zeros a call's carry starts from, made once a batch
+
+    def lane_call(outer, layers, span, start: int, page_tables, lengths,
+                  pools, final: bool, lora=None, grammar=None):
+        """The serving lane's entry: ONE chunk program over ``span``
+        ((B, m x C) token ids, cut by the caller on the host) at the
+        absolute positions ``start`` ... ``start + m x C - 1``, ``m``
+        whole chunks of one prompt (the program is generic in its
+        width; a width compiles once). ``lengths``: the real prompt
+        length on the prompt's ``final`` call, the span's end before
+        it. Returns ``(first, pools)``; ``first`` is None unless
+        ``final``: the finishing program (final norm and the
+        whole-vocabulary head) runs for the one call whose last
+        position it reads. One dispatch a call, two on the final."""
+        B = span.shape[0]
+        if B not in x0:
+            x0[B] = jnp.zeros((B, hidden), dtype)
+        with TraceAnnotation("factory:prefill.chunk"):
+            x_last, pools = prefill_chunk(
+                outer, layers, span, start, page_tables, lengths, pools,
+                x0[B], lora)
+        if not final:
+            return None, pools
+        with TraceAnnotation("factory:prefill.finish"):
+            return finish_prefill(outer, x_last, grammar), pools
+
     # the shim itself is plain python; expose the jitted programs it
     # drives so the serving engine's recompile detector (obs layer:
     # program-cache growth across a call) can watch prefill too
     prefill_chunked._jit_inner = (prefill_chunk, finish_prefill)
+    prefill_chunked.lane_call = lane_call
     return prefill_chunked
 
 
@@ -2525,6 +2554,17 @@ def llama_serving_decode_factory(model: LlamaForCausalLM,
         page_size_ = page_size
         n_pool_pages_ = n_pool_pages
         chunked_prefill_ = chunked_prefill
+        # chunks ONE lane call may span (``prefill.lane_call``). None:
+        # the factory sets no limit of its own — the gather path holds
+        # no block of query rows in VMEM, the engine's budget bounds it
+        chunked_prefill_widest_ = None
+        # ... and ONE lane program, the widest, that a narrower span
+        # rides padded: bound by the weights it reads, the program costs
+        # about the same at 64 and at 256 tokens (8.9 / 9.0 / 10.1 / 10.2
+        # ms at 1 to 4 chunks on Mistral's 8 layers, TPU v5e), while
+        # each further width is a trace and a lowering (0.3 - 0.5 s of
+        # every start, the persistent compile cache notwithstanding)
+        chunked_prefill_pads_ = True
         tp_ = tp  # TPConfig when the paged path is mesh-sharded
         lora_ = lora  # LoRAConfig when multi-adapter serving is built
         # GrammarConfig when constrained decoding is built, plus the

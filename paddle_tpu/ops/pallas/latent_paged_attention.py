@@ -157,7 +157,10 @@ def latent_paged_attention(q, pool, layer: int, page_tables, seq_lens,
         interpret=_interpret(),
         name="latent_paged_attention",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            # 16 heads x a lane call of 3 or 4 pages (3072 / 4096 rows)
+            # need 18.0 / 21.2 MB of VMEM, over Mosaic's scoped 16
+            **({"vmem_limit_bytes": 32 << 20} if rows > 2048 else {})),
     )(jnp.asarray(starts, jnp.int32).reshape(B),
       jnp.asarray(page_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32), q, pool)

@@ -300,8 +300,11 @@ def _paged_call(q4, k_pages, v_pages, page_tables, seq_lens, starts,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # a 64-token chunk under 8 query heads a kv head (512 rows)
-            # needs 18.7 MB of VMEM, over Mosaic's scoped default of 16
-            **({"vmem_limit_bytes": 32 << 20} if rows > 384 else {})),
+            # needs 18.7 MB of VMEM, over Mosaic's scoped default of 16;
+            # a lane call of several pages more (1024 rows 33.3 MB, 1536
+            # rows 48.4): 32 MB for every 768 rows
+            **({"vmem_limit_bytes": (32 << 20) * -(-rows // 768)}
+               if rows > 384 else {})),
     )(jnp.asarray(starts, jnp.int32).reshape(B),
       jnp.asarray(page_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32),

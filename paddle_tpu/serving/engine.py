@@ -126,17 +126,20 @@ class EngineClock:
         — the async prefill lane uses it to split a flat per-call
         prefill cost evenly across a prompt's chunk calls, so running
         N bounded calls instead of one monolithic call charges the
-        SAME total. A ragged-fused call passes a LIST of per-chunk
-        costs (one flat split per row advanced) and is charged their
-        SUM — k chunks fused into one program price identically to k
-        sequential chunk calls, never re-multiplied or discounted.
+        SAME total. A fused call (the lane's span of chunks, the
+        ragged lane's rows) passes a LIST of per-chunk costs and is
+        charged them one after another — k chunks fused into one
+        program price identically, to the bit, to k sequential chunk
+        calls, never re-multiplied or discounted.
         Without units/cost the flat per-call cost keeps legacy replays
         bit-identical; a measured clock always charges wall time."""
         if self.mode == "fixed":
             out = fn()
-            if cost is not None:
-                self.t += float(sum(cost)) \
-                    if isinstance(cost, (list, tuple)) else float(cost)
+            if isinstance(cost, (list, tuple)):
+                for c in cost:      # one after another, as calls would
+                    self.t += float(c)
+            elif cost is not None:
+                self.t += float(cost)
             elif units is not None and (units == 0
                                         or f"{kind}_unit"
                                         in self.costs):
@@ -1004,8 +1007,13 @@ class ServingEngine:
                 draft=spec_draft, kv_quant=kv_quant,
                 grammar=grammar_config,
                 # the window pool's size goes to a two-kind model alone
+                # (its ring takes the wider of a decode call's steps and
+                # a lane call's pages: the budget bounds those here)
                 **({"n_window_pages": n_window_pages,
-                    "window_slack": decode_chunk} if windowed else {}))
+                    "window_slack": max(
+                        decode_chunk,
+                        ((prefill_chunk_budget or 1) - 1) * page_size)}
+                   if windowed else {}))
         else:
             if spec_draft is not None:
                 raise ValueError(
@@ -1382,6 +1390,21 @@ class ServingEngine:
                              "per turn (or None for the interleaved "
                              "legacy loop)")
         self.prefill_chunk_budget = prefill_chunk_budget
+        # the chunks ONE lane call may span: the turn's budget, under
+        # the widest call the factory states (None: no limit of its own)
+        budget = prefill_chunk_budget or 1
+        self._lane_widest = min(budget, getattr(
+            serving, "chunked_prefill_widest_", None) or budget)
+        # a factory may state that its chunk program costs about the
+        # same at every width (``chunked_prefill_pads_``): the lane then
+        # has ONE program, the widest, and a narrower span rides it
+        # padded (its table widened by as many columns of the padding
+        # page, so that the program's page slice never runs off it).
+        # Else every width 1 ... widest is a program of its own
+        pads = bool(getattr(serving, "chunked_prefill_pads_", False))
+        self._lane_widths = (self._lane_widest,) if pads \
+            else tuple(range(1, self._lane_widest + 1))
+        self._lane_pad_cols = self._lane_widest - 1 if pads else 0
         self._g_lane_depth = None
         if prefill_chunk_budget is not None:
             # created ONLY when the lane exists, so pre-disagg runs
@@ -1521,7 +1544,14 @@ class ServingEngine:
             # the window pool's floor: a row holds at most ``ring``
             # pages of the kind, so slots x ring (+ the padding page)
             # can never run dry mid-request, whatever is parked
-            ring = window_ring(self.window, page_size, self._slack)
+            # (``_window_slack``: what a call may write past the window
+            # it reads — a decode call's steps, or the pages of a lane
+            # call after its first: for the call's duration the row
+            # holds window + width pages)
+            self._window_slack = max(
+                self._slack, (self._lane_widest - 1) * self.chunk_C)
+            ring = window_ring(self.window, page_size,
+                               self._window_slack)
             if self.n_window_pages - 1 < slots * ring:
                 raise ValueError(
                     f"n_window_pages {self.n_window_pages} is under "
@@ -1583,6 +1613,8 @@ class ServingEngine:
         # tables' slots (what a paged kernel walks, and what a grid as
         # wide as the table would visit)
         self._paged_walk = [0, 0]
+        # the run's lane calls by width (chunks a call spanned)
+        self._lane_calls: Dict[int, int] = {}
         self.eos_token_id = eos_token_id
         self._expect_churn = expect_churn
         self._dense = dense_parts
@@ -1657,6 +1689,35 @@ class ServingEngine:
                 "serving_pool_bytes_per_device",
                 "KV pool bytes resident on one device of the TP mesh")
             self._g_pool_bytes.set(float(per_dev))
+        if prefill_chunk_budget is not None and not self.ragged_prefill \
+                and not getattr(serving, "wants_numpy_", False):
+            self._warm_lane()
+
+    def _warm_lane(self):
+        """Compile every program the lane may call BEFORE the first
+        request (a prompt's remainder decides a call's width, so a
+        run's first call of a width would else compile inside it): each
+        of ``_lane_widths`` once over the reserved page 0 (a table of
+        zeros: what lands there is the padding page's garbage), the
+        first as a final call so that the finishing program compiles
+        too, under the banks' signatures the run's calls will carry."""
+        acache = self._make_adapter_cache()
+        gcache = self._make_grammar_cache()
+        kw = {}
+        if acache is not None:
+            kw["lora"] = self._lora_arg(acache, [0])
+        if gcache is not None:
+            kw["grammar"] = self._grammar_arg(gcache, [0])
+        arr = self._arr
+        pt = arr(np.zeros((1, self._table_cols + self._lane_pad_cols),
+                          np.int32))
+        for w in self._lane_widths:
+            n = w * self.chunk_C
+            _, self._pools = self._p_prefill.lane_call(
+                self._p_outer, self._p_layers,
+                arr(np.zeros((1, n), np.int32)), 0, pt,
+                arr(np.asarray([n], np.int32)), self._pools,
+                w == self._lane_widths[0], **kw)
 
     def pool_bytes_per_device(self) -> Optional[int]:
         """One device's share of the live KV pool, bytes (None when
@@ -2509,6 +2570,7 @@ class ServingEngine:
         if self._call_counts is not None:
             self._call_counts.reset()    # the run's calls alone
         self._paged_walk = [0, 0]
+        self._lane_calls = {}
         self._kv_held = [0, 0, 0]
         return time.perf_counter()
 
@@ -2574,6 +2636,15 @@ class ServingEngine:
                    slots=self.slots,
                    paged_pages_walked=self._paged_walk[0],
                    paged_table_slots=self._paged_walk[1])
+        if self.prefill_chunk_budget is not None \
+                and not self.ragged_prefill:
+            # how often the lane's fused call engages: calls, the chunks
+            # they spanned, and the calls by width
+            by_width = dict(sorted(self._lane_calls.items()))
+            row.update(
+                lane_calls=sum(by_width.values()),
+                lane_chunks=sum(w * n for w, n in by_width.items()),
+                lane_calls_by_width=by_width)
         if counts is not None:
             row["model_counts"] = counts
         if kinds is not None:
@@ -3049,20 +3120,25 @@ class ServingEngine:
         passed over ``_LANE_STARVE_LIMIT`` consecutive times runs its
         next chunk regardless, so a long prefill drains at >= 1 chunk
         per (limit+1) chunks even under a sustained stream of short
-        arrivals. Each chunk is ONE bounded call into the
-        chunked-prefill program — the prompt sliced to the chunk
-        boundary with ``lengths`` clamped to it — which computes
-        exactly what the monolithic prefill computes for those
-        positions (causal attention never looks past the chunk, so
-        greedy tokens are bit-equal); a request's own chunks still
-        run in order, and its final chunk passes the true length and
-        yields the real first-token logits. Fixed-clock pricing: with
-        a ``prefill_unit`` entry each chunk costs one unit; with only
-        a flat per-call cost, that cost is split EVENLY across the
-        request's chunk calls, so the lane charges the same total the
-        monolithic call would (an N-chunk prompt must not become N
-        times pricier just because the lane bounds its calls).
-        Returns (chunks computed, prompt tokens computed)."""
+        arrivals. The chunks that consecutive picks would hand ONE
+        request run as ONE call (``prefill.lane_call``: a span of up
+        to ``_lane_widest`` chunks in one program, its token ids cut
+        on the host, ``lengths`` clamped to the span's end), so the
+        sequence of (request, chunk) a trace computes is the
+        chunk-a-call loop's; the call computes exactly what the
+        monolithic prefill computes for those positions (causal
+        attention never looks past the span, so greedy tokens are
+        bit-equal); a request's own chunks still run in order, and
+        its final call passes the true length and alone runs the
+        finishing program for the real first-token logits.
+        Fixed-clock pricing: with a ``prefill_unit`` entry each chunk
+        costs one unit; with only a flat per-call cost, that cost is
+        split EVENLY across the request's chunks, so the lane charges
+        the same total the monolithic call would (an N-chunk prompt
+        must not become N times pricier just because the lane bounds
+        its calls); a call of w chunks is charged its w chunks, one
+        after another. Returns (chunks computed, prompt tokens
+        computed)."""
         if self.ragged_prefill:
             return self._lane_step_ragged(
                 lane, book, clock, m, active, free_slots, slot_log,
@@ -3071,66 +3147,94 @@ class ServingEngine:
         C = self.chunk_C
         chunks_run = 0
         tokens_run = 0
-        flat = self.clock_mode == "fixed" \
-            and "prefill_unit" not in (self.fixed_costs or {})
+        costs = self.fixed_costs or {}
+        fixed = self.clock_mode == "fixed"
+        flat = fixed and "prefill_unit" not in costs
+        lane_call = self._p_prefill.lane_call
         while lane and chunks_run < self.prefill_chunk_budget:
             with self._phase("lane.pick") as pick:
                 oldest = min(lane, key=lambda x: (x.t_admit, x.req.rid))
-                if oldest.skipped >= self._LANE_STARVE_LIMIT:
-                    e = oldest
-                else:
-                    e = min(lane, key=lambda x: (x.remaining_chunks(),
-                                                 x.t_admit, x.req.rid))
+                srf = min(lane, key=lambda x: (x.remaining_chunks(),
+                                               x.t_admit, x.req.rid))
+                e = oldest if oldest.skipped >= self._LANE_STARVE_LIMIT \
+                    else srf
+                # the chunks this and the next picks would hand ``e`` one
+                # after another: its remainder only shrinks, so it stays
+                # the shortest until its prompt or the budget ends, the
+                # oldest entry's turn comes (aging), or — picked for its
+                # age alone — at once. They run as ONE call, as wide as
+                # the factory's programs go
+                w = min(self.prefill_chunk_budget - chunks_run,
+                        e.remaining_chunks(), self._lane_widest)
+                if e is not srf:
+                    w = 1
                 if e is oldest:
                     oldest.skipped = 0
                 else:
-                    oldest.skipped += 1
+                    w = min(w, self._LANE_STARVE_LIMIT - oldest.skipped)
+                    oldest.skipped += w
                 sid = pick.rid = e.req.rid
                 k = e.next_chunk
-                final = (k + 1 == e.n_chunks)
-                toks = e.toks[:, :(k + 1) * C]
-                lens = np.asarray(
-                    [len(e.req.prompt) if final else (k + 1) * C],
-                    np.int32)
+                end = (k + w) * C
+                final = (k + w == e.n_chunks)
+                span = e.toks[:, k * C:end]
+                pt = e.pt
+                if self._lane_pad_cols:
+                    # the one program is ``_lane_widest`` chunks wide: a
+                    # narrower span rides it padded. ``lengths`` masks
+                    # the padding out of every row's attention, and what
+                    # it writes lands in the row's OWN later pages (the
+                    # prompt's next chunks or its decode positions, each
+                    # written again before it is read) or, past the
+                    # table, on the padding page
+                    span = np.pad(span, ((0, 0), (
+                        0, self._lane_widest * C - span.shape[1])))
+                    pt = np.pad(pt, ((0, 0), (0, self._lane_pad_cols)))
+                lens = np.asarray([len(e.req.prompt) if final else end],
+                                  np.int32)
                 if self.window is not None:
-                    # the chunk's window-kind page, and the row's tables
+                    # the call's window-kind pages, and the row's tables
                     # as they stand now (pages behind the window are gone)
-                    book.window_extend(sid, (k + 1) * C)
-                    e.pt = np.zeros_like(e.pt)   # the last may be in flight
-                    self._fill_tables(e.pt[0], book, sid)
+                    book.window_extend(sid, end)
+                    pt = e.pt = np.zeros_like(e.pt)  # the last may be in flight
+                    self._fill_tables(pt[0], book, sid)
 
-            def _call(toks=toks, pt=e.pt, lens=lens, resume=k * C,
-                      aslot=e.aslot, gslot=e.gslot, gstate=e.gstate):
+            def _call(span=span, pt=pt, lens=lens, start=k * C,
+                      final=final, aslot=e.aslot, gslot=e.gslot,
+                      gstate=e.gstate):
                 arr = self._arr
                 kw = {}
                 if acache is not None:
                     kw["lora"] = self._lora_arg(acache, [aslot])
                 if gcache is not None:
-                    # only the FINAL chunk's logits are harvested, so
-                    # masking every chunk with the row's current gid
-                    # is exact (intermediate chunks discard theirs)
+                    # only the FINAL call's logits are harvested, and
+                    # they alone are masked, by the row's current gid
                     kw["grammar"] = self._grammar_arg(
                         gcache, [gcache.flat_id(gslot, gstate)
                                  if gslot else 0])
-                return self._p_prefill(
-                    self._p_outer, self._p_layers, arr(toks),
-                    arr(pt), arr(lens), self._pools,
-                    resume_from=resume, **kw)
+                return lane_call(
+                    self._p_outer, self._p_layers, arr(span), start,
+                    arr(pt), arr(lens), self._pools, final, **kw)
+            # one span a program call (``units=1``: a factory that counts
+            # its calls keeps one entry for it); a fixed clock prices the
+            # call's chunks one by one, as the calls they were
             first, self._pools = self._timed(
                 tr, clock, "prefill", _call, jitfn=self._p_prefill,
-                rid=sid, units=1, chunk=k, of=e.n_chunks,
-                cost=((self.fixed_costs or {}).get("prefill", 1.0)
-                      / e.run_chunks if flat else None),
+                rid=sid, units=1, chunk=k, of=e.n_chunks, width=w,
+                cost=([costs.get("prefill", 1.0) / e.run_chunks if flat
+                       else costs["prefill_unit"]] * w if fixed
+                      else None),
                 **self._tp_attr)
-            e.next_chunk += 1
-            chunks_run += 1
-            tokens_run += C
+            e.next_chunk += w
+            chunks_run += w
+            tokens_run += w * C
+            self._lane_calls[w] = self._lane_calls.get(w, 0) + 1
             if self.window is not None and not final:
-                # publish the chunk's pages BEFORE any of them is given
+                # publish the call's pages BEFORE any of them is given
                 # back: a parked window page has to carry its key
                 if self.prefix_cache:
-                    book.publish_upto(sid, e.req.prompt, (k + 1) * C)
-                self._window_give_back(book, sid, (k + 1) * C)
+                    book.publish_upto(sid, e.req.prompt, end)
+                self._window_give_back(book, sid, end)
             if not final:
                 continue
             with self._phase("lane.complete", sid):
@@ -4041,7 +4145,7 @@ class EngineSession:
             eng.n_pool_pages, eng.page_size, kv_heads=1, head_dim=1,
             **({} if eng.window is None else dict(
                 window_pages=eng.n_window_pages, window=eng.window,
-                window_slack=eng._slack)))
+                window_slack=eng._window_slack)))
         eng._note_pool(self.book, self.m)
         # per-session host arena (hostmem= engines; None otherwise):
         # each replica owns its spill tier — eviction spill, priced

@@ -153,6 +153,8 @@ class SimServing:
         self.chunked_prefill_ = chunked_prefill or page_size
         if self.chunked_prefill_ % page_size:
             raise ValueError("chunked_prefill must be a page multiple")
+        # chunks one lane call may span: no limit of the sim's own
+        self.chunked_prefill_widest_ = None
         self.vocab = int(vocab)
         self.salt = int(salt)
         # wrapping-uint64 polynomial-hash powers, highest degree first
@@ -390,6 +392,9 @@ class SimServing:
             resume = max(resume, 0)
             for pos in range(resume, L):
                 pools[pt[0, pos // ps], pos % ps] = toks[0, pos]
+            return first_token(pt, L, pools, lora, grammar), pools
+
+        def first_token(pt, L, pools, lora, grammar):
             pages = pt[0, :-(-L // ps)]
             seq = pools[pages].reshape(-1)[:L]
             a_salt = 0
@@ -401,9 +406,24 @@ class SimServing:
                     seq, a_salt, self._grammar_row(grammar, 0))
             else:
                 first = self._token(seq, a_salt)
-            return np.asarray([first], np.int64), pools
+            return np.asarray([first], np.int64)
+
+        def lane_call(outer, layers, span, start, pt, lens, pools,
+                      final, lora=None, grammar=None):
+            """The real shim's lane entry, sim edition: the span's
+            tokens land at ``start`` ... through the page table; the
+            prompt's ``final`` call hashes the pooled history."""
+            span = np.asarray(span)
+            pt = np.asarray(pt)
+            L = int(np.asarray(lens)[0])
+            for pos in range(start, min(start + span.shape[1], L)):
+                pools[pt[0, pos // ps], pos % ps] = span[0, pos - start]
+            if not final:
+                return None, pools
+            return first_token(pt, L, pools, lora, grammar), pools
 
         prefill._cache_size = lambda: 0  # no jit cache to watch
+        prefill.lane_call = lane_call
         return prefill
 
     def _make_prefill_ragged(self):

@@ -152,10 +152,12 @@ LATENT = dict(slots=32, table=97, page=64, heads=16, width=640, rank=512,
               pool=3617, layers=7)
 
 
-@pytest.mark.parametrize("chunk,rows", [(1, LATENT["slots"]), (64, 1)])
+@pytest.mark.parametrize("chunk,rows", [(1, LATENT["slots"]), (64, 1), (256, 1)])
 def test_latent_paged_kernel_compiles_at_the_cells_shapes(v5e, chunk, rows):
-    """Decode (chunk 1, every slot a row) and a 64-token prefill chunk of
-    one request, each against the whole pool addressed by (layer, page)."""
+    """Decode (chunk 1, every slot a row), a 64-token prefill chunk of one
+    request and the lane's widest call (4 chunks: 4096 query rows, 21.2 MB
+    of VMEM, which the launcher asks for), each against the whole pool
+    addressed by (layer, page)."""
     from paddle_tpu.ops.pallas.latent_paged_attention import (
         latent_paged_attention, page_width)
     one = SingleDeviceSharding(v5e[0])
@@ -385,13 +387,14 @@ WINDOWED = dict(slots=32, table=137, page=64, kv_heads=8, window=512,
 
 
 @pytest.mark.parametrize("heads,kind", [(48, "global"), (64, "window")])
-@pytest.mark.parametrize("chunk,rows", [(1, WINDOWED["slots"]), (64, 1)])
+@pytest.mark.parametrize("chunk,rows", [(1, WINDOWED["slots"]), (64, 1), (128, 1)])
 def test_windowed_paged_kernel_compiles_at_the_cells_shapes(v5e, chunk, rows,
                                                             heads, kind):
-    """Query groups of 6 and 8 a KV head, decode (every slot a row) and a
+    """Query groups of 6 and 8 a KV head, decode (every slot a row), a
     64-token chunk (384 and 512 rows a KV head: the second needs more than
-    Mosaic's scoped default of VMEM, which the launcher asks for), with and
-    without the walk's lower bound."""
+    Mosaic's scoped default of VMEM, which the launcher asks for) and the
+    lane's widest call (2 chunks: 768 and 1024 rows, the second 33.3 MB),
+    with and without the walk's lower bound."""
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention, paged_prefill_attention)
     one = SingleDeviceSharding(v5e[0])

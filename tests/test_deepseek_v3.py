@@ -349,10 +349,12 @@ def test_the_engine_serves_what_the_reference_puts_first(net, weights):
     assert admits[-1] > firsts[0]                       # admitted mid-decode
     assert served_gaps(net, weights, res, reqs).max() <= TOL
     assert served_gaps(net, weights, res, reqs, quant="int8").max() > 50 * TOL
-    # fixed shapes: churn never compiled a second program
+    # fixed shapes: churn never compiled a program (the chunk program has
+    # one a width of the lane's call, each compiled when the engine was built)
     chunk_program, finish = eng._p_prefill._jit_inner
     assert eng._p_decode_n._jit_inner[0]._cache_size() == 1
-    assert chunk_program._cache_size() == 1 and finish._cache_size() == 1
+    assert chunk_program._cache_size() == eng._lane_widest == 2
+    assert finish._cache_size() == 1
     res2 = engine(net, slots=2).run(reqs)               # another batch shape, same tokens
     assert res2.outputs == res.outputs
 
@@ -399,12 +401,19 @@ def test_counters_exist_for_this_model_alone(net):
     calls = res.overhead["calls"]
     assert counts["kind"].count("decode") == calls["decode"]["n"]
     assert counts["kind"].count("prefill") == calls["prefill"]["n"]
+    widths = []
     for kind, layer_calls, pairs, hit, largest, read in zip(
             counts["kind"], *(counts[k] for k in M.CALL_COUNTS)):
-        rows = 4 if kind == "decode" else PAGE          # slots, or a chunk's positions
+        rows = 4                                        # the slots
+        if kind == "prefill":
+            rows = pairs // (2 * 3)                     # a call's positions: whole chunks
+            assert rows % PAGE == 0
+            widths.append(rows // PAGE)
         assert layer_calls == 2 and pairs == 2 * rows * 3
         assert 3 * 2 <= hit <= 8 * 2 and pairs / 16 <= largest <= 2 * rows
         assert (read > 0) == (kind == "decode")
+    by_width = res.overhead["lane_calls_by_width"]
+    assert {w: widths.count(w) for w in set(widths)} == by_width and set(by_width) <= {1, 2}
     assert obs_metrics.REGISTRY.counter("serving_moe_pairs_total").value \
         == sum(counts["pairs"])
 

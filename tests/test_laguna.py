@@ -289,7 +289,8 @@ def test_decode_n_compiles_once_across_churn_and_the_counts_ride(tiny):
     res = eng.run(reqs)
     assert all(len(res.outputs[r.rid]) == r.max_new_tokens for r in reqs)
     assert eng._p_decode_n._jit_inner[0]._cache_size() == 1
-    assert all(f._cache_size() == 1 for f in eng._p_prefill._jit_inner)
+    chunk_program, finish = eng._p_prefill._jit_inner    # a program a width of the lane's call
+    assert chunk_program._cache_size() == eng._lane_widest == 2 and finish._cache_size() == 1
     eng2 = engine(net, clock="measured")
     res = eng2.run(reqs)
     ov = res.overhead

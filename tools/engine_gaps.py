@@ -288,6 +288,9 @@ def main(argv=None) -> int:
     (out_dir / f"{stem}.json").write_text(json.dumps(report, indent=1) + "\n")
     print("engine_gaps " + json.dumps({k: report[k] for k in
                                        ("gaps", "long_calls_in_trace", "window_stalls")}))
+    # how often the lane's fused call engaged (absent before it existed)
+    print("lane " + json.dumps({k: overhead.get(k) for k in
+                                ("lane_calls", "lane_chunks", "lane_calls_by_width")}))
     print(json.dumps(result), flush=True)
     return 0
 
