@@ -204,20 +204,24 @@ def rope_interleaved(x, pos, theta):
 def mla_project(cfg, lp, h, pos):
     """h (B, T, H), pos (B, T) or (T,) -> ``q_nope`` (B, T, nh, nope),
     ``q_rope`` (B, T, nh, rope) after RoPE, and what the cache holds of
-    these positions, ``latent`` (B, T, rank + rope)."""
+    these positions, ``latent`` (B, T, rank + rope).  A config that states
+    ``mla_use_nope`` rotates nothing: its ``rope`` columns enter the score
+    as they are projected."""
     B, T, _ = h.shape
     nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     pos = jnp.broadcast_to(pos, (B, T))
     with jax.named_scope("mla.project"):
         q = (h @ lp["self_attn.q_proj.weight"]).reshape(
             B, T, nh, cfg.qk_head_dim)
-        q_nope = q[..., :cfg.qk_nope_head_dim]
-        q_rope = rope_interleaved(q[..., cfg.qk_nope_head_dim:],
-                                  pos[..., None], cfg.rope_theta)
+        q_nope, q_rope = q[..., :cfg.qk_nope_head_dim], \
+            q[..., cfg.qk_nope_head_dim:]
         kva = h @ lp["self_attn.kv_a_proj_with_mqa.weight"]
         c = _rms(kva[..., :rank], lp["self_attn.kv_a_layernorm.weight"],
                  cfg.rms_norm_eps)
-        k_rope = rope_interleaved(kva[..., rank:], pos, cfg.rope_theta)
+        k_rope = kva[..., rank:]
+        if not getattr(cfg, "mla_use_nope", False):
+            q_rope = rope_interleaved(q_rope, pos[..., None], cfg.rope_theta)
+            k_rope = rope_interleaved(k_rope, pos, cfg.rope_theta)
         return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
 
 
@@ -256,9 +260,10 @@ def absorbed_output(cfg, lp, ctx, T):
 
 
 def feed_forward(cfg, lp, h):
-    """-> (y, the expert layer's counts, or None for a dense layer)."""
+    """-> (y, the expert layer's counts, or None for a dense layer).  A
+    config that states ``experts_held`` holds that share of the experts."""
     if ROUTER in lp:
-        return expert_layer(cfg, lp, h)
+        return expert_layer(cfg, lp, h, getattr(cfg, "experts_held", None))
     g, u, d = (lp[k] for k in _DENSE_KEYS)
     return (jax.nn.silu(h @ g) * (h @ u)) @ d, None
 

@@ -42,6 +42,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 ROUTER = "mlp.gate.weight"
 ROUTER_BIAS = "mlp.gate.e_score_correction_bias"
@@ -122,13 +123,17 @@ def routed_part(cfg, lp, x, held=None):
                                  "experts: say which (held=)")
             local = idx
         else:
-            held = jnp.asarray(held, jnp.int32)
+            held = np.asarray(held, np.int32)
             if held.shape != (n_held,):
                 raise ValueError(f"held names {held.shape} experts, the "
                                  f"stacks hold {n_held}")
-            # an expert held elsewhere sorts behind every group
-            local = jnp.full((E,), n_held, jnp.int32).at[held].set(
-                jnp.arange(n_held, dtype=jnp.int32))[idx]
+            # an expert held elsewhere sorts behind every group (the map is
+            # made on the host: as a traced scatter its indices and updates
+            # are one constant where held is 0..n-1, which XLA:TPU's
+            # scatter fusion aborts on)
+            place = np.full((E,), n_held, np.int32)
+            place[held] = np.arange(n_held, dtype=np.int32)
+            local = jnp.asarray(place)[idx]
         flat = local.reshape(-1)                     # pair p = token p // k
         order = jnp.argsort(flat, stable=True)       # pairs grouped by expert
         sizes = jnp.zeros((n_held + 1,), jnp.int32).at[flat].add(1)[:n_held]

@@ -506,6 +506,104 @@ class _WindowKind:
             table.append(p)
 
 
+class _StateKind:
+    """The book's THIRD kind: what a model's recurrent (linear-attention)
+    layers hold of a sequence (``PagedKVCache(state_slots=,
+    state_snapshots=)``) — not pages but one fixed-size ENTRY, with no
+    table and no growth.
+
+    Entries ``0 .. slots - 1`` are the decode slots' running states (slot
+    ``s`` computes in entry ``s``: nothing to allocate). The
+    ``n_snapshots`` entries behind them belong to the prefix cache. A
+    latent page says what the keys at its positions were; it does not say
+    what the state was, and a state cannot be rebuilt from pages without
+    re-running every position under it. So a prefix hit is valid only at
+    a position where a SNAPSHOT stands: a copy of a running entry, taken
+    at a page edge and keyed — like the window kind's pages — by the
+    (global) page that ends at that position. A snapshot is pinned while
+    a row resumes from it, otherwise the least recently used one is
+    overwritten when a new one is wanted, and it is dropped when its page
+    loses its identity (the chain is the one chain over token pages)."""
+
+    def __init__(self, slots: int, n_snapshots: int):
+        self.slots = slots
+        self.n_snapshots = n_snapshots
+        self.reset()
+        self.taken = 0              # snapshots taken
+        self.evicted = 0            # snapshots overwritten for a new one
+        self.cut = 0                # prefix hits shortened or lost here
+        self.cut_tokens = 0         # matched tokens recomputed for it
+        self.matched_tokens = 0     # tokens the chain could have served
+
+    def reset(self):
+        self._free = list(range(self.slots + self.n_snapshots - 1,
+                                self.slots - 1, -1))
+        self._by_key: dict = {}     # global page -> entry; insertion = LRU
+        self._key: dict = {}        # entry -> the page it is keyed by
+        self._pins: dict = {}       # entry -> rows resuming from it
+
+    def populations(self):
+        """(pinned, unpinned, free) snapshot entries."""
+        return (len(self._pins), len(self._key) - len(self._pins),
+                len(self._free))
+
+    def deepest(self, chain, cap: int) -> int:
+        """The most pages ``n <= cap`` of ``chain`` at whose end a
+        snapshot stands (0: the zero state stands at the start)."""
+        for n in range(cap, 0, -1):
+            if chain[n - 1] in self._by_key:
+                return n
+        return 0
+
+    def pin(self, page: int) -> int:
+        """The entry keyed by ``page``, most recently used from now and
+        not to be overwritten until ``unpin``."""
+        entry = self._by_key.pop(page)
+        self._by_key[page] = entry
+        self._pins[entry] = self._pins.get(entry, 0) + 1
+        return entry
+
+    def unpin(self, entry: int):
+        n = self._pins.get(entry, 1) - 1
+        if n > 0:
+            self._pins[entry] = n
+        else:
+            self._pins.pop(entry, None)
+
+    def take(self, page: int):
+        """An entry for a new snapshot keyed by ``page``: a free one, else
+        the least recently used that no row is resuming from. None where
+        ``page`` has its snapshot already (touched) or every entry is
+        pinned."""
+        if page in self._by_key:
+            self._by_key[page] = self._by_key.pop(page)
+            return None
+        if self._free:
+            entry = self._free.pop()
+        else:
+            old = next((p for p, e in self._by_key.items()
+                        if e not in self._pins), None)
+            if old is None:
+                return None
+            entry = self._by_key.pop(old)
+            del self._key[entry]
+            self.evicted += 1
+        self._by_key[page] = entry
+        self._key[entry] = page
+        self.taken += 1
+        return entry
+
+    def unkey(self, page: int):
+        """The page lost its identity: its snapshot can serve no prefix
+        again (a pinned one is being copied from in program order, and
+        the copy was dispatched before anything could overwrite it)."""
+        entry = self._by_key.pop(page, None)
+        if entry is not None:
+            del self._key[entry]
+            self._pins.pop(entry, None)
+            self._free.append(entry)
+
+
 class PagedKVCache:
     """Host-side page-pool bookkeeping for serving loops: a free list of
     pages plus per-sequence tables (~ vLLM's BlockManager). Device data
@@ -514,12 +612,17 @@ class PagedKVCache:
     def __init__(self, n_pages: int, page_size: int, kv_heads: int,
                  head_dim: int, dtype=jnp.bfloat16,
                  window_pages: int | None = None,
-                 window: int | None = None, window_slack: int = 1):
+                 window: int | None = None, window_slack: int = 1,
+                 state_slots: int | None = None, state_snapshots: int = 0):
         # a second kind of page (``_WindowKind``) for a model whose
         # sliding-window layers keep a pool of their own; None: every
         # statement below runs as it did before the kind existed
         self._win = None if window_pages is None else _WindowKind(
             window_pages, page_size, window, window_slack)
+        # a third kind (``_StateKind``): a state entry a sequence and the
+        # prefix cache's snapshots, for a model with recurrent layers
+        self._state = None if state_slots is None else _StateKind(
+            state_slots, state_snapshots)
         self._pub: dict = {}        # seq -> (pages published, last page)
         self._cut_of: dict = {}     # seq -> its hit was cut (for rollback)
         self._kind_bytes: dict | None = None
@@ -966,8 +1069,9 @@ class PagedKVCache:
         the wrong-context-KV hazard — every key chained THROUGH it
         (a future sequence must never match stale children under the
         recycled id and share unrelated K/V)."""
-        if self._win is not None:
-            self._win.unkey(p)
+        for kind in (self._win, self._state):
+            if kind is not None:
+                kind.unkey(p)
         key = self._page_key.pop(p, None)
         if key is not None:
             self._prefix.pop(key, None)
@@ -981,8 +1085,9 @@ class PagedKVCache:
             if page_c is not None \
                     and self._page_key.get(page_c) == ck:
                 self._page_key.pop(page_c, None)
-                if self._win is not None:
-                    self._win.unkey(page_c)
+                for kind in (self._win, self._state):
+                    if kind is not None:
+                        kind.unkey(page_c)
 
     def acquire_prefix(self, seq_id, tokens) -> int:
         """Match ``tokens`` against cached FULL prompt pages; matched
@@ -1006,6 +1111,14 @@ class PagedKVCache:
             if hit < cap:
                 self._win.cut += 1
                 self._cut_of[seq_id] = True
+        elif self._state is not None:
+            chain, hit, cap = self._state_hit(tokens)
+            pages = chain[:hit]
+            st = self._state
+            st.matched_tokens += cap * self.page_size
+            st.cut_tokens += (cap - hit) * self.page_size
+            st.cut += hit < cap
+            self._cut_of[seq_id] = (cap, hit)
         for page in pages:
             if page in self._evictable:
                 del self._evictable[page]  # revival: LRU -> resident
@@ -1019,6 +1132,7 @@ class PagedKVCache:
         self.lengths[seq_id] = n
         if self._win is not None:
             self._win.share(seq_id, chain, hit)
+        if self._win is not None or self._state is not None:
             self._pub[seq_id] = (hit, pages[-1] if pages else 0)
         return n
 
@@ -1034,6 +1148,12 @@ class PagedKVCache:
         n_cached = len(self.tables.get(seq_id, ())) * self.page_size
         if self._win is not None and self._cut_of.pop(seq_id, False):
             self._win.cut -= 1      # the retry will count it again
+        if self._state is not None and seq_id in self._cut_of:
+            cap, hit = self._cut_of.pop(seq_id)    # the retry counts again
+            st = self._state
+            st.matched_tokens -= cap * self.page_size
+            st.cut_tokens -= (cap - hit) * self.page_size
+            st.cut -= hit < cap
         self.free(seq_id)
         self._stats["hit_tokens"] -= n_cached
         self._stats["lookup_tokens"] -= \
@@ -1063,7 +1183,43 @@ class PagedKVCache:
         admission turn to price prefill work before committing."""
         if self._win is not None:
             return self._two_kind_hit(tokens)[1] * self.page_size
+        if self._state is not None:
+            return self._state_hit(tokens)[1] * self.page_size
         return sum(self.page_size for _ in self._chain(tokens))
+
+    def _state_hit(self, tokens):
+        """-> (the matched chain, pages a book with a state kind can serve
+        of it, pages it could were a snapshot at every page): the deepest
+        position under the matched chain at which a snapshot stands. A hit
+        never takes the whole prompt (the final chunk always runs)."""
+        chain = list(self._chain(tokens))
+        cap = min(len(chain), (len(tokens) - 1) // self.page_size)
+        return chain, self._state.deepest(chain, cap), cap
+
+    # --- the state kind (books of a model with recurrent layers) --------
+
+    def state_resume(self, seq_id):
+        """The snapshot entry ``seq_id``'s acquired prefix ends on, pinned
+        until ``state_resumed`` (None: it starts from the zero state)."""
+        n, last = self._pub.get(seq_id, (0, 0))
+        return self._state.pin(last) if n else None
+
+    def state_resumed(self, entry: int):
+        """The copy out of ``entry`` is dispatched: it may be overwritten
+        (later, in program order)."""
+        self._state.unpin(entry)
+
+    def state_snapshot(self, seq_id, tokens, n_tokens: int):
+        """``seq_id``'s running state stands at ``n_tokens`` (a page's
+        edge inside its prompt): publish its pages up to there and hand
+        out the entry to copy the state to, or None where that position
+        has its snapshot already, the chain was evicted under the
+        sequence, or every entry is pinned."""
+        self.publish_upto(seq_id, tokens, n_tokens)
+        n, last = self._pub.get(seq_id, (None, 0))
+        if n is None or n * self.page_size != n_tokens or not last:
+            return None
+        return self._state.take(last)
 
     def _two_kind_hit(self, tokens):
         """-> (the matched global chain, pages a two-kind book can serve
@@ -1125,7 +1281,8 @@ class PagedKVCache:
                 self._page_key[page] = key
                 self._children.setdefault(parent, set()).add(key)
             parent = self._prefix[key]
-            self._win.publish(seq_id, i, parent)
+            if self._win is not None:
+                self._win.publish(seq_id, i, parent)
             i += 1
         self._pub[seq_id] = (i, parent)
 
@@ -1152,7 +1309,7 @@ class PagedKVCache:
     def register_prefix(self, seq_id, tokens):
         """Publish seq_id's FULL prompt pages (now holding real K/V) for
         sharing. Call after the prompt's prefill wrote its pages."""
-        if self._win is not None:
+        if self._win is not None or self._state is not None:
             return self.publish_upto(seq_id, tokens, len(tokens))
         table = self.tables.get(seq_id, [])
         parent = 0
@@ -1201,6 +1358,7 @@ class PagedKVCache:
     def free(self, seq_id):
         if self._win is not None:
             self._win.free(seq_id)
+        if self._win is not None or self._state is not None:
             self._pub.pop(seq_id, None)
             self._cut_of.pop(seq_id, None)
         for p in self.tables.pop(seq_id, []):
@@ -1245,10 +1403,11 @@ class PagedKVCache:
         self._quant.clear()  # both tiers go: pre-purge int8 content is
         # as untrusted as the full-precision pages
         self._free = list(range(n_pages - 1, 0, -1))
-        if self._win is not None:
-            self._win.reset()
-            self._pub.clear()
-            self._cut_of.clear()
+        for kind in (self._win, self._state):
+            if kind is not None:
+                kind.reset()
+                self._pub.clear()
+                self._cut_of.clear()
         if self._arena is not None:
             # the host tier dies with the pool: pre-purge spilled
             # content is exactly as untrusted as pre-purge device
@@ -1320,6 +1479,13 @@ class PagedKVCache:
         if self._win is not None:
             balanced = balanced and obs_ledger.census_balanced(
                 self._win.n_pages - 1, *self._win.populations())
+        if self._state is not None:
+            # every snapshot entry is pinned, parked or free, and every
+            # parked or pinned one is keyed by a page that has its identity
+            st = self._state
+            balanced = balanced and obs_ledger.census_balanced(
+                st.n_snapshots, *st.populations()) \
+                and obs_ledger.overlay_contained(st._by_key, self._page_key)
         return balanced and tier_ok
 
     def cache_stats(self) -> dict:
@@ -1353,6 +1519,18 @@ class PagedKVCache:
             out["prefix_hits_cut_by_window"] = self._win.cut
             if self._kind_bytes is not None:
                 out["page_bytes"] = dict(self._kind_bytes)
+        if self._state is not None:
+            # the state kind's own accounting ONLY where there is one
+            st = self._state
+            out["state"] = dict(
+                zip(("pinned_snapshots", "parked_snapshots",
+                     "free_snapshots"), st.populations()),
+                n_snapshots=st.n_snapshots, slots=st.slots,
+                state_snapshots_taken=st.taken,
+                state_snapshots_evicted=st.evicted,
+                prefix_hits_cut_by_snapshot=st.cut,
+                prefix_tokens_cut_by_snapshot=st.cut_tokens,
+                prefix_tokens_matched=st.matched_tokens)
         if self._pool_bytes is not None:
             # only when noted (a sharded serving pool): unsharded runs
             # keep the pre-TP dict byte-for-byte

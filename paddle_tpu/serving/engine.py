@@ -339,26 +339,51 @@ _WINDOWED_REFUSES = (
     "None: a whole prompt's window pages at once) — got ")
 
 
+_STATE_REFUSES = (
+    "a latent+state cache keeps a latent paged pool beside one fixed-size "
+    "state entry a sequence (and the prefix cache's snapshots of it): it "
+    "does not compose yet with tp (the states are not sharded), kv_quant "
+    "/ kv_cache_dtype (no codec for a float32 state), hostmem and KV "
+    "handoff (export / import / reshard / repage / transcode move pages, "
+    "and a chain without its state resumes nowhere), lora / adapters, "
+    "spec (a rejected proposal cannot be taken out of a state), grammar "
+    "or dispatch_ahead (its programs take no such argument, and a stashed "
+    "batch would step a state twice), ragged_prefill, or prefill outside "
+    "the lane (prefill_chunk_budget=None: snapshots are taken at the "
+    "lane's call boundaries) — got ")
+
+
+# what each cache layout other than the head-major one does not compose
+# with yet: layout -> (the refusal's message, whether it also refuses
+# ragged_prefill and prefill outside the lane, what the dense policy is
+# told). ONE table, read in one place (``_refuse_layout``)
+_LAYOUT_REFUSES = {
+    "latent": (_LATENT_REFUSES, False, (
+        "with a latent cache",
+        "the dense wave cache stores per-head K and V")),
+    "windowed": (_WINDOWED_REFUSES, True, (
+        "with a two-kind cache",
+        "the dense wave cache has no page kinds")),
+    "latent+state": (_STATE_REFUSES, True, (
+        "with a latent+state cache",
+        "the dense wave cache has no state entry a sequence")),
+}
+
+
 def _kv_layout(obj) -> str:
     """The cache layout a model or a prebuilt factory states."""
     return getattr(obj, "kv_layout_", "head_major")
 
 
-def _refuse_latent(**used):
-    """The one refusal of everything a latent cache does not compose
-    with: raises naming the options in use, returns when none is."""
-    _refuse(_LATENT_REFUSES, **used)
-
-
-def _refuse(message: str, **used):
+def _refuse_layout(layout: str, **used):
+    """The one refusal of everything a cache layout does not compose
+    with: raises naming the options in use, returns when none is (or the
+    layout refuses nothing)."""
+    if layout not in _LAYOUT_REFUSES:
+        return
     named = sorted(k for k, v in used.items() if v is not None)
     if named:
-        raise ValueError(message + ", ".join(named))
-
-
-def _refuse_windowed(**used):
-    """``_refuse_latent``'s twin for a two-kind cache."""
-    _refuse(_WINDOWED_REFUSES, **used)
+        raise ValueError(_LAYOUT_REFUSES[layout][0] + ", ".join(named))
 
 
 def _coerce_paged_only(policy, what: str, why: str):
@@ -854,7 +879,9 @@ class ServingEngine:
                  dispatch_ahead: bool = False, hostmem=None,
                  grammar=None, grammar_config=None,
                  adapter_schemas=None, ledger=None,
-                 n_window_pages: Optional[int] = None):
+                 n_window_pages: Optional[int] = None,
+                 n_state_snapshots: Optional[int] = None,
+                 state_snapshot_every: Optional[int] = None):
         # ``tp``: None (byte-identical to the single-device engine —
         # outputs, slot logs, metrics records, registry contents), a
         # TPConfig, or an int degree. With a MODEL it is threaded into
@@ -930,39 +957,35 @@ class ServingEngine:
         # every head, ``kv_layout_ = "latent"`` on the model or the
         # prebuilt factory): whatever assumes K and V pages with a
         # head axis is refused HERE, once, with one message
-        latent = _kv_layout(serving if serving is not None
-                            else model) == "latent"
-        if latent:
-            _refuse_latent(
-                tp=tp, lora=lora, adapters=adapters, spec=spec,
-                spec_draft=spec_draft, kv_quant=kv_quant,
-                kv_cache_dtype=kv_cache_dtype, hostmem=hostmem,
-                grammar=grammar, grammar_config=grammar_config,
-                dispatch_ahead=dispatch_ahead or None)
-            policy = _coerce_paged_only(
-                policy, "with a latent cache",
-                "the dense wave cache stores per-head K and V")
-        # A TWO-KIND cache (``kv_layout_ = "windowed"``: global layers'
-        # pages grow with the sequence, sliding-window layers' pages are
-        # given back behind the window): the same pattern, once
-        windowed = _kv_layout(serving if serving is not None
-                              else model) == "windowed"
-        if windowed:
-            _refuse_windowed(
-                tp=tp, lora=lora, adapters=adapters, spec=spec,
+        # A TWO-KIND cache (``"windowed"``: global layers' pages grow
+        # with the sequence, sliding-window layers' pages are given back
+        # behind the window) and a LATENT+STATE one (``"latent+state"``: a
+        # latent pool beside one state entry a sequence) refuse the same
+        # and the lane's alternatives besides: one table, one call
+        layout = _kv_layout(serving if serving is not None else model)
+        windowed = layout == "windowed"
+        stateful = layout == "latent+state"
+        if layout in _LAYOUT_REFUSES:
+            _, lane_only, dense_why = _LAYOUT_REFUSES[layout]
+            _refuse_layout(
+                layout, tp=tp, lora=lora, adapters=adapters, spec=spec,
                 spec_draft=spec_draft, kv_quant=kv_quant,
                 kv_cache_dtype=kv_cache_dtype, hostmem=hostmem,
                 grammar=grammar, grammar_config=grammar_config,
                 dispatch_ahead=dispatch_ahead or None,
-                ragged_prefill=ragged_prefill or None,
-                prefill_outside_the_lane=True
-                if prefill_chunk_budget is None else None)
-            policy = _coerce_paged_only(
-                policy, "with a two-kind cache",
-                "the dense wave cache has no page kinds")
-        elif n_window_pages is not None:
+                **({"ragged_prefill": ragged_prefill or None,
+                    "prefill_outside_the_lane":
+                        True if prefill_chunk_budget is None else None}
+                   if lane_only else {}))
+            policy = _coerce_paged_only(policy, *dense_why)
+        if n_window_pages is not None and not windowed:
             raise ValueError("n_window_pages= sizes a two-kind cache's "
                              "window pool; this model has one kind")
+        if not stateful and (n_state_snapshots is not None
+                             or state_snapshot_every is not None):
+            raise ValueError("n_state_snapshots= / state_snapshot_every= "
+                             "size a latent+state cache's snapshots; this "
+                             "model keeps no state entry")
         if serving is None:
             if model is None:
                 raise ValueError("pass a model or a prebuilt serving "
@@ -1013,7 +1036,10 @@ class ServingEngine:
                     "window_slack": max(
                         decode_chunk,
                         ((prefill_chunk_budget or 1) - 1) * page_size)}
-                   if windowed else {}))
+                   if windowed else {}),
+                # the snapshot entries go to a latent+state model alone
+                **({"n_state_snapshots": n_state_snapshots or 0}
+                   if stateful else {}))
         else:
             if spec_draft is not None:
                 raise ValueError(
@@ -1284,10 +1310,24 @@ class ServingEngine:
             if windowed else None
         self.n_window_pages = getattr(serving, "n_window_pages_", None) \
             if windowed else None
-        self._table_cols = self.W * (2 if windowed else 1)
-        if windowed and serving.chunked_prefill_ != page_size:
-            raise ValueError("a two-kind cache prefills a page a chunk "
-                             "(a hit resumes on a page's edge)")
+        # a latent+state cache: a row's state entry rides one column
+        # more (a decode call's row is its slot's entry); snapshots are
+        # taken where a lane call ends on a multiple of ``_state_every``
+        # positions or on a prompt's last full page. None: no state kind
+        self._state_every = None
+        self.n_state_snapshots = None
+        if stateful:
+            self.n_state_snapshots = serving.n_state_snapshots_
+            self._state_every = state_snapshot_every \
+                if state_snapshot_every is not None else 16 * page_size
+            if self._state_every % page_size:
+                raise ValueError(
+                    f"state_snapshot_every {self._state_every} must be a "
+                    f"multiple of page_size {page_size}")
+        self._table_cols = self.W * (2 if windowed else 1) + stateful
+        if (windowed or stateful) and serving.chunked_prefill_ != page_size:
+            raise ValueError("a cache of more than one kind prefills a page "
+                             "a chunk (a hit resumes on a page's edge)")
         self.chunk_C = serving.chunked_prefill_
         # ``clock``: "measured" | "fixed" (virtual time), "wall"
         # (real time: what a live server runs on), or an EngineClock
@@ -1581,8 +1621,34 @@ class ServingEngine:
                     "serving_kv_pages_if_all_global_total",
                     "pages a layer the same rows would hold were "
                     "every layer global, summed over turns")}
-        # the run's page census by kind, sampled a turn (two kinds only)
-        self._kv_held = [0, 0, 0]        # turns, global, window
+        self._ctr_state = None
+        if self._state_every is not None:
+            # created ONLY for a latent+state model
+            _sc = obs_metrics.REGISTRY.counter
+            self._ctr_state = {
+                "state_snapshots_taken": _sc(
+                    "serving_state_snapshots_taken_total",
+                    "running states copied to a snapshot entry for the "
+                    "prefix cache"),
+                "state_snapshots_evicted": _sc(
+                    "serving_state_snapshots_evicted_total",
+                    "snapshot entries overwritten (least recently used) "
+                    "for a new snapshot"),
+                "prefix_hits_cut_by_snapshot": _sc(
+                    "serving_prefix_hits_cut_by_snapshot_total",
+                    "prefix hits shortened or lost because no snapshot "
+                    "stood at the matched chain's end"),
+                "kv_bytes_held_latent": _sc(
+                    "serving_kv_bytes_held_latent_total",
+                    "bytes of latent pages held by running rows, summed "
+                    "over turns"),
+                "kv_bytes_held_state": _sc(
+                    "serving_kv_bytes_held_state_total",
+                    "bytes of state entries held by running rows, summed "
+                    "over turns")}
+        # the run's census by kind, sampled a turn (more than one kind):
+        # turns, global | latent pages, window pages | state entries
+        self._kv_held = [0, 0, 0]
         self.clock_mode = clock
         self.fixed_costs = fixed_costs
         # ``ledger``: None (byte-identical — the tr-is-None
@@ -1673,7 +1739,8 @@ class ServingEngine:
         # a latent pool is noted too: its page is not K and V of the
         # head width, so the book is told its bytes (tp / kv_quant are
         # None there: the refusals above)
-        if tp is not None or kv_quant is not None or latent or windowed:
+        if tp is not None or kv_quant is not None \
+                or layout in _LAYOUT_REFUSES:
             # a quantizing factory prices its own pool (the sim's
             # token pools model the int8 layout arithmetically; the
             # real factory's leaves ARE the small arrays)
@@ -1718,6 +1785,10 @@ class ServingEngine:
                 arr(np.zeros((1, n), np.int32)), 0, pt,
                 arr(np.asarray([n], np.int32)), self._pools,
                 w == self._lane_widths[0], **kw)
+        if self._state_every is not None:
+            # the snapshot / restore copy compiles here too (slot 0 onto
+            # itself: nothing runs yet)
+            self._pools = self.serving.state_copy(self._pools, 0, 0)
 
     def pool_bytes_per_device(self) -> Optional[int]:
         """One device's share of the live KV pool, bytes (None when
@@ -2424,6 +2495,12 @@ class ServingEngine:
                 self._kv_held[0] += 1
                 self._kv_held[1] += pops["global"][0]
                 self._kv_held[2] += pops["window"][0]
+            elif self._state_every is not None:
+                # latent pages held, and one state entry a row that holds
+                # pages (running or in the lane)
+                self._kv_held[0] += 1
+                self._kv_held[1] += len(book._refs)
+                self._kv_held[2] += len(book.tables)
             if acache is not None:
                 a_inv &= acache.census_ok()
             if gcache is not None:
@@ -2453,6 +2530,34 @@ class ServingEngine:
         under ``turn``."""
         with self._phase("window.release", sid):
             book.window_release(sid, next_pos)
+
+    def _state_restore(self, book, sid, slot: int):
+        """A latent+state cache at admission: the snapshot the row's
+        acquired prefix ends on is copied onto its slot's entry (a row
+        that starts at position 0 needs nothing: its first call starts
+        from the zero state). A span of its own under ``admit``."""
+        entry = book.state_resume(sid)
+        if entry is None:
+            return
+        with self._phase("state.restore", sid):
+            self._pools = self.serving.state_copy(self._pools, entry, slot)
+            book.state_resumed(entry)
+
+    def _state_snapshot(self, book, e, end: int):
+        """After a lane call that ended at ``end``: where that is a
+        multiple of ``_state_every`` or the prompt's last full page (and
+        no padded position was run: the state stands AT ``end``), the
+        slot's entry is copied to a snapshot entry keyed by the page that
+        ends there. A span of its own under ``turn``."""
+        n = len(e.req.prompt)
+        if not self.prefix_cache or end > n or (
+                end % self._state_every and end != n - n % self.chunk_C):
+            return
+        with self._phase("state.snapshot", e.req.rid):
+            entry = book.state_snapshot(e.req.rid, e.req.prompt, end)
+            if entry is not None:
+                self._pools = self.serving.state_copy(
+                    self._pools, e.slot, entry)
 
     def _pad_len(self, n: int) -> int:
         # pad prompts to the CHUNK multiple (a page multiple by factory
@@ -2621,6 +2726,31 @@ class ServingEngine:
                 **{k: sums[k] for k in ("kv_pages_if_all_global",
                                         "window_pages_released",
                                         "prefix_hits_cut_by_window")}}
+        if self._state_every is not None and book is not None:
+            # a latent+state cache's own accounting (absent for every
+            # other model): what the rows held by kind summed over the
+            # turns sampled, a page's and an entry's bytes (and a page's
+            # were every layer a latent one), and the snapshots' work
+            turns, held_p, held_s = self._kv_held
+            sv, st = self.serving, book.cache_stats()["state"]
+            pb = {"latent": sv.page_bytes_["latent"],
+                  "state": sv.state_entry_bytes_,
+                  "latent_all_layers": sv.page_bytes_all_latent_}
+            for key in ("state_snapshots_taken", "state_snapshots_evicted",
+                        "prefix_hits_cut_by_snapshot"):
+                self._ctr_state[key].inc(st[key])
+            self._ctr_state["kv_bytes_held_latent"].inc(
+                held_p * pb["latent"])
+            self._ctr_state["kv_bytes_held_state"].inc(held_s * pb["state"])
+            kinds = {
+                "kv_pages_held": {"latent": held_p, "state": held_s,
+                                  "turns": turns},
+                "kv_page_bytes": pb,
+                **{k: st[k] for k in (
+                    "state_snapshots_taken", "state_snapshots_evicted",
+                    "prefix_hits_cut_by_snapshot",
+                    "prefix_tokens_cut_by_snapshot",
+                    "prefix_tokens_matched")}}
         if clock.mode == "fixed":
             return None
         run_wall = time.perf_counter() - run_w0    # before the summing
@@ -2910,6 +3040,9 @@ class ServingEngine:
                 toks[0, :len(r.prompt)] = r.prompt
                 pt = np.zeros((1, self._table_cols), np.int32)
                 self._fill_tables(pt[0], book, sid)
+                if self._state_every is not None:
+                    pt[0, -1] = slot        # the row's state entry
+                    self._state_restore(book, sid, slot)
                 lens = np.asarray([len(r.prompt)], np.int32)
                 resume = (n_cached // self.chunk_C) * self.chunk_C
                 # the factory clamps resume so the FINAL chunk always runs
@@ -3175,6 +3308,16 @@ class ServingEngine:
                     oldest.skipped += w
                 sid = pick.rid = e.req.rid
                 k = e.next_chunk
+                if self._state_every is not None:
+                    # a call ends where a snapshot is due: the next
+                    # multiple of ``_state_every``, or the prompt's last
+                    # full page
+                    stop = (k * C // self._state_every + 1) \
+                        * self._state_every
+                    last = len(e.req.prompt) // C * C
+                    if k * C < last < stop:
+                        stop = last
+                    w = min(w, stop // C - k)
                 end = (k + w) * C
                 final = (k + w == e.n_chunks)
                 span = e.toks[:, k * C:end]
@@ -3229,6 +3372,8 @@ class ServingEngine:
             chunks_run += w
             tokens_run += w * C
             self._lane_calls[w] = self._lane_calls.get(w, 0) + 1
+            if self._state_every is not None:
+                self._state_snapshot(book, e, end)
             if self.window is not None and not final:
                 # publish the call's pages BEFORE any of them is given
                 # back: a parked window page has to carry its key
@@ -3452,8 +3597,7 @@ class ServingEngine:
         real llama factory's pools, whose every leaf is page-indexed
         on axis 2 ((L, Hkv, P, page_size, ...) arrays — int8
         data+scale tuples included)."""
-        if _kv_layout(self.serving) == "latent":
-            _refuse_latent(kv_handoff_export=True)
+        _refuse_layout(_kv_layout(self.serving), kv_handoff_export=True)
         fn = getattr(self.serving, "export_kv_pages", None)
         ids = list(page_ids)
         if fn is not None:
@@ -3466,8 +3610,7 @@ class ServingEngine:
         """Scatter a handoff's exported page content into THIS
         engine's pool at ``page_ids`` (the importer's freshly
         allocated chain). Counterpart of ``export_kv_pages``."""
-        if _kv_layout(self.serving) == "latent":
-            _refuse_latent(kv_handoff_import=True)
+        _refuse_layout(_kv_layout(self.serving), kv_handoff_import=True)
         fn = getattr(self.serving, "import_kv_pages", None)
         ids = list(page_ids)
         if fn is not None:
@@ -4145,7 +4288,10 @@ class EngineSession:
             eng.n_pool_pages, eng.page_size, kv_heads=1, head_dim=1,
             **({} if eng.window is None else dict(
                 window_pages=eng.n_window_pages, window=eng.window,
-                window_slack=eng._window_slack)))
+                window_slack=eng._window_slack)),
+            **({} if eng._state_every is None else dict(
+                state_slots=eng.slots,
+                state_snapshots=eng.n_state_snapshots)))
         eng._note_pool(self.book, self.m)
         # per-session host arena (hostmem= engines; None otherwise):
         # each replica owns its spill tier — eviction spill, priced

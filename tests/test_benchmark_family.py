@@ -81,7 +81,7 @@ def test_the_configuration_keeps_every_published_key_but_its_depth():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     entry = next(c for c in bench["configs"] if c["name"] == "moonlight-16b-a3b-serve-l7")
     assert entry["source"] == cfg["source"] and len(entry["source"]) < 200
-    assert [w["chips"] for w in bench["workloads"]].count(4) == 1 and len(bench["workloads"]) == 7
+    assert [w["chips"] for w in bench["workloads"]].count(4) == 1 and len(bench["workloads"]) == 8
 
 
 def test_the_familys_leaves_are_the_programs_state_dict():

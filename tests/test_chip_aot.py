@@ -448,3 +448,75 @@ def test_windowed_programs_keep_both_pools_in_place(v5e):
         stats = c.memory_analysis()
         assert stats.alias_size_in_bytes >= pool_bytes
         assert stats.temp_size_in_bytes < pool_bytes // 8
+
+
+# the linear-attention cell's geometry (serve_linear_latent_agent): 64 slots
+# and 96 snapshot entries of 7 KDA layers x (32, 128, 128) float32 + the
+# convolutions' 3 x 12288 inputs; 2 latent layers over 8193 pages, 281
+# table entries and the state entry's column
+STATE = dict(slots=64, entries=160, table=281, page=64, heads=32, d=128,
+             kda_layers=7, mla_layers=2, pool=8193, width=640)
+
+
+def test_kda_decode_kernel_compiles_at_the_cells_shapes(v5e):
+    """Every slot a row against the whole state operand addressed by
+    (layer, row): aliased, nothing of its size beside it."""
+    from paddle_tpu.ops.pallas.kda_decode import kda_decode_step
+    one = SingleDeviceSharding(v5e[0])
+    g = STATE
+    f32 = lambda *s: _sds(s, jnp.float32, one)  # noqa: E731
+    vec = f32(g["slots"], g["heads"], g["d"])
+    c = _compile(lambda S, q, k, v, a, b, act: kda_decode_step(S, 3, q, k, v, a, b, act),
+                 f32(g["kda_layers"], g["entries"], g["heads"], g["d"], g["d"]),
+                 vec, vec, vec, vec, f32(g["slots"], g["heads"]),
+                 _sds((g["slots"],), jnp.bool_, one), donate_argnums=(0,))
+    assert _mosaic_calls(c) == 1 and "%kda_decode_step" in c.as_text()
+    stats = c.memory_analysis()
+    state_bytes = g["kda_layers"] * g["entries"] * g["heads"] * g["d"] * g["d"] * 4
+    assert stats.alias_size_in_bytes >= state_bytes
+    assert stats.temp_size_in_bytes < state_bytes // 64
+
+
+def test_state_programs_keep_the_pool_and_the_states_in_place(v5e):
+    """The decode program and the lane's widest and narrowest calls of the
+    nine-layer cut at the published widths with a chip's 64 of 256 experts:
+    the latent pool, the states and the convolutions' inputs are donated and
+    aliased, nothing of their size is made beside them, a KDA layer steps
+    through the kernel once and a latent layer attends once a head group
+    (a held-expert map made as a traced scatter aborted this compiler)."""
+    from paddle_tpu.models.nlp import kimi_linear as M
+    one = SingleDeviceSharding(v5e[0])
+    g = STATE
+    net = M.KimiLinearForCausalLM(M.KimiLinearConfig(
+        num_hidden_layers=9, vocab_size=40960, experts_held=tuple(range(64))))
+    net.decode_params = lambda: (dict(net.outer),          # shapes in place of arrays
+                                 [dict(lp) for lp in net.layers])
+    outer, layers, _, prefill, _, decode_n = M.state_paged_decode_factory(
+        net, page_size=g["page"], n_pool_pages=3, n_state_entries=2,
+        chunked_prefill=g["page"])
+    pools = (_sds((g["mla_layers"], g["pool"], g["page"], g["width"]), BF16, one),
+             _sds((g["kda_layers"], g["entries"], g["heads"], g["d"], g["d"]),
+                  jnp.float32, one),
+             _sds((g["kda_layers"], g["entries"], 3, 3 * g["heads"] * g["d"]), BF16, one))
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    i32 = lambda *s: _sds(s, jnp.int32, one)  # noqa: E731
+    with lower_for_chip():
+        dec = decode_n._jit_inner[0].lower(
+            _on(outer, one), _on(layers, one), i32(g["slots"]),
+            i32(g["slots"], g["table"] + 1), i32(g["slots"]), pools, 1).compile()
+        calls = {w: prefill._jit_inner[0].program.lower(
+            _on(outer, one), _on(layers, one), i32(1, w * g["page"]), i32(),
+            i32(1, g["table"] + 1), i32(1), pools,
+            _sds((1, 2304), BF16, one)).compile() for w in (1, 4)}
+
+    def kernels(c, name):
+        return [ln for ln in c.as_text().splitlines()
+                if "custom-call(" in ln and f"%{name}" in ln.split("=")[0]]
+    assert len(kernels(dec, "kda_decode_step")) == g["kda_layers"]
+    assert len(kernels(dec, "latent_paged_attention")) == g["mla_layers"]
+    assert len(kernels(calls[1], "latent_paged_attention")) == g["mla_layers"]
+    assert len(kernels(calls[4], "latent_paged_attention")) == 2 * g["mla_layers"]
+    for c in (dec, *calls.values()):
+        stats = c.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes
+        assert stats.temp_size_in_bytes < pool_bytes // 8
