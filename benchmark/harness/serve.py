@@ -6,7 +6,8 @@ import time
 
 import jax
 
-from . import device, serve_check, traffic, weights as W
+from . import device, sentinel, serve_check, traffic, weights as W
+from ..metrics.readers import serve_latency
 from .checks import Checks
 from .clock import WallClock
 from .gclog import GcLog
@@ -85,10 +86,11 @@ def observe(reqs, res, clock, family, model, peak) -> dict:
             "model_flops": work, "peak": peak, "chips": 1, "model": model}
 
 
-def stalls(clock, gc_log, top=5) -> dict:
+def stalls(clock, gc_log, machine_stops, top=5) -> dict:
     """Where a window lost time in one piece: the calls that ran longest over
     the median of their kind and size, the longest stretches of host time
-    between two calls (waits for an arrival taken out), and the collector."""
+    between two calls (waits for an arrival taken out), the collector, and
+    the stops of the whole machine that the sentinel saw."""
     spans = [(kind, a, b, units if isinstance(units, (int, float, type(None))) else str(units))
              for kind, a, b, units in clock.spans]
     sizes = {}
@@ -108,7 +110,8 @@ def stalls(clock, gc_log, top=5) -> dict:
             "host_between_calls": [[k, ms(d), round(at, 3)] for d, k, at in between],
             "gc": dict(gc_log.summary(), longest=[
                 [g, ms(d), round(at - clock.t0, 3)]
-                for g, d, at in sorted(gc_log.events, key=lambda e: -e[1])[:top]])}
+                for g, d, at in sorted(gc_log.events, key=lambda e: -e[1])[:top]]),
+            "machine_stops": sentinel.summary(machine_stops)}
 
 
 def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
@@ -135,7 +138,8 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
                                   seconds))
         tw.arm()
     gc_log = GcLog()
-    res = eng.run(trace_reqs)
+    with sentinel.Sentinel() as watch:
+        res = eng.run(trace_reqs)
     gc_log.close()
     if tw is not None:
         tw.finish()
@@ -143,6 +147,8 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
     clock = eng.bench_clock
     peak = spec.peak(devices[0].device_kind) if devices[0].platform == "tpu" else None
     obs = observe(reqs, res, clock, family, model, peak)
+    obs["machine_stops"] = sentinel.on_clock(watch.stops, clock.t0, obs["first_due_s"],
+                                             obs["first_due_s"] + obs["window_s"])
     if fault == "token_altered":    # tests only: a served token changed where it is produced
         victim = max(obs["requests"], key=lambda r: r["prompt_len"] + len(r["output"]))
         victim["output"][len(victim["output"]) // 2] ^= 1
@@ -151,7 +157,7 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
     dense_waves = sum(d.get("backend") != "paged" for d in res.decisions)
     census_broken = 0 if res.cache_stats.get("invariant_ok") else 1
 
-    stalled = stalls(clock, gc_log)
+    stalled = stalls(clock, gc_log, obs["machine_stops"])
     del eng, res, trace_reqs
     gc.collect()
     t_ref = time.perf_counter()
@@ -168,7 +174,8 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
     checks.add("pool_census_broken", census_broken)
     info = {"traffic": traffic.summary(reqs), "window_s": obs["window_s"],
             "samples": {"requests": len(reqs), "token_gaps": sum(
-                max(len(r["token_times"]) - 1, 0) for r in obs["requests"])},
+                max(len(r["token_times"]) - 1, 0) for r in obs["requests"]),
+                "gaps_left_out": serve_latency.gaps_left_out(obs)},
             "setup_s": setup_s, "setup_parts": marks.parts,
             "memory_peak_at": marks.peak_bytes,
             "reference_s": time.perf_counter() - t_ref,

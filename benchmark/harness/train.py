@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import device, train_check, weights as W
+from . import device, sentinel, train_check, weights as W
 from .checks import Checks
 from .gclog import GcLog
 from .trace import TraceWindow, custom_calls
@@ -151,11 +151,12 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
     setup_s = time.perf_counter() - t_setup
 
     gc_log = GcLog()
-    if trace:
-        tw = TraceWindow(spec.root.parent / ".bench_trace")
-        t0, done_at, losses = traced_window(trainer, tw, TRACED_STEPS)
-    else:
-        t0, done_at, losses = window(trainer, seconds, run_ahead)
+    with sentinel.Sentinel() as watch:
+        if trace:
+            tw = TraceWindow(spec.root.parent / ".bench_trace")
+            t0, done_at, losses = traced_window(trainer, tw, TRACED_STEPS)
+        else:
+            t0, done_at, losses = window(trainer, seconds, run_ahead)
     gc_log.close()
     compiles_in_window = counter.count - compiles_before
     window_s = done_at[-1] - t0
@@ -165,12 +166,15 @@ def run(spec, cell, seed, seconds, trace, devices, counter, t_setup,
     peak = spec.peak(devices[0].device_kind) if devices[0].platform == "tpu" else None
     stats = step_stats(done_at, t0)
     stats["gc"] = gc_log.summary()
+    machine_stops = sentinel.on_clock(watch.stops, t0, 0.0, window_s)
+    stats["machine_stops"] = sentinel.summary(machine_stops)
     obs = {"kind": "train", "steps": len(done_at), "window_s": window_s,
            "step_ends_s": [t - t0 for t in done_at], "step_stats": stats,
            "tokens_per_step": job["batch"] * job["seq"], "chips": chips,
            "model_flops": len(done_at) * family.train_step_flops(model, job["batch"],
                                                                  job["seq"]),
-           "peak": peak, "setup_s": setup_s, "model": model, "job": job}
+           "peak": peak, "setup_s": setup_s, "model": model, "job": job,
+           "machine_stops": machine_stops}
 
     mesh = trainer.mesh
     del trainer

@@ -5,6 +5,8 @@ import math
 
 import numpy as np
 
+from benchmark.harness.sentinel import spans_a_stop
+
 
 def _percentile(values, q):
     values = [v for v in values if v is not None]
@@ -25,11 +27,20 @@ def _ttfts(obs):
 
 
 def _gaps(obs):
+    """Gaps between consecutive output tokens of every request, less those
+    that span a stop of the whole machine (``obs["machine_stops"]``, seen by
+    the sentinel process).  The program's own pauses all stay in."""
+    stops = obs.get("machine_stops") or ()
     out = []
     for r in obs["requests"]:
         t = r["token_times"]
-        out.extend(b - a for a, b in zip(t, t[1:]))
+        out.extend(b - a for a, b in zip(t, t[1:]) if not spans_a_stop(a, b, stops))
     return out
+
+
+def gaps_left_out(obs) -> int:
+    """How many gaps ``_gaps`` leaves out for spanning a stop of the machine."""
+    return len(_gaps(dict(obs, machine_stops=()))) - len(_gaps(obs))
 
 
 def ttft_percentile(obs, params):

@@ -51,6 +51,10 @@ def run_cell(spec, name: str, seed: int, seconds: float, trace: bool,
     obs = out["obs"]
     entries = spec.per_layer(name) if trace else spec.end_to_end(name)
     metrics = spec.read_metrics(entries, obs)
+    if obs.get("machine_stops"):    # what a reader that leaves them out reads with them in
+        with_stops = spec.read_metrics(entries, dict(obs, machine_stops=[]))
+        out["info"]["metrics_with_stops"] = {k: v["value"] for k, v in with_stops.items()
+                                             if metrics.get(k) != v}
     info = dict(out["info"], workload=name, seed=seed, seconds=seconds,
                 not_compared=out["checks"].not_compared,
                 start_to_chip_s={"total": t_chips - T_PROCESS,

@@ -21,8 +21,10 @@ def test_serve_cell_runs_and_is_correct(tiny_spec, capsys):
     assert "check served_gap_max: value" in captured.err and "limit 0.35 ok" in captured.err
     info = next(l for l in captured.out.splitlines() if l.startswith("info "))
     stalls = json.loads(info[5:])["stalls"]
-    assert {"calls_over_median", "host_between_calls", "gc"} <= set(stalls)
+    assert {"calls_over_median", "host_between_calls", "gc", "machine_stops"} <= set(stalls)
     assert stalls["calls_over_median"] and {"collections", "full", "longest_ms"} <= set(stalls["gc"])
+    assert {"count", "longest_ms", "total_ms", "at"} <= set(stalls["machine_stops"])
+    assert "gaps_left_out" in json.loads(info[5:])["samples"]
     json.dumps(out)
 
 
@@ -37,7 +39,8 @@ def test_train_cell_runs_and_is_correct(tiny_spec, capsys):
     assert set(out["metrics"]) == {"train_tok_s_chip", "setup_s"}
     info = next(l for l in capsys.readouterr().out.splitlines() if l.startswith("info "))
     steps = json.loads(info[5:])["steps"]
-    assert {"count", "mean_ms", "p50_ms", "max_ms", "over_1.05_p50", "lost_s", "gc"} <= set(steps)
+    assert {"count", "mean_ms", "p50_ms", "max_ms", "over_1.05_p50", "lost_s", "gc",
+            "machine_stops"} <= set(steps)
 
 
 @pytest.mark.parametrize("fault,number", [
