@@ -87,16 +87,39 @@ def weights(net):
     return {k: v._value for k, v in net.state_dict().items()}
 
 
+_LOGITS = {}
+
+
 def ref_logits(net, weights, tokens, quant=None):
-    return R.logits(ref_cfg(net.config), weights, jnp.asarray(tokens, jnp.int32),
-                    quant)
+    """One pass of the reference a sequence and ``quant`` in the file (the
+    served-token gaps and the int8 control share it)."""
+    key = id(weights), tuple(int(t) for t in tokens), quant
+    if key not in _LOGITS:      # the weights ride along: their id stays theirs
+        _LOGITS[key] = weights, R.logits(ref_cfg(net.config), weights,
+                                         jnp.asarray(key[1], jnp.int32), quant)
+    return _LOGITS[key][1]
 
 
-def engine(net, **kw):
+# One factory a geometry in this file: engines that differ in the clock or
+# the policy alone share its compiled programs (``serving=``), built by the
+# first engine that needs them.  Engines on one factory run one after another
+# (the live pools ride on it, and what a run leaves there no later run reads).
+_SERVING = {}
+
+
+def engine(net, own=False, **kw):
+    """An engine on the file's factory of its geometry; ``own``: on a
+    factory of its own (a test that counts compiles)."""
     args = dict(slots=4, max_len=128, page_size=PAGE, policy="paged",
                 prefill_chunk_budget=2, clock="fixed")
     args.update(kw)
-    return ServingEngine(net, **args)
+    key = net, repr(sorted(dict(args, clock=None, policy=None).items()))
+    if own or key not in _SERVING:
+        eng = ServingEngine(net, **args)
+        if not own:
+            _SERVING[key] = eng.serving
+        return eng
+    return ServingEngine(serving=_SERVING[key], **args)
 
 
 def requests(n=6, seed=0, lo=5, hi=60, new=6, gap=0.1):
@@ -340,7 +363,7 @@ def test_the_engine_serves_what_the_reference_puts_first(net, weights):
         own = tuple(int(t) for t in rng.integers(0, 256, size=6 + i))
         reqs[i] = Request(rid=f"doc{i}", arrival=at, prompt=doc + own,
                           max_new_tokens=8, prefix_group=0)
-    eng = engine(net)
+    eng = engine(net, own=True)
     res = eng.run(reqs)
     assert all(len(res.outputs[r.rid]) == 8 for r in reqs)
     assert res.prefix_cached["doc5"] == 40 and res.prefix_cached["doc1"] == 0

@@ -96,8 +96,22 @@ def tiny():
     return cfg, loaded(cfg, weights), weights
 
 
+_LOGITS = {}
+
+
 def ref_logits(cfg, weights, tokens, quant=None):
-    return np.asarray(R.logits(model_dict(cfg), weights, jnp.asarray(tokens), quant))
+    """One pass of the reference a sequence and ``quant`` in the file (the
+    planted fault's period, which a test may set, is part of the key).  Each
+    pass runs at 128 positions, so that it reuses the operations the first
+    one compiled: the reference is causal, and a row sees nothing of the
+    zeros after the sequence."""
+    key = id(weights), tuple(int(t) for t in tokens), quant, R.STATE_DROP_EVERY
+    if key not in _LOGITS:      # the weights ride along: their id stays theirs
+        padded = np.zeros(128, np.int32)
+        padded[:len(tokens)] = key[1]
+        _LOGITS[key] = weights, np.asarray(R.logits(
+            model_dict(cfg), weights, jnp.asarray(padded), quant))[:len(tokens)]
+    return _LOGITS[key][1]
 
 
 def tokens_of(n, seed=1, vocab=256):
@@ -184,11 +198,21 @@ def test_the_full_forward_is_the_plain_references_logits(tiny):
     np.testing.assert_allclose(got, ref_logits(cfg, weights, toks), atol=LOGITS)
 
 
+_PROGRAMS = {}
+
+
 def _factory(net, **kw):
+    """The programs of one build, compiled once in the file, and pools of
+    their own for every caller (a call donates them): zeros, as built."""
     args = dict(page_size=PAGE, n_pool_pages=40, n_state_entries=5,
                 chunked_prefill=PAGE, emit="logits")
     args.update(kw)
-    return M.state_paged_decode_factory(net, **args)
+    key = net, repr(sorted(args.items()))
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = M.state_paged_decode_factory(net, **args)
+        assert not any(a.any() for a in jax.tree_util.tree_leaves(_PROGRAMS[key][2]))
+    parts = _PROGRAMS[key]
+    return (*parts[:2], jax.tree_util.tree_map(jnp.zeros_like, parts[2]), *parts[3:])
 
 
 @pytest.mark.parametrize("calls", [(2, 1, 1), (4,), (1, 1, 1, 1), (3, 1)])
@@ -251,12 +275,25 @@ def test_decode_n_steps_an_idle_row_never(tiny):
 
 
 # -- through ServingEngine --------------------------------------------------
+# One factory a geometry in this file: engines that differ in the clock or
+# the prefix cache alone share its compiled programs (``serving=``), built by
+# the first engine that needs them.  Engines on one factory run one after
+# another (the live pools ride on it, and what a run leaves there no later
+# run reads).
+_SERVING = {}
+
+
 def _engine(net, **kw):
     args = dict(slots=3, max_len=256, page_size=PAGE, n_pool_pages=60, policy="paged",
                 prefill_chunk_budget=4, n_state_snapshots=4, state_snapshot_every=32,
                 clock="fixed")
     args.update(kw)
-    return ServingEngine(net, **args)
+    key = net, repr(sorted(dict(args, clock=None, prefix_cache=None).items()))
+    if key not in _SERVING:
+        eng = ServingEngine(net, **args)
+        _SERVING[key] = eng.serving
+        return eng
+    return ServingEngine(serving=_SERVING[key], **args)
 
 
 def _req(i, prompt, arrival=0.0, n=12):

@@ -27,6 +27,7 @@ from paddle_tpu.models.nlp import deepseek_v3 as D
 from paddle_tpu.models.nlp import laguna as L
 from paddle_tpu.serving import (EngineClock, Request, ServingEngine,
                                 make_sim_serving)
+from paddle_tpu.serving.engine import _jit_cache_size
 
 COSTS = {"prefill_unit": 0.3, "decode": 0.1}     # no binary fractions: sums round
 LIMIT = ServingEngine._LANE_STARVE_LIMIT
@@ -88,11 +89,27 @@ def factory(request):
     return request.param, build(), page, long_chunks, widest, kw
 
 
-def _engine(net, page, **kw):
+# One factory a geometry and model: engines that differ in the clock, its
+# costs or the tracer alone share its compiled programs (``serving=``), built
+# by the first engine that needs them.  Engines on one factory run one after
+# another (the live pools ride on it, and what a run leaves there no later
+# run reads).
+_SERVING = {}
+
+
+def _engine(net, page, own=False, **kw):
+    """An engine on the factory of its model and geometry; ``own``: on a
+    factory of its own (a test that counts compiles)."""
     args = dict(slots=8, max_len=page * 48, page_size=page, policy="paged",
                 prefill_chunk_budget=4, clock="fixed", fixed_costs=COSTS)
     args.update(kw)
-    return ServingEngine(net, **args)
+    key = net, repr(sorted(dict(args, clock=None, fixed_costs=None, trace=None).items()))
+    if own or key not in _SERVING:
+        eng = ServingEngine(net, **args)
+        if not own:
+            _SERVING[key] = eng.serving
+        return eng
+    return ServingEngine(serving=_SERVING[key], **args)
 
 
 def _trace(page, long_chunks, seed=3):
@@ -202,7 +219,7 @@ def test_every_width_is_compiled_when_the_engine_is_built(factory):
     """A first run of one-chunk prompts compiles the decode program; a run
     with every remainder then compiles nothing."""
     name, net, page, long_chunks, widest, kw = factory
-    eng = _engine(net, page, **kw)
+    eng = _engine(net, page, own=True, **kw)
     chunk_program, finish = eng._p_prefill._jit_inner
     # a program a width, or (the Llama gather path states that it pads) the
     # widest alone, which narrower spans ride padded
@@ -219,6 +236,27 @@ def test_every_width_is_compiled_when_the_engine_is_built(factory):
     assert eng._ctr_compiles.value == before
     assert chunk_program._cache_size() == programs and finish._cache_size() == 1
     assert all(len(out) > 0 for out in res.outputs.values())
+
+
+def test_a_second_engine_on_a_prebuilt_factory_compiles_nothing():
+    """What the serving tests build on (``_engine``): a factory passed as
+    ``serving=`` shares its compiled programs, so that an engine on it adds
+    no entry to the ``decode_n`` or lane programs' caches, neither while it
+    is built (every lane width is called then) nor while it runs."""
+    net, page = _llama(), 4
+    reqs = _trace(page, 30)
+    first = _engine(net, page, own=True)
+    res = first.run(reqs)
+
+    def sizes(eng):
+        return [_jit_cache_size(p) for p in (eng._p_decode_n, *eng._p_prefill._jit_inner)]
+    built = sizes(first)
+    second = ServingEngine(serving=first.serving, slots=8, policy="paged",
+                           prefill_chunk_budget=4, clock="fixed", fixed_costs=COSTS)
+    assert sizes(second) == built
+    compiles = second._ctr_compiles.value
+    assert second.run(reqs).outputs == res.outputs
+    assert sizes(second) == built and second._ctr_compiles.value == compiles
 
 
 def test_a_padded_span_at_the_tables_end_lands_on_the_padding_page():
